@@ -16,6 +16,7 @@ serve/, rl/ (rllib), workflow/ (durable crash-resumable step DAGs),
 collective/, util/.
 """
 
+from ray_tpu._private.compile_cache import ensure_compile_cache
 from ray_tpu._private.config import GlobalConfig as _config  # noqa: F401
 from ray_tpu._private.worker import (
     ObjectRef,
@@ -34,6 +35,10 @@ from ray_tpu.runtime_context import get_runtime_context
 from ray_tpu import exceptions
 
 __version__ = "0.1.0"
+
+# Every process that compiles imports this package first (drivers, worker
+# processes, node daemons), so this is the one call site.
+ensure_compile_cache()
 
 
 def announce_object(ref) -> None:

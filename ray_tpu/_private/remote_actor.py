@@ -38,6 +38,7 @@ from ray_tpu._private.log import get_logger
 from ray_tpu._private.object_server import PeerUnreachableError
 from ray_tpu._private import tracing
 from ray_tpu._private.serialization import SerializedObject
+from ray_tpu._private.tpu_chips import chips_requested
 from ray_tpu.exceptions import ActorDiedError, RayTaskError
 
 log = get_logger(__name__)
@@ -193,6 +194,7 @@ class RemoteActorRuntime:
                 "max_concurrency": self.max_concurrency,
                 "max_restarts": self.max_restarts,
                 "runtime_target": self.opts.get("runtime"),
+                "num_tpus": chips_requested(self.opts),
                 "driver_id": self.head.client_id,
                 "driver_addr": list(self.head._object_server.address),
                 "name": self.class_name,
@@ -535,6 +537,7 @@ class ActorHost:
             name=p.get("name") or cls.__name__,
             actor_name=None,
             runtime_target=p.get("runtime_target"),
+            num_tpus=int(p.get("num_tpus") or 0),
         )
         abin = aid.binary()
         with self._lock:

@@ -377,11 +377,15 @@ class RemoteRouter:
     # ------------------------------------------------------ actor placement
     @staticmethod
     def actor_demand(opts: dict) -> Dict[str, float]:
-        """Resource demand of an actor from its options (num_cpus +
-        custom resources + PG bundle shape)."""
+        """Resource demand of an actor from its options (num_cpus,
+        num_tpus — num_gpus is its alias, as for tasks — custom
+        resources and PG bundle shape)."""
         demand: Dict[str, float] = {}
         if opts.get("num_cpus"):
             demand["CPU"] = float(opts["num_cpus"])
+        num_acc = opts.get("num_tpus", opts.get("num_gpus"))
+        if num_acc:
+            demand["TPU"] = float(num_acc)
         strat = opts.get("scheduling_strategy")
         from ray_tpu.util.scheduling_strategies import (
             PlacementGroupSchedulingStrategy,

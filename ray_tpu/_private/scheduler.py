@@ -689,6 +689,30 @@ class LocalScheduler:
         except Exception:  # noqa: BLE001 — already reclaimed
             pass
 
+    def _lease_for(self, spec: TaskSpec):
+        """The process a task runs in: a pooled CPU-pinned worker, or —
+        for a task that holds ``TPU`` (already reserved in the resource
+        pool by dispatch) — a worker of its own that is shown exactly
+        the chips taken here. Returns ``(worker, chips)``."""
+        from ray_tpu._private.tpu_chips import whole_chips
+        from ray_tpu._private.worker import global_worker
+
+        n = whole_chips(spec.resources)
+        chips = (global_worker().chips.take(n, f"task {spec.name}")
+                 if n else ())
+        try:
+            return self._worker_pool.lease(
+                runtime_env=spec.runtime_env, tpu_chips=chips), chips
+        except BaseException:
+            global_worker().chips.give_back(chips)
+            raise
+
+    def _release_for(self, w, chips):
+        from ray_tpu._private.worker import global_worker
+
+        self._worker_pool.release(w)  # a chip worker exits here
+        global_worker().chips.give_back(chips)
+
     def _execute_in_process(self, spec: TaskSpec, args, kwargs,
                             cancelled_event):
         """Ship the task to a leased worker process; outputs come back
@@ -703,7 +727,7 @@ class LocalScheduler:
         from ray_tpu._private.worker_pool import maybe_stage
 
         ctx = global_worker().serialization_context
-        w = self._worker_pool.lease(runtime_env=spec.runtime_env)
+        w, chips = self._lease_for(spec)
         staged: list = []
         ret_keys = [self._ret_key(oid, spec.attempt)
                     for oid in spec.return_ids]
@@ -765,7 +789,7 @@ class LocalScheduler:
             raise
         finally:
             self._delete_shm_keys(staged)
-            self._worker_pool.release(w)
+            self._release_for(w, chips)
 
     def _delete_shm_keys(self, keys):
         for key in keys:
@@ -837,7 +861,7 @@ class LocalScheduler:
 
         ctx = global_worker().serialization_context
         stream = global_worker().streams.get_or_create(spec.task_id)
-        w = self._worker_pool.lease(runtime_env=spec.runtime_env)
+        w, chips = self._lease_for(spec)
         staged: list = []
         try:
             digest, fn_bytes = pack_function(spec.function)
@@ -864,7 +888,7 @@ class LocalScheduler:
                     self._proc_running.pop(spec.task_id, None)
         finally:
             self._delete_shm_keys(staged)
-            self._worker_pool.release(w)
+            self._release_for(w, chips)
 
     def _store_outputs(self, spec: TaskSpec, result: Any):
         from ray_tpu._private.worker import global_worker

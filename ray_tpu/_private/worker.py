@@ -465,19 +465,18 @@ class Worker:
         if num_cpus is None:
             num_cpus = os.cpu_count() or 1
         total = {"CPU": float(num_cpus)}
-        if num_tpus is None:
-            try:
-                import jax
+        # Chips are counted from the device nodes, never through JAX: a
+        # driver that initialised a backend would own every chip, and
+        # the worker processes that run the models could open none.
+        from ray_tpu._private.tpu_chips import ChipTable, detect_num_chips
 
-                num_tpus = len([
-                    d for d in jax.devices() if d.platform != "cpu"
-                ])
-            except Exception:  # noqa: BLE001 — jax optional at init
-                num_tpus = 0
+        if num_tpus is None:
+            num_tpus = detect_num_chips()
         if num_tpus:
             total["TPU"] = float(num_tpus)
         total.update(resources or {})
         self.resource_pool = ResourcePool(total)
+        self.chips = ChipTable(int(total.get("TPU", 0)))
         pool_size = GlobalConfig.worker_pool_size or max(int(num_cpus), 4)
         # Process execution plane: worker processes leased from a pool, fed
         # over the native shm store (reference: raylet WorkerPool + plasma).
@@ -496,7 +495,7 @@ class Worker:
                 self.worker_pool = WorkerPool(
                     self.shm_store, num_workers=max(int(num_cpus), 1),
                     max_msg=GlobalConfig.worker_channel_bytes,
-                    log_dir=log_dir)
+                    log_dir=log_dir, host_chips=self.chips.total)
                 # Stream worker prints back to the driver (log plane).
                 from ray_tpu._private.log_monitor import LogMonitor
 

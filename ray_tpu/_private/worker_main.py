@@ -150,10 +150,6 @@ def worker_loop(store_name: str, req_id: int, rep_id: int,
                 worker_id: int, max_msg: int,
                 api_req_id: int = 0, api_rep_id: int = 0,
                 ack_id: int = 0) -> None:
-    # Workers never touch the TPU: the device belongs to the driver (the
-    # compiled-graph path); keep jax (if imported by user code) on CPU.
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
     import cloudpickle
 
     from ray_tpu._native.store import NativeMutableChannel, NativeObjectStore

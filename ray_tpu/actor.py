@@ -21,6 +21,7 @@ from typing import Any, Dict, Optional
 from ray_tpu._private.config import GlobalConfig
 from ray_tpu._private.ids import ActorID, ObjectID, TaskID
 from ray_tpu._private.log import get_logger
+from ray_tpu._private.tpu_chips import chips_requested
 from ray_tpu._private.worker import ObjectRef, auto_init, global_worker
 from ray_tpu._private import tracing
 
@@ -71,7 +72,8 @@ class _ActorRuntime:
     def __init__(self, actor_id: ActorID, cls: type, init_args, init_kwargs,
                  *, max_concurrency: int, max_restarts: int, name: str,
                  actor_name: Optional[str],
-                 runtime_target: Optional[str] = None):
+                 runtime_target: Optional[str] = None,
+                 num_tpus: int = 0):
         self.actor_id = actor_id
         self.cls = cls
         self.init_args = init_args
@@ -80,6 +82,8 @@ class _ActorRuntime:
         self.restarts_used = 0
         self.class_name = name
         self.actor_name = actor_name
+        self._chips: tuple = ()         # TPU chips held (see below)
+        self._chips_lock = threading.Lock()
         self.dead = False
         self.death_cause: Optional[str] = None
         self._mailbox: "queue.Queue" = queue.Queue()
@@ -112,7 +116,48 @@ class _ActorRuntime:
         self._proc = None
         self._restart_pending = False
         self.pid: Optional[int] = None
+        # Device ownership: an actor that declares TPU holds whole chips
+        # for its lifetime (across restarts) and its worker process is
+        # the one process that opens them. A claim that cannot be met
+        # fails here, naming the holders — it neither waits nor runs on
+        # the CPU instead.
+        self._chip_pool = worker
+        if num_tpus:
+            holder = f"actor {name} (id {actor_id.hex()[:12]})"
+            if not worker.resource_pool.try_acquire(
+                    {"TPU": float(num_tpus)}):
+                raise worker.chips.busy(num_tpus, holder)
+            self._chips = worker.chips.take(num_tpus, holder)
         self._start_loop()
+
+    @property
+    def dead(self) -> bool:
+        return self._dead
+
+    @dead.setter
+    def dead(self, value: bool):
+        self._dead = value
+        if value:
+            self._release_chips()
+
+    def _release_chips(self):
+        """A dead actor runs nothing more: make sure the process that
+        opened its chips is gone, then hand them to the next claimant."""
+        with self._chips_lock:
+            chips, self._chips = self._chips, ()
+        if not chips:
+            return
+        proc = self._proc
+        if proc is not None:
+            proc.kill()
+            try:
+                # Seconds, for a process that mapped several chips.
+                proc.proc.wait(timeout=30.0)
+            except Exception as exc:  # noqa: BLE001 — still release
+                log.warning("actor %s worker %s did not exit after kill: "
+                            "%r", self.class_name, proc.pid, exc)
+        self._chip_pool.chips.give_back(chips)
+        self._chip_pool.resource_pool.release({"TPU": float(len(chips))})
 
     # ---------------------------------------------------------------- loops
     def _start_loop(self):
@@ -222,7 +267,9 @@ class _ActorRuntime:
         proc = WorkerProcess(worker.shm_store,
                              max_msg=GlobalConfig.worker_channel_bytes,
                              log_dir=os.path.join(worker.session_dir,
-                                                  "logs"))
+                                                  "logs"),
+                             tpu_chips=self._chips,
+                             host_chips=worker.chips.total)
         staged = []
         try:
             args, kwargs = _resolve_values(
@@ -1374,6 +1421,7 @@ class ActorClass:
                     name=self._cls.__name__,
                     actor_name=actor_name,
                     runtime_target=opts.get("runtime"),
+                    num_tpus=chips_requested(opts),
                 )
         except BaseException:
             if actor_name and worker.head_client is not None:

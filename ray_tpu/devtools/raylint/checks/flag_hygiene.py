@@ -38,7 +38,6 @@ from ray_tpu.devtools.raylint.walker import ModuleInfo
 # Every entry must be documented in README.md's flag table.
 BOOTSTRAP_ENV_FLAGS: Set[str] = {
     "RAY_TPU_CLUSTER_TOKEN",     # transport auth — read pre-handshake
-    "RAY_TPU_PLATFORM",          # device-plane selection before jax init
     "RAY_TPU_NUM_PROCESSES",     # multi-process identity, set by launcher
     "RAY_TPU_PROCESS_ID",        # multi-process identity, set by launcher
     "RAY_TPU_PARENT_PID",        # spawner pid for the worker orphan fence

@@ -1,6 +1,6 @@
 """Typed error surface.
 
-Mirrors the reference's exception taxonomy (reference:
+Mirrors the reference's exception hierarchy (reference:
 python/ray/exceptions.py [unverified]) so users migrating from it find the
 same failure vocabulary: remote task errors carry the reconstructed remote
 traceback; object loss / worker death / timeouts are distinct types.

@@ -146,17 +146,28 @@ class InferenceEngine:
             prefill_chunk,
             verify_step,
         )
+        from ray_tpu.ops import backend as ops_backend
+        from ray_tpu.parallel.sharding import (
+            param_sharding_tree,
+            shard_params,
+        )
 
         self.config = config or EngineConfig()
         self.model_cfg = self.config.resolved_model()
-        if params is None:
-            params = init_params(
-                self.model_cfg, jax.random.PRNGKey(self.config.param_seed))
         self.mesh = None
         rules = None
         if self.config.tp_size > 1:
             self.mesh, rules = self._build_tp_mesh(self.config.tp_size)
-            params = self._shard_params(params, rules)
+        if params is None:
+            init = partial(init_params, self.model_cfg)
+            if self.mesh is not None:
+                # Born sharded: no device ever holds the whole tree.
+                init = jax.jit(init, out_shardings=param_sharding_tree(
+                    self.mesh, self._param_specs(rules)))
+            params = init(jax.random.PRNGKey(self.config.param_seed))
+        elif self.mesh is not None:
+            params = shard_params(params, self.mesh,
+                                  self._param_specs(rules))
         self.params = params
         self.cache = PagedKVCache(
             self.model_cfg, self.config.num_blocks, self.config.block_size,
@@ -170,8 +181,7 @@ class InferenceEngine:
             max_queued_requests=self.config.max_queued_requests)
         # Donation rewrites the cache in place on accelerators; the CPU
         # backend only warns, so skip it there to keep logs clean.
-        backend = jax.default_backend()
-        donate = (1,) if backend != "cpu" else ()
+        donate = () if ops_backend.on_cpu() else (1,)
         self._prefill_chunk = jax.jit(
             partial(prefill_chunk, self.model_cfg, mesh=self.mesh,
                     rules=rules),
@@ -221,6 +231,8 @@ class InferenceEngine:
         self.num_steps = 0
         self.num_prefill_tokens = 0      # prompt tokens actually computed
         self.num_generated_tokens = 0
+        self.num_failed_requests = 0     # FAILED finishes (incl. the
+        self.last_failure: Optional[str] = None  # loop's catch-all)
         # -- speculative-decoding counters --
         self.spec_rounds = 0             # verify steps run
         self.spec_proposed = 0           # draft tokens proposed
@@ -258,15 +270,12 @@ class InferenceEngine:
         framework axes, every other axis size 1, so the default
         ShardingRules apply unchanged — batch axes become no-op
         shards)."""
-        import os
-
         import jax
 
         from ray_tpu.parallel.mesh import MeshConfig, make_mesh
         from ray_tpu.parallel.sharding import ShardingRules
 
-        platform = os.environ.get("RAY_TPU_PLATFORM")
-        devices = jax.devices(platform) if platform else jax.devices()
+        devices = jax.devices()
         if len(devices) < tp:
             raise ValueError(
                 f"tp_size {tp} exceeds {len(devices)} visible devices")
@@ -274,7 +283,7 @@ class InferenceEngine:
                          devices=devices[:tp])
         return mesh, ShardingRules()
 
-    def _shard_params(self, params, rules):
+    def _param_specs(self, rules):
         cfg = self.model_cfg
         if cfg.n_heads % self.config.tp_size or \
                 cfg.n_kv_heads % self.config.tp_size:
@@ -282,9 +291,8 @@ class InferenceEngine:
                 f"n_heads {cfg.n_heads} / n_kv_heads {cfg.n_kv_heads} "
                 f"must divide tp_size {self.config.tp_size}")
         from ray_tpu.models import param_specs
-        from ray_tpu.parallel.sharding import shard_params
 
-        return shard_params(params, self.mesh, param_specs(cfg, rules))
+        return param_specs(cfg, rules)
 
     # ------------------------------------------------------------ lifecycle
     def _ensure_loop(self):
@@ -448,6 +456,9 @@ class InferenceEngine:
         self._requests.pop(req.seq_id, None)
         req.t_finish = time.monotonic()
         self._record_timing(req, status)
+        if status == FAILED:
+            self.num_failed_requests += 1
+            self.last_failure = repr(error)
         if status in (FAILED, SHED) and error is not None:
             req.output_queue.put((_ERROR, error))
         else:
@@ -930,6 +941,8 @@ class InferenceEngine:
             "steps": self.num_steps,
             "prefill_tokens": self.num_prefill_tokens,
             "generated_tokens": self.num_generated_tokens,
+            "failed_requests": self.num_failed_requests,
+            "last_failure": self.last_failure,
             "ttft_decomposition": self.ttft_decomposition(),
             "held_sequences": len(self._held),
         }
@@ -946,6 +959,11 @@ class InferenceEngine:
             }
         out.update(self.scheduler.stats())
         out.update(self.cache.stats())
+        # The process that holds the model says where it runs, so a
+        # caller elsewhere can refuse a CPU result.
+        from ray_tpu.ops.backend import device_info
+
+        out.update(device_info())
         return out
 
     def ttft_decomposition(self) -> Dict[str, Any]:

@@ -41,6 +41,7 @@ free only its private blocks.
 
 from __future__ import annotations
 
+import functools
 import hashlib
 import threading
 from collections import OrderedDict
@@ -98,21 +99,23 @@ class PagedKVCache:
         self.block_size = int(block_size)
         self.enable_prefix_caching = bool(enable_prefix_caching)
         self.mesh = mesh
-        self.data = init_kv_cache(model_cfg, num_blocks, block_size, dtype)
+        init = functools.partial(init_kv_cache, model_cfg, num_blocks,
+                                 block_size, dtype)
         if mesh is not None:
-            # TP decode: the pool lives sharded along n_kv_heads across
-            # the mesh; every block id indexes the same logical block on
-            # every shard, so the host bookkeeping below is unchanged.
+            # TP decode: the pool is BORN sharded along n_kv_heads across
+            # the mesh (no device ever holds all of it); every block id
+            # indexes the same logical block on every shard, so the host
+            # bookkeeping below is unchanged.
             import jax
 
-            from ray_tpu.parallel.sharding import kv_cache_specs
+            from ray_tpu.parallel.sharding import (
+                kv_cache_specs,
+                param_sharding_tree,
+            )
 
-            specs = kv_cache_specs(rules)
-            self.data = {
-                k: jax.device_put(
-                    v, jax.sharding.NamedSharding(mesh, specs[k]))
-                for k, v in self.data.items()
-            }
+            init = jax.jit(init, out_shardings=param_sharding_tree(
+                mesh, kv_cache_specs(rules)))
+        self.data = init()
         # LIFO free list, block 0 reserved as NULL.
         self._free: List[int] = list(range(num_blocks - 1, 0, -1))
         self._tables: Dict[int, List[int]] = {}
@@ -357,7 +360,9 @@ class PagedKVCache:
         if self._block_copy is None:
             import jax
 
-            donate = (0,) if jax.default_backend() != "cpu" else ()
+            from ray_tpu.ops import backend
+
+            donate = () if backend.on_cpu() else (0,)
             self._block_copy = jax.jit(
                 lambda arr, s, d: arr.at[:, d].set(arr[:, s]),
                 donate_argnums=donate)

@@ -164,7 +164,8 @@ def rope(x, positions, theta):
 def _attention_dense(q, k, v, causal=True, grad=True):
     """q [B,S,Hq,Dh], k/v [B,S,Hkv,Dh] -> [B,S,Hq,Dh].
 
-    On TPU with tileable shapes this dispatches to the Pallas flash
+    Where ``ops.flash_attention.use_flash`` accepts the shape (never on
+    the CPU backend) this dispatches to the Pallas flash
     kernel (ops/flash_attention.py, differentiable via its blockwise
     custom_vjp) — the [S, S] score matrix never hits HBM, which is what
     unlocks long sequences and large batches under grad. The kernel's
@@ -178,20 +179,21 @@ def _attention_dense(q, k, v, causal=True, grad=True):
     contract against K/V at n_kv_heads width (the same grouped form the
     paged decode cache relies on).
     """
+    from ray_tpu.ops.flash_attention import (
+        flash_attention,
+        flash_attention_grouped,
+        use_flash,
+    )
+
     B, S, Hq, Dh = q.shape
     Hkv = k.shape[2]
-    on_tpu = any(d.platform == "tpu" for d in jax.devices())
-    if on_tpu and S >= 128 and S % 128 == 0 and Dh % 8 == 0:
+    if use_flash(S, S, Dh, q.dtype):
         if Hq != Hkv and not grad:
-            from ray_tpu.ops.flash_attention import flash_attention_grouped
-
             o = flash_attention_grouped(q.transpose(0, 2, 1, 3),
                                         k.transpose(0, 2, 1, 3),
                                         v.transpose(0, 2, 1, 3),
                                         causal=causal)
             return o.transpose(0, 2, 1, 3)
-        from ray_tpu.ops.flash_attention import flash_attention
-
         if Hq != Hkv:
             k = jnp.repeat(k, Hq // Hkv, axis=2)
             v = jnp.repeat(v, Hq // Hkv, axis=2)

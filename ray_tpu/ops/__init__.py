@@ -1,9 +1,9 @@
 """TPU kernels (Pallas) for the framework's hot ops.
 
 The reference's hot kernels live in CUDA via torch; here they are Pallas
-TPU kernels with jax-level fallbacks. Kernels auto-fall back to the pure
-jax implementation off-TPU (CPU tests) or when shapes don't fit the TPU
-tiling constraints, so every call site is portable.
+TPU kernels. A kernel is interpreted on the CPU backend (the tests) and
+compiled everywhere else (``ops/backend.py`` is the only place that
+asks); shapes outside a kernel's stated guard take the ``jnp`` form.
 """
 
 from ray_tpu.ops.flash_attention import (

@@ -8,8 +8,8 @@ ring_attention layer uses across chips; this kernel is the within-chip
 block loop).
 
 Grid: (batch*heads, num_q_blocks); the k-loop runs inside the kernel via
-fori_loop over VMEM blocks. Falls back to a pure-jax implementation on
-non-TPU backends or awkward shapes.
+fori_loop over VMEM blocks. Shapes outside ``kernel_accepts`` take the
+dense ``jnp`` form; the backend never decides that (``ops/backend.py``).
 """
 
 from __future__ import annotations
@@ -22,7 +22,20 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ray_tpu.ops import backend
+
 NEG_INF = -1e30
+
+# What the TPU compiler accepts, found by compiling ahead of time for a
+# v5e topology (tests/test_tpu_aot.py holds both sides of each limit):
+#  - the log-sum-exp store ``lse_ref[:, dslice(qi * block_q, block_q)]``
+#    needs a lane-aligned offset, so blocks are multiples of 128;
+#  - every program keeps whole per-head arrays resident in VMEM (K and V;
+#    q, dout and out in the dk/dv kernel) under a 16 MiB scoped limit.
+#    A per-head array (S x D x itemsize) of 3 MiB compiles in the forward
+#    and both backward kernels; 3.5 MiB is refused.
+_LANE = 128
+_VMEM_HEAD_ARRAY_BYTES = 3 << 20
 
 
 def _fallback(q, k, v, causal, scale):
@@ -198,6 +211,29 @@ def _auto_block(seq: int, cap: int = 512) -> int:
     return b
 
 
+def kernel_accepts(sq: int, sk: int, d: int, itemsize: int,
+                   block_q: int, block_k: int, *, interpret: bool) -> bool:
+    """The one statement of which shapes run the kernels. The tiling
+    rules hold in both modes; the lane and VMEM limits are the TPU
+    compiler's, so the interpreter (CPU tests, small tiles) skips them."""
+    if sq < 8 or sk < 8 or d % 8 or sq % block_q or sk % block_k:
+        return False
+    if interpret:
+        return True
+    if block_q % _LANE or block_k % _LANE:
+        return False
+    return max(sq, sk) * d * itemsize <= _VMEM_HEAD_ARRAY_BYTES
+
+
+def use_flash(sq: int, sk: int, d: int, dtype) -> bool:
+    """Model-path dispatch: the compiled kernel wherever it is accepted,
+    the model's dense ``jnp`` attention on the CPU backend and for the
+    shapes the guard refuses."""
+    return not backend.on_cpu() and kernel_accepts(
+        sq, sk, d, jnp.dtype(dtype).itemsize, _auto_block(sq),
+        _auto_block(sk), interpret=False)
+
+
 def flash_attention(
     q: jax.Array,
     k: jax.Array,
@@ -217,19 +253,13 @@ def flash_attention(
         scale = q.shape[-1] ** -0.5
     B, H, Sq, D = q.shape
     Sk = k.shape[2]
-    on_tpu = any(d.platform == "tpu" for d in jax.devices())
     if interpret is None:
-        interpret = not on_tpu
-    if block_q is None:
-        block_q = _auto_block(Sq)
-    if block_k is None:
-        block_k = _auto_block(Sk)
-    # Tiling constraints: block divisibility and lane-width-friendly D.
-    if (Sq % min(block_q, Sq) or Sk % min(block_k, Sk)
-            or Sq < 8 or Sk < 8 or D % 8):
+        interpret = backend.on_cpu()
+    block_q = min(block_q or _auto_block(Sq), Sq)
+    block_k = min(block_k or _auto_block(Sk), Sk)
+    if not kernel_accepts(Sq, Sk, D, q.dtype.itemsize, block_q, block_k,
+                          interpret=bool(interpret)):
         return _fallback(q, k, v, causal, scale)
-    block_q = min(block_q, Sq)
-    block_k = min(block_k, Sk)
     return _flash_core(q, k, v, causal, scale, block_q, block_k,
                        bool(interpret))
 
@@ -266,18 +296,13 @@ def flash_attention_grouped(
         return flash_attention(q, k, v, causal=causal, scale=scale,
                                block_q=block_q, block_k=block_k,
                                interpret=interpret)
-    on_tpu = any(d.platform == "tpu" for d in jax.devices())
     if interpret is None:
-        interpret = not on_tpu
-    if block_q is None:
-        block_q = _auto_block(Sq)
-    if block_k is None:
-        block_k = _auto_block(Sk)
-    if (Sq % min(block_q, Sq) or Sk % min(block_k, Sk)
-            or Sq < 8 or Sk < 8 or D % 8):
+        interpret = backend.on_cpu()
+    block_q = min(block_q or _auto_block(Sq), Sq)
+    block_k = min(block_k or _auto_block(Sk), Sk)
+    if not kernel_accepts(Sq, Sk, D, q.dtype.itemsize, block_q, block_k,
+                          interpret=bool(interpret)):
         return _fallback_grouped(q, k, v, causal, scale)
-    block_q = min(block_q, Sq)
-    block_k = min(block_k, Sk)
     return _flash_forward_grouped(q, k, v, causal, scale, block_q,
                                   block_k, bool(interpret))
 

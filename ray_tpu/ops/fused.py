@@ -3,7 +3,7 @@
 XLA fuses most elementwise chains into adjacent matmuls on its own; these
 Pallas kernels cover the reductions it fuses less aggressively (norm +
 scale in one VMEM pass; log-softmax + gather in one pass over the vocab
-axis). All have jax fallbacks for CPU/odd shapes.
+axis). Odd shapes take the ``jnp`` form; the CPU backend interprets.
 """
 
 from __future__ import annotations
@@ -14,6 +14,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from ray_tpu.ops import backend
 
 
 def _rms_kernel(x_ref, w_ref, o_ref, *, eps: float):
@@ -31,9 +33,8 @@ def rms_norm_fused(x: jax.Array, w: jax.Array, eps: float = 1e-6,
     rows = 1
     for s in x.shape[:-1]:
         rows *= s
-    on_tpu = any(d.platform == "tpu" for d in jax.devices())
     if interpret is None:
-        interpret = not on_tpu
+        interpret = backend.on_cpu()
     if rows == 0 or D % 8 or rows % min(block_rows, rows):
         x32 = x.astype(jnp.float32)
         var = jnp.mean(x32 * x32, axis=-1, keepdims=True)

@@ -81,13 +81,7 @@ def make_mesh(
     elif axis_sizes:
         raise ValueError("pass either a MeshConfig or axis kwargs, not both")
     if devices is None:
-        import os
-
-        # Pin the device platform explicitly (e.g. tests force "cpu" so the
-        # 8-device virtual mesh is used even when a TPU plugin also
-        # registered itself as the default backend).
-        platform = os.environ.get("RAY_TPU_PLATFORM")
-        devices = jax.devices(platform) if platform else jax.devices()
+        devices = jax.devices()
     sizes = config.sizes(len(devices))
     arr = np.asarray(devices).reshape(sizes)
     return Mesh(arr, AXES)

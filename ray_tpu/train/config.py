@@ -9,8 +9,17 @@ from typing import Any, Dict, Optional
 @dataclasses.dataclass
 class ScalingConfig:
     num_workers: int = 1
-    use_tpu: bool = False  # reference: use_gpu
+    # One whole TPU chip for each worker (reference: use_gpu): the worker
+    # process is then the one that opens it. For another count, put
+    # ``"TPU": n`` in ``resources_per_worker`` instead.
+    use_tpu: bool = False
     resources_per_worker: Optional[Dict[str, float]] = None
+
+    def worker_resources(self) -> Dict[str, float]:
+        resources = dict(self.resources_per_worker or {})
+        if self.use_tpu:
+            resources.setdefault("TPU", 1.0)
+        return resources
 
     @property
     def total_workers(self) -> int:

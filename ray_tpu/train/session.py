@@ -49,6 +49,14 @@ class TrainContext:
     def get_trial_name(self) -> str:
         return self.trial_name
 
+    def get_device_info(self) -> Dict[str, Any]:
+        """Platform, device kind and device count of THIS worker process
+        as JAX reports them — put it in ``report()`` so the driver can
+        refuse a result that did not come from the chip."""
+        from ray_tpu.ops.backend import device_info
+
+        return device_info()
+
     @property
     def collective_group(self) -> str:
         """The worker group's actor-plane collective group name (joined

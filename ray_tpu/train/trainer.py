@@ -204,9 +204,9 @@ class JaxTrainer:
         # feasibility — an infeasible-local demand forces every worker
         # onto the cluster (one per node when capacity divides that way).
         worker_opts: Dict[str, Any] = {"scheduling_strategy": "SPREAD"}
-        if self._scaling.resources_per_worker:
-            worker_opts["resources"] = dict(
-                self._scaling.resources_per_worker)
+        resources = self._scaling.worker_resources()
+        if resources:
+            worker_opts["resources"] = resources
         workers = [TrainWorker.options(**worker_opts).remote()
                    for _ in range(n)]
         run_refs = [w.run.remote(i) for i, w in enumerate(workers)]

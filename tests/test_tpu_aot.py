@@ -1,0 +1,235 @@
+"""Compile for a TPU v5e ahead of time, with no chip present.
+
+The installed libtpu compiles for a DESCRIBED topology under
+``JAX_PLATFORMS=cpu``: ``get_topology_desc("tpu", "v5e:2x2")`` gives four
+abstract ``TPU v5 lite`` devices, and lowering against shardings on them
+runs the real TPU compiler, Mosaic included, and reports memory. So a
+kernel the compiler refuses, or a program that does not fit the chip's
+HBM, fails here on the CPU instead of costing chip time.
+
+The shapes are chip_smoke.py's, so what this file accepts is what the
+smoke then runs. The kernels pick interpret mode from the one predicate
+in ``ops/backend.py``; compiling FOR the TPU from a CPU process is the
+one place that answer is wrong, so the fixture overrides it.
+"""
+
+import functools
+import importlib
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
+from jax.sharding import PartitionSpec as P
+
+import chip_smoke
+from ray_tpu.models import (
+    TransformerConfig,
+    decode_step,
+    init_kv_cache,
+    init_params,
+    param_specs,
+    prefill_chunk,
+)
+from ray_tpu.ops import backend, flash_attention, flash_attention_grouped
+from ray_tpu.ops.fused import rms_norm_fused
+from ray_tpu.parallel.sharding import ShardingRules, kv_cache_specs
+
+fa = importlib.import_module("ray_tpu.ops.flash_attention")
+
+HBM_BYTES = 15.75 * 2 ** 30   # what the compiler allows of a v5e's 16 GB
+
+
+@pytest.fixture(scope="module")
+def tpu():
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as exc:  # noqa: BLE001 — no libtpu in this install
+        pytest.skip(f"no ahead-of-time TPU compiler here: {exc!r}")
+    assert [d.device_kind for d in topo.devices] == ["TPU v5 lite"] * 4
+    return topo.devices
+
+
+@pytest.fixture(autouse=True)
+def compile_for_tpu(monkeypatch):
+    monkeypatch.setattr(backend, "on_cpu", lambda: False)
+
+
+def _abstract(tree, sharding):
+    """ShapeDtypeStructs of ``tree`` placed by ``sharding`` (one sharding,
+    or a matching tree of them)."""
+    if not isinstance(sharding, (dict, list, tuple)):
+        sharding = jax.tree.map(lambda _: sharding, tree)
+    return jax.tree.map(
+        lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+        tree, sharding)
+
+
+def _kernel_calls(compiled) -> int:
+    return compiled.as_text().count("tpu_custom_call")
+
+
+def _qkv(dev, s, d, dtype=jnp.bfloat16, heads=4, kv_heads=None):
+    one = SingleDeviceSharding(dev)
+    q = jax.ShapeDtypeStruct((1, heads, s, d), dtype, sharding=one)
+    kv = jax.ShapeDtypeStruct((1, kv_heads or heads, s, d), dtype,
+                              sharding=one)
+    return q, kv, kv
+
+
+def _flash_grad(q, k, v):
+    return jax.grad(lambda *a: flash_attention(*a).astype(
+        jnp.float32).sum(), argnums=(0, 1, 2))(q, k, v)
+
+
+# ---------------------------------------------------------------- kernels
+@pytest.mark.parametrize("d", [64, 128])
+@pytest.mark.parametrize("s", [1024, 4096])
+def test_flash_kernels_compile(tpu, s, d):
+    """Forward, both backward kernels and the grouped forward compile at
+    the sizes the smoke's numerics phase then runs."""
+    assert _kernel_calls(
+        jax.jit(flash_attention).lower(*_qkv(tpu[0], s, d)).compile()) == 1
+    assert _kernel_calls(
+        jax.jit(_flash_grad).lower(*_qkv(tpu[0], s, d)).compile()) == 3
+    assert _kernel_calls(jax.jit(flash_attention_grouped).lower(
+        *_qkv(tpu[0], s, d, heads=8, kv_heads=2)).compile()) == 1
+
+
+@pytest.mark.parametrize("s", [64, 192])
+def test_flash_guard_keeps_unaligned_blocks_off_the_compiler(tpu, s):
+    """A block that is not a multiple of 128 lanes is refused by Mosaic
+    (the log-sum-exp store); the guard sends it down the dense path."""
+    block = fa._auto_block(s)
+    assert block % 128 and not fa.use_flash(s, s, 64, jnp.bfloat16)
+    for fn, args in ((flash_attention, _qkv(tpu[0], s, 64)),
+                     (_flash_grad, _qkv(tpu[0], s, 64)),
+                     (flash_attention_grouped,
+                      _qkv(tpu[0], s, 64, heads=8, kv_heads=2))):
+        assert _kernel_calls(jax.jit(fn).lower(*args).compile()) == 0
+    with pytest.raises(Exception, match="multiple of 128"):
+        jax.jit(lambda q, k, v: fa._flash_core(
+            q, k, v, True, 0.125, block, block, False)).lower(
+            *_qkv(tpu[0], s, 64)).compile()
+
+
+def test_flash_vmem_limit_is_a_limit(tpu):
+    """The kernels keep whole per-head arrays in VMEM. The guard's
+    3 MiB per-head array (head_dim 128, bf16: S = 12288) compiles,
+    forward and backward; S = 16384 is refused by the compiler, and the
+    guard never lets it get there."""
+    assert fa.use_flash(12288, 12288, 128, jnp.bfloat16)
+    args = _qkv(tpu[0], 12288, 128)
+    assert _kernel_calls(jax.jit(_flash_grad).lower(*args).compile()) == 3
+    assert not fa.use_flash(16384, 16384, 128, jnp.bfloat16)
+    assert not fa.use_flash(8192, 8192, 128, jnp.float32)
+    with pytest.raises(Exception, match="vmem"):
+        jax.jit(lambda q, k, v: fa._flash_core(
+            q, k, v, True, 0.09, 512, 512, False)).lower(
+            *_qkv(tpu[0], 16384, 128)).compile()
+    # Past the limit the public op lowers to the dense form, no kernel.
+    lowered = jax.jit(flash_attention).lower(*_qkv(tpu[0], 16384, 128))
+    assert "tpu_custom_call" not in lowered.as_text()
+
+
+def test_rms_norm_fused_compiles(tpu):
+    one = SingleDeviceSharding(tpu[0])
+    x = jax.ShapeDtypeStruct((8, 1024, 2048), jnp.bfloat16, sharding=one)
+    w = jax.ShapeDtypeStruct((2048,), jnp.float32, sharding=one)
+    assert _kernel_calls(jax.jit(rms_norm_fused).lower(x, w).compile()) == 1
+
+
+# ------------------------------------------------- serving at smoke widths
+def _serve_programs(sharding_of, tp_mesh=None, num_blocks=None):
+    """Abstract (params, cache) of the smoke's serving configuration and
+    jitted prefill/decode as the engine builds them."""
+    cfg = TransformerConfig(dtype=jnp.bfloat16, **chip_smoke.SERVE_MODEL)
+    eng = dict(chip_smoke.SERVE_ENGINE)
+    if num_blocks:
+        eng["num_blocks"] = num_blocks
+    rules = ShardingRules() if tp_mesh is not None else None
+    params = jax.eval_shape(
+        functools.partial(init_params, cfg), jax.random.PRNGKey(0))
+    cache = jax.eval_shape(functools.partial(
+        init_kv_cache, cfg, eng["num_blocks"], eng["block_size"]))
+    if tp_mesh is None:
+        p_sh = c_sh = sharding_of
+    else:
+        p_sh = jax.tree.map(lambda s: NamedSharding(tp_mesh, s),
+                            param_specs(cfg, rules),
+                            is_leaf=lambda s: isinstance(s, P))
+        c_sh = {k: NamedSharding(tp_mesh, s)
+                for k, s in kv_cache_specs(rules).items()}
+    kw = dict(mesh=tp_mesh, rules=rules)
+    return (cfg, _abstract(params, p_sh), _abstract(cache, c_sh),
+            jax.jit(functools.partial(prefill_chunk, cfg, **kw),
+                    donate_argnums=(1,)),
+            jax.jit(functools.partial(decode_step, cfg, **kw),
+                    donate_argnums=(1,)))
+
+
+def _ints(shape, sharding):
+    return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=sharding)
+
+
+def _fits(compiled) -> float:
+    m = compiled.memory_analysis()
+    used = (m.argument_size_in_bytes + m.temp_size_in_bytes
+            + m.output_size_in_bytes - m.alias_size_in_bytes)
+    assert used < HBM_BYTES, f"{used / 2 ** 30:.2f} GiB does not fit a v5e"
+    return used
+
+
+def test_serving_programs_fit_one_chip(tpu):
+    """decode_step at the smoke's full batch and prefill_chunk at its
+    full token budget, with its pool, compile and fit 16 GB. The next
+    pool size up does not: the scan-carried pool is copied, not updated
+    in place (a finding for a later perf issue, a limit for now)."""
+    one = SingleDeviceSharding(tpu[0])
+    cfg, params, cache, prefill, decode = _serve_programs(one)
+    eng = chip_smoke.SERVE_ENGINE
+    b = eng["max_num_seqs"]
+    m = 4096 // eng["block_size"]
+    _fits(decode.lower(params, cache, _ints((b,), one), _ints((b,), one),
+                       _ints((b, m), one)).compile())
+    c = eng["prefill_token_budget"]
+    _fits(prefill.lower(params, cache, _ints((1, c), one), _ints((1,), one),
+                        _ints((1,), one), _ints((1, m), one)).compile())
+    _cfg, params, cache, _prefill, decode = _serve_programs(
+        one, num_blocks=2 * eng["num_blocks"])
+    with pytest.raises(Exception, match="memory space hbm"):
+        decode.lower(params, cache, _ints((b,), one), _ints((b,), one),
+                     _ints((b, m), one)).compile()
+
+
+def test_tp4_decode_compiles_on_the_2x2_host(tpu):
+    mesh = Mesh(np.asarray(tpu).reshape(1, 1, 1, 4, 1, 1),
+                ("dp", "fsdp", "pp", "tp", "sp", "ep"))
+    rep = NamedSharding(mesh, P())
+    cfg, params, cache, _prefill, decode = _serve_programs(None, mesh)
+    eng = chip_smoke.SERVE_ENGINE
+    b, m = eng["max_num_seqs"], 4096 // eng["block_size"]
+    used = _fits(decode.lower(params, cache, _ints((b,), rep),
+                              _ints((b,), rep), _ints((b, m), rep)
+                              ).compile())
+    # Per chip: a quarter of the weights and of the pool, not all of it.
+    assert used < 4 * chip_smoke._param_count(chip_smoke.SERVE_MODEL)
+
+
+# ------------------------------------------------------------- train step
+def test_train_step_compiles_with_the_flash_kernels(tpu):
+    """The 201M train step of the smoke at 8 x 1024 holds the flash
+    forward, dq and dk/dv kernels — the dense path does not stand in."""
+    import optax
+
+    cfg = TransformerConfig(dtype=jnp.bfloat16, **chip_smoke.TRAIN_MODEL)
+    lowered = chip_smoke._lower_train_step(
+        cfg, optax.adamw(3e-4), sharding=SingleDeviceSharding(tpu[0]))
+    assert lowered.as_text().count("tpu_custom_call") == 3
+    compiled = lowered.compile()
+    assert _kernel_calls(compiled) == 3
+    _fits(compiled)
