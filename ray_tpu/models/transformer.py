@@ -141,12 +141,14 @@ def param_specs(cfg: TransformerConfig,
     }
 
 
+@jax.named_scope("norm")
 def rms_norm(x, w, eps=1e-6):
     x32 = x.astype(jnp.float32)
     var = jnp.mean(x32 * x32, axis=-1, keepdims=True)
     return (x32 * lax.rsqrt(var + eps)).astype(x.dtype) * w.astype(x.dtype)
 
 
+@jax.named_scope("rope")
 def rope(x, positions, theta):
     # x: [B, S, H, Dh]; rotate pairs (even, odd halves).
     Dh = x.shape[-1]
@@ -161,6 +163,7 @@ def rope(x, positions, theta):
                             x1 * sin + x2 * cos], axis=-1)
 
 
+@jax.named_scope("seg.attn_core")
 def _attention_dense(q, k, v, causal=True, grad=True):
     """q [B,S,Hq,Dh], k/v [B,S,Hkv,Dh] -> [B,S,Hq,Dh].
 
@@ -212,19 +215,37 @@ def _attention_dense(q, k, v, causal=True, grad=True):
     return o.reshape(B, S, Hq, Dh)
 
 
-def _project_qkv(cfg, lp, h, positions):
-    """q/k/v projection + rope, shared by the training layer body and
-    the cached prefill/decode paths. h [B, S, D] -> q [B,S,Hq,Dh],
-    k/v [B,S,Hkv,Dh] (k/v at n_kv_heads width)."""
+@jax.named_scope("seg.embed")
+def _embed(cfg, params, tokens):
+    return params["embed"].astype(cfg.dtype)[tokens]
+
+
+@jax.named_scope("seg.attn_proj")
+def _project_qkv(cfg, lp, x, positions):
+    """Attention norm, q/k/v projection + rope, shared by the training
+    layer body and the cached prefill/decode paths. x [B, S, D] ->
+    q [B,S,Hq,Dh], k/v [B,S,Hkv,Dh] (k/v at n_kv_heads width)."""
     dt = cfg.dtype
-    B, S, _ = h.shape
+    B, S, _ = x.shape
     Hd = cfg.head_dim
+    h = rms_norm(x, lp["attn_norm"])
     q = (h @ lp["wq"].astype(dt)).reshape(B, S, -1, Hd)
     k = (h @ lp["wk"].astype(dt)).reshape(B, S, -1, Hd)
     v = (h @ lp["wv"].astype(dt)).reshape(B, S, -1, Hd)
     q = rope(q, positions, cfg.rope_theta)
     k = rope(k, positions, cfg.rope_theta)
     return q, k, v
+
+
+@jax.named_scope("seg.attn_proj")
+def _attn_out(cfg, lp, x, o, tp_axis=None):
+    """Output projection of the attention heads o [B, S, Hq, Dh] and the
+    residual add onto x [B, S, D]."""
+    B, S, _ = x.shape
+    o = o.reshape(B, S, -1) @ lp["wo"].astype(cfg.dtype)
+    if tp_axis is not None:
+        o = lax.psum(o, tp_axis)  # row-parallel output proj
+    return x + o
 
 
 def _layer_fn(cfg: TransformerConfig, lp: Dict[str, jax.Array], x: jax.Array,
@@ -235,29 +256,27 @@ def _layer_fn(cfg: TransformerConfig, lp: Dict[str, jax.Array], x: jax.Array,
     """One transformer block. In manual mode the weights arriving here are
     the local TP shard (wide axis pre-sliced) and attention/MoE take the
     collective axes to use; in GSPMD mode all axes are None."""
-    dt = cfg.dtype
-    B, S, _D = x.shape
-
-    # ---- attention ----------------------------------------------------------
-    h = rms_norm(x, lp["attn_norm"])
-    q, k, v = _project_qkv(cfg, lp, h, positions)
+    q, k, v = _project_qkv(cfg, lp, x, positions)
     if sp_axis is not None:
-        Hq, Hkv = q.shape[2], k.shape[2]
-        if Hq != Hkv:
-            k = jnp.repeat(k, Hq // Hkv, axis=2)
-            v = jnp.repeat(v, Hq // Hkv, axis=2)
-        o = ring_attention(
-            q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
-            v.transpose(0, 2, 1, 3), axis_name=sp_axis, causal=True,
-        ).transpose(0, 2, 1, 3)
+        with jax.named_scope("seg.attn_core"):
+            Hq, Hkv = q.shape[2], k.shape[2]
+            if Hq != Hkv:
+                k = jnp.repeat(k, Hq // Hkv, axis=2)
+                v = jnp.repeat(v, Hq // Hkv, axis=2)
+            o = ring_attention(
+                q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
+                v.transpose(0, 2, 1, 3), axis_name=sp_axis, causal=True,
+            ).transpose(0, 2, 1, 3)
     else:
         o = _attention_dense(q, k, v)
-    o = o.reshape(B, S, -1) @ lp["wo"].astype(dt)
-    if tp_axis is not None:
-        o = lax.psum(o, tp_axis)  # row-parallel output proj
-    x = x + o
+    x = _attn_out(cfg, lp, x, o, tp_axis)
+    return _mlp_residual(cfg, lp, x, layer_idx,
+                         tp_axis=tp_axis, ep_axis=ep_axis)
 
-    # ---- mlp ---------------------------------------------------------------
+
+@jax.named_scope("seg.mlp")
+def _mlp_residual(cfg, lp, x, layer_idx, tp_axis=None, ep_axis=None):
+    """MLP norm, ``_mlp_block`` and the residual add, x [B, S, D]."""
     h = rms_norm(x, lp["mlp_norm"])
     return x + _mlp_block(cfg, lp, h, layer_idx,
                           tp_axis=tp_axis, ep_axis=ep_axis)
@@ -333,8 +352,7 @@ def forward(cfg: TransformerConfig, params: Dict[str, Any],
             x, NamedSharding(mesh, r.spec(*logical)))
 
     B, S = tokens.shape
-    dt = cfg.dtype
-    x = params["embed"].astype(dt)[tokens]
+    x = _embed(cfg, params, tokens)
     x = constrain(x, "batch", "sequence", "embed")
     positions = jnp.broadcast_to(jnp.arange(S), (B, S))
 
@@ -351,17 +369,32 @@ def forward(cfg: TransformerConfig, params: Dict[str, Any],
 
     idxs = jnp.arange(cfg.n_layers)
     x, _ = lax.scan(body, x, (params["layers"], idxs))
-    x = rms_norm(x, params["final_norm"])
-    logits = x @ params["lm_head"].astype(dt)
-    return constrain(logits.astype(jnp.float32), "batch", "sequence", "vocab")
+    return constrain(_final_logits(cfg, params, x),
+                     "batch", "sequence", "vocab")
+
+
+@jax.named_scope("seg.head_loss")
+def _lm_head(cfg, params, x):
+    """f32 logits of final-normed hidden states."""
+    return (x @ params["lm_head"].astype(cfg.dtype)).astype(jnp.float32)
+
+
+@jax.named_scope("seg.head_loss")
+def _final_logits(cfg, params, x):
+    return _lm_head(cfg, params, rms_norm(x, params["final_norm"]))
+
+
+@jax.named_scope("seg.head_loss")
+def _next_token_nll(logits, targets):
+    logp = jax.nn.log_softmax(logits, axis=-1)
+    nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
+    return jnp.mean(nll)
 
 
 def loss_fn(cfg: TransformerConfig, params, tokens, targets,
             mesh=None, rules=None) -> jax.Array:
-    logits = forward(cfg, params, tokens, mesh, rules)
-    logp = jax.nn.log_softmax(logits, axis=-1)
-    nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
-    return jnp.mean(nll)
+    return _next_token_nll(forward(cfg, params, tokens, mesh, rules),
+                           targets)
 
 
 # ---------------------------------------------------------------------------
@@ -454,9 +487,8 @@ def make_spmd_train_step(cfg: TransformerConfig, mesh: Mesh, params,
     def local_loss(params, tokens, targets):
         """Per-shard loss: tokens [B_local, S_local] (dp×sp sharded)."""
         B, S = tokens.shape
-        dt = cfg.dtype
         stage = lax.axis_index("pp")
-        x = params["embed"].astype(dt)[tokens]
+        x = _embed(cfg, params, tokens)
         s_idx = lax.axis_index("sp") if sp_n > 1 else 0
         positions = jnp.broadcast_to(
             jnp.arange(S) + s_idx * S, (B, S))
@@ -476,11 +508,7 @@ def make_spmd_train_step(cfg: TransformerConfig, mesh: Mesh, params,
             x, _ = stage_fn(params["layers"], (x, positions),
                             jnp.zeros((), jnp.int32))
 
-        x = rms_norm(x, params["final_norm"])
-        logits = (x @ params["lm_head"].astype(dt)).astype(jnp.float32)
-        logp = jax.nn.log_softmax(logits, axis=-1)
-        nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
-        return jnp.mean(nll)
+        return _next_token_nll(_final_logits(cfg, params, x), targets)
 
     from ray_tpu.parallel.mesh import AXES
 
@@ -605,9 +633,8 @@ def prefill_with_cache(cfg: TransformerConfig, params, cache,
     the result is bit-identical to an unpadded per-sequence run.
     """
     B, S = tokens.shape
-    dt = cfg.dtype
     block_size = cache["k"].shape[2]
-    x = params["embed"].astype(dt)[tokens]
+    x = _embed(cfg, params, tokens)
     positions = jnp.broadcast_to(jnp.arange(S), (B, S))
     # Physical slot of every position: (block_tables[b, s//bs], s % bs).
     blk = jnp.take_along_axis(block_tables, positions // block_size,
@@ -617,24 +644,23 @@ def prefill_with_cache(cfg: TransformerConfig, params, cache,
     def body(carry, lp_idx):
         x, ck, cv = carry
         lp, idx = lp_idx
-        h = rms_norm(x, lp["attn_norm"])
-        q, k, v = _project_qkv(cfg, lp, h, positions)
-        ck = ck.at[idx, blk, off].set(k)
-        cv = cv.at[idx, blk, off].set(v)
-        o = _attention_dense(q, k, v, causal=True, grad=False)
-        x = x + o.reshape(B, S, -1) @ lp["wo"].astype(dt)
-        h = rms_norm(x, lp["mlp_norm"])
-        x = x + _mlp_block(cfg, lp, h, idx)
+        q, k, v = _project_qkv(cfg, lp, x, positions)
+        with jax.named_scope("seg.attn_core"):
+            ck = ck.at[idx, blk, off].set(k)
+            cv = cv.at[idx, blk, off].set(v)
+            o = _attention_dense(q, k, v, causal=True, grad=False)
+        x = _attn_out(cfg, lp, x, o)
+        x = _mlp_residual(cfg, lp, x, idx)
         return (x, ck, cv), None
 
     idxs = jnp.arange(cfg.n_layers)
     (x, ck, cv), _ = lax.scan(
         body, (x, cache["k"], cache["v"]), (params["layers"], idxs))
-    x = rms_norm(x, params["final_norm"])
-    last = jnp.take_along_axis(
-        x, (prompt_lens - 1)[:, None, None].clip(0), axis=1)[:, 0]
-    logits = (last @ params["lm_head"].astype(dt)).astype(jnp.float32)
-    return logits, {"k": ck, "v": cv}
+    with jax.named_scope("seg.head_loss"):
+        x = rms_norm(x, params["final_norm"])
+        last = jnp.take_along_axis(
+            x, (prompt_lens - 1)[:, None, None].clip(0), axis=1)[:, 0]
+    return _lm_head(cfg, params, last), {"k": ck, "v": cv}
 
 
 def prefill_chunk(cfg: TransformerConfig, params, cache,
@@ -668,11 +694,10 @@ def prefill_chunk(cfg: TransformerConfig, params, cache,
     """
     x, ck, cv = _chunk_scan(cfg, params, cache, tokens, start_pos,
                             block_tables, mesh, rules)
-    last = jnp.take_along_axis(
-        x, (chunk_lens - 1)[:, None, None].clip(0), axis=1)[:, 0]
-    logits = (last @ params["lm_head"].astype(cfg.dtype)
-              ).astype(jnp.float32)
-    return logits, {"k": ck, "v": cv}
+    with jax.named_scope("seg.head_loss"):
+        last = jnp.take_along_axis(
+            x, (chunk_lens - 1)[:, None, None].clip(0), axis=1)[:, 0]
+    return _lm_head(cfg, params, last), {"k": ck, "v": cv}
 
 
 def _chunk_scan(cfg: TransformerConfig, params, cache, tokens, start_pos,
@@ -681,11 +706,10 @@ def _chunk_scan(cfg: TransformerConfig, params, cache, tokens, start_pos,
     run the chunk through every layer against the paged cache, writing
     each position's K/V before it is attended, and return the final-
     normed hidden states ``[B, C, D]`` plus the updated K/V pools."""
-    B, C = tokens.shape
-    dt = cfg.dtype
+    C = tokens.shape[1]
     block_size = cache["k"].shape[2]
     M = block_tables.shape[1]
-    x = params["embed"].astype(dt)[tokens]
+    x = _embed(cfg, params, tokens)
     positions = start_pos[:, None] + jnp.arange(C)[None, :]    # [B, C]
     blk = jnp.take_along_axis(
         block_tables, jnp.minimum(positions // block_size, M - 1),
@@ -697,29 +721,29 @@ def _chunk_scan(cfg: TransformerConfig, params, cache, tokens, start_pos,
     def body(carry, lp_idx):
         x, ck, cv = carry
         lp, idx = lp_idx
-        h = rms_norm(x, lp["attn_norm"])
-        q, k, v = _project_qkv(cfg, lp, h, positions)
-        q = _infer_constrain(q, mesh, rules, None, None, "heads",
-                             "head_dim")
-        k = _infer_constrain(k, mesh, rules, None, None, "kv_heads",
-                             "head_dim")
-        v = _infer_constrain(v, mesh, rules, None, None, "kv_heads",
-                             "head_dim")
-        # Write the chunk's K/V, then attend over [0, position] per
-        # token — each new slot is part of its own context.
-        ck = ck.at[idx, blk, off].set(k)
-        cv = cv.at[idx, blk, off].set(v)
-        o = paged_attention_prefill(q, ck[idx], cv[idx], block_tables,
-                                    positions, mesh=mesh, rules=rules)
-        x = x + o.reshape(B, C, -1) @ lp["wo"].astype(dt)
-        h = rms_norm(x, lp["mlp_norm"])
-        x = x + _mlp_block(cfg, lp, h, idx)
+        q, k, v = _project_qkv(cfg, lp, x, positions)
+        with jax.named_scope("seg.attn_core"):
+            q = _infer_constrain(q, mesh, rules, None, None, "heads",
+                                 "head_dim")
+            k = _infer_constrain(k, mesh, rules, None, None, "kv_heads",
+                                 "head_dim")
+            v = _infer_constrain(v, mesh, rules, None, None, "kv_heads",
+                                 "head_dim")
+            # Write the chunk's K/V, then attend over [0, position] per
+            # token — each new slot is part of its own context.
+            ck = ck.at[idx, blk, off].set(k)
+            cv = cv.at[idx, blk, off].set(v)
+            o = paged_attention_prefill(q, ck[idx], cv[idx], block_tables,
+                                        positions, mesh=mesh, rules=rules)
+        x = _attn_out(cfg, lp, x, o)
+        x = _mlp_residual(cfg, lp, x, idx)
         return (x, ck, cv), None
 
     idxs = jnp.arange(cfg.n_layers)
     (x, ck, cv), _ = lax.scan(
         body, (x, cache["k"], cache["v"]), (params["layers"], idxs))
-    return rms_norm(x, params["final_norm"]), ck, cv
+    with jax.named_scope("seg.head_loss"):
+        return rms_norm(x, params["final_norm"]), ck, cv
 
 
 def verify_step(cfg: TransformerConfig, params, cache,
@@ -748,9 +772,7 @@ def verify_step(cfg: TransformerConfig, params, cache,
     """
     x, ck, cv = _chunk_scan(cfg, params, cache, tokens, start_pos,
                             block_tables, mesh, rules)
-    logits = (x @ params["lm_head"].astype(cfg.dtype)
-              ).astype(jnp.float32)
-    return logits, {"k": ck, "v": cv}
+    return _lm_head(cfg, params, x), {"k": ck, "v": cv}
 
 
 def decode_step(cfg: TransformerConfig, params, cache,
@@ -768,10 +790,8 @@ def decode_step(cfg: TransformerConfig, params, cache,
 
     Returns (logits [B, vocab] f32, new cache).
     """
-    B = tokens.shape[0]
-    dt = cfg.dtype
     block_size = cache["k"].shape[2]
-    x = params["embed"].astype(dt)[tokens][:, None]  # [B, 1, D]
+    x = _embed(cfg, params, tokens[:, None])         # [B, 1, D]
     pos2 = positions[:, None]                        # [B, 1]
     context_lens = positions + 1
     blk = jnp.take_along_axis(block_tables, pos2 // block_size,
@@ -783,29 +803,26 @@ def decode_step(cfg: TransformerConfig, params, cache,
     def body(carry, lp_idx):
         x, ck, cv = carry
         lp, idx = lp_idx
-        h = rms_norm(x, lp["attn_norm"])
-        q, k, v = _project_qkv(cfg, lp, h, pos2)
-        q = _infer_constrain(q, mesh, rules, None, None, "heads",
-                             "head_dim")
-        k = _infer_constrain(k, mesh, rules, None, None, "kv_heads",
-                             "head_dim")
-        v = _infer_constrain(v, mesh, rules, None, None, "kv_heads",
-                             "head_dim")
-        # Write THIS token's k/v, then attend over [0, positions] —
-        # the new slot is part of its own context (self-attention).
-        ck = ck.at[idx, blk, off].set(k[:, 0])
-        cv = cv.at[idx, blk, off].set(v[:, 0])
-        o = paged_attention_decode(
-            q[:, 0], ck[idx], cv[idx], block_tables, context_lens,
-            mesh=mesh, rules=rules)
-        x = x + (o.reshape(B, 1, -1) @ lp["wo"].astype(dt))
-        h = rms_norm(x, lp["mlp_norm"])
-        x = x + _mlp_block(cfg, lp, h, idx)
+        q, k, v = _project_qkv(cfg, lp, x, pos2)
+        with jax.named_scope("seg.attn_core"):
+            q = _infer_constrain(q, mesh, rules, None, None, "heads",
+                                 "head_dim")
+            k = _infer_constrain(k, mesh, rules, None, None, "kv_heads",
+                                 "head_dim")
+            v = _infer_constrain(v, mesh, rules, None, None, "kv_heads",
+                                 "head_dim")
+            # Write THIS token's k/v, then attend over [0, positions] —
+            # the new slot is part of its own context (self-attention).
+            ck = ck.at[idx, blk, off].set(k[:, 0])
+            cv = cv.at[idx, blk, off].set(v[:, 0])
+            o = paged_attention_decode(
+                q[:, 0], ck[idx], cv[idx], block_tables, context_lens,
+                mesh=mesh, rules=rules)
+        x = _attn_out(cfg, lp, x, o)
+        x = _mlp_residual(cfg, lp, x, idx)
         return (x, ck, cv), None
 
     idxs = jnp.arange(cfg.n_layers)
     (x, ck, cv), _ = lax.scan(
         body, (x, cache["k"], cache["v"]), (params["layers"], idxs))
-    x = rms_norm(x[:, 0], params["final_norm"])
-    logits = (x @ params["lm_head"].astype(dt)).astype(jnp.float32)
-    return logits, {"k": ck, "v": cv}
+    return _final_logits(cfg, params, x[:, 0]), {"k": ck, "v": cv}

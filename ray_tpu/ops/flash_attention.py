@@ -328,8 +328,9 @@ def _flash_forward_grouped(q, k, v, causal, scale, block_q, block_k,
     def kv_index(b, i):
         return ((b // Hq) * Hkv + (b % Hq) // group, 0, 0)
 
-    out, _lse = pl.pallas_call(
+    call = pl.pallas_call(
         kernel,
+        name="flash_fwd_grouped",
         grid=(B * Hq, Sq // block_q),
         in_specs=[
             pl.BlockSpec((None, block_q, D), lambda b, i: (b, i, 0)),
@@ -345,7 +346,9 @@ def _flash_forward_grouped(q, k, v, causal, scale, block_q, block_k,
             jax.ShapeDtypeStruct((B * Hq, 8, Sq), jnp.float32),
         ],
         interpret=interpret,
-    )(qr, kr, vr)
+    )
+    with jax.named_scope("flash_fwd_grouped"):
+        out, _lse = call(qr, kr, vr)
     return out.reshape(B, Hq, Sq, D)
 
 
@@ -363,8 +366,9 @@ def _flash_forward(q, k, v, causal, scale, block_q, block_k, interpret):
     kr = k.reshape(B * H, Sk, D)
     vr = v.reshape(B * H, Sk, D)
 
-    out, lse = pl.pallas_call(
+    call = pl.pallas_call(
         kernel,
+        name="flash_fwd",
         grid=(B * H, Sq // block_q),
         in_specs=[
             pl.BlockSpec((None, block_q, D), lambda b, i: (b, i, 0)),
@@ -380,7 +384,9 @@ def _flash_forward(q, k, v, causal, scale, block_q, block_k, interpret):
             jax.ShapeDtypeStruct((B * H, 8, Sq), jnp.float32),
         ],
         interpret=interpret,
-    )(qr, kr, vr)
+    )
+    with jax.named_scope("flash_fwd"):
+        out, lse = call(qr, kr, vr)
     return out.reshape(B, H, Sq, D), lse.reshape(B, H, 8, Sq)
 
 
@@ -422,8 +428,9 @@ def _flash_bwd_rule(causal, scale, block_q, block_k, interpret,
     dq_kernel = functools.partial(
         _attn_bwd_dq_kernel, block_k=block_k, seq_k=Sk, causal=causal,
         scale=scale, block_q=block_q)
-    dq = pl.pallas_call(
+    dq_call = pl.pallas_call(
         dq_kernel,
+        name="flash_bwd_dq",
         grid=(BH, Sq // block_q),
         in_specs=[
             pl.BlockSpec((None, block_q, D), lambda b, i: (b, i, 0)),
@@ -436,13 +443,16 @@ def _flash_bwd_rule(causal, scale, block_q, block_k, interpret,
         out_specs=pl.BlockSpec((None, block_q, D), lambda b, i: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, Sq, D), q.dtype),
         interpret=interpret,
-    )(qr, kr, vr, dor, outr, lser)
+    )
+    with jax.named_scope("flash_bwd_dq"):
+        dq = dq_call(qr, kr, vr, dor, outr, lser)
 
     dkv_kernel = functools.partial(
         _attn_bwd_dkv_kernel, block_q=block_q, seq_q=Sq, causal=causal,
         scale=scale, block_k=block_k)
-    dk, dv = pl.pallas_call(
+    dkv_call = pl.pallas_call(
         dkv_kernel,
+        name="flash_bwd_dkv",
         grid=(BH, Sk // block_k),
         in_specs=[
             pl.BlockSpec((None, block_k, D), lambda b, i: (b, i, 0)),
@@ -461,7 +471,9 @@ def _flash_bwd_rule(causal, scale, block_q, block_k, interpret,
             jax.ShapeDtypeStruct((BH, Sk, D), v.dtype),
         ],
         interpret=interpret,
-    )(kr, vr, qr, dor, outr, lser)
+    )
+    with jax.named_scope("flash_bwd_dkv"):
+        dk, dv = dkv_call(kr, vr, qr, dor, outr, lser)
 
     return (dq.reshape(q.shape).astype(q.dtype),
             dk.reshape(k.shape).astype(k.dtype),

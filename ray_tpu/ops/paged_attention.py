@@ -54,6 +54,7 @@ def _constrain(x, mesh, rules, *logical):
     return constrain_logical(x, mesh, rules, *logical)
 
 
+@jax.named_scope("seg.attn_core")
 def paged_attention_decode(q, k_cache, v_cache, block_tables,
                            context_lens, mesh=None, rules=None):
     """Single-token attention of each sequence against its paged context.
@@ -90,6 +91,7 @@ def paged_attention_decode(q, k_cache, v_cache, block_tables,
     return o.reshape(B, Hq, Dh)
 
 
+@jax.named_scope("seg.attn_core")
 def paged_attention_prefill(q, k_cache, v_cache, block_tables,
                             q_positions, mesh=None, rules=None):
     """Chunked-prefill attention: C query tokens per sequence against

@@ -22,6 +22,20 @@ Division of labor between the two profilers:
   A slow step shows up here when the host is the bottleneck and in
   the xplane trace when the device is.
 
+**What a capture names.** The model names its own device work, so a
+``profile_trace`` of any step, training or serving, shows in the trace
+viewer's operation details (the ``op_name`` of each XLA operation) which
+part of the model the operation is: one of ``SEGMENTS`` (``seg.embed``,
+``seg.attn_proj``, ``seg.attn_core``, ``seg.mlp``, ``seg.head_loss``;
+the outermost one on the path is the operation's segment, ``norm`` and
+``rope`` are finer scopes inside), and on the flash kernels one of
+``KERNELS`` (``flash_fwd``, ``flash_fwd_grouped``, ``flash_bwd_dq``,
+``flash_bwd_dkv``), which is also the kernel instruction's own name
+(``%flash_fwd.6``). ``transpose(jvp(...))`` on the path marks the backward
+pass; an operation with names and no segment is the optimizer's. The
+scopes are written in ``models/transformer.py`` and ``ops/``; they exist
+while a program is traced and cost nothing when it runs.
+
 The two meet in the debug-bundle plane: every ``profile_trace``
 capture registers its logdir with the flight recorder, so a bundle
 (``ray_tpu.debug_dump()``) lists the device-trace artifacts produced
@@ -33,6 +47,17 @@ from __future__ import annotations
 import contextlib
 import os
 from typing import Iterator, Optional
+
+# The names the model gives its device work: ``jax.named_scope``s in
+# ``models/transformer.py`` and ``ops/paged_attention.py``. A reader
+# gives an operation to the OUTERMOST of these on its ``op_name`` path;
+# the ``seg.`` prefix is one no JAX primitive or transform produces.
+SEGMENTS = ("seg.embed", "seg.attn_proj", "seg.attn_core", "seg.mlp",
+            "seg.head_loss")
+# The Pallas kernels of ``ops/flash_attention.py``: each one's ``name=``
+# and the scope around its call.
+KERNELS = ("flash_fwd", "flash_fwd_grouped", "flash_bwd_dq",
+           "flash_bwd_dkv")
 
 
 @contextlib.contextmanager

@@ -1,0 +1,158 @@
+"""The names the model gives its device work, checked on the CPU.
+
+``ray_tpu/util/profiling.py`` holds the vocabulary (``SEGMENTS``,
+``KERNELS``); ``models/transformer.py``, ``ops/paged_attention.py`` and
+``ops/flash_attention.py`` write the ``jax.named_scope``s. XLA keeps a
+scope as the ``op_name`` of every instruction it compiles, which is what a
+profile shows and what ``perfbench/segments.py`` reads. These tests compile
+tiny programs for the CPU and read the names back: a refactor that drops a
+matmul out of every segment, or a scope out of the vocabulary, fails here.
+"""
+
+import functools
+import glob
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import optax
+import pytest
+
+from perfbench import segments
+from ray_tpu.models import (
+    TransformerConfig,
+    decode_step,
+    init_kv_cache,
+    init_params,
+    loss_fn,
+    prefill_chunk,
+)
+from ray_tpu.ops import flash_attention
+from ray_tpu.util import profiling
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+CFG = TransformerConfig(vocab_size=64, d_model=32, n_layers=2, n_heads=4,
+                        n_kv_heads=2, d_ff=64, dtype=jnp.bfloat16)
+
+
+def _params():
+    return jax.eval_shape(functools.partial(init_params, CFG),
+                          jax.random.PRNGKey(0))
+
+
+def _tokens(*shape):
+    return jax.ShapeDtypeStruct(shape, jnp.int32)
+
+
+def _paths(compiled_text):
+    """(op_name path, is a matmul) of every instruction of a compiled
+    program, fused ones included."""
+    return [(path, instr.opcode in segments.MATMUL_OPCODES)
+            for instrs in segments.parse(compiled_text).values()
+            for instr in instrs for path in instr.paths]
+
+
+def _segments_on(path):
+    return set(re.findall(r"(?<![\w.])seg\.\w+", path))
+
+
+@pytest.fixture(scope="module")
+def train_step():
+    """(lowered, compiled text) of a value_and_grad + AdamW step."""
+    opt = optax.adamw(3e-4)
+
+    def step(params, opt_state, tokens, targets):
+        loss, grads = jax.value_and_grad(
+            lambda p: loss_fn(CFG, p, tokens, targets))(params)
+        updates, opt_state = opt.update(grads, opt_state, params)
+        return optax.apply_updates(params, updates), opt_state, loss
+
+    params = _params()
+    lowered = jax.jit(step).lower(params, jax.eval_shape(opt.init, params),
+                                  _tokens(2, 16), _tokens(2, 16))
+    return lowered, lowered.compile().as_text()
+
+
+@pytest.fixture(scope="module")
+def gradient_paths():
+    text = jax.jit(jax.grad(lambda p, t, y: loss_fn(CFG, p, t, y))).lower(
+        _params(), _tokens(2, 16), _tokens(2, 16)).compile().as_text()
+    return _paths(text)
+
+
+@pytest.mark.parametrize("segment", profiling.SEGMENTS)
+def test_gradient_holds_each_segment_forward_and_backward(gradient_paths,
+                                                          segment):
+    mine = [p for p, _ in gradient_paths if segment in _segments_on(p)]
+    assert any("jvp(" in p and "transpose(" not in p for p in mine), segment
+    assert any("transpose(jvp(" in p for p in mine), segment
+
+
+def test_every_matmul_of_the_train_step_lies_under_one_segment(train_step):
+    lowered, text = train_step
+    matmuls = [p for p, is_matmul in _paths(text) if is_matmul]
+    # 4 + 2 + 3 a layer forward, twice that backward, the head's three: the
+    # layer body is lowered once, so the count does not grow with depth.
+    assert len(matmuls) == lowered.as_text().count("stablehlo.dot_general")
+    assert len(matmuls) == 30
+    for path in matmuls:
+        assert len(_segments_on(path)) == 1, path
+    by_segment = {s: sum(s in _segments_on(p) for p in matmuls)
+                  for s in profiling.SEGMENTS}
+    assert by_segment == {"seg.embed": 0, "seg.attn_proj": 12,
+                          "seg.attn_core": 6, "seg.mlp": 9,
+                          "seg.head_loss": 3}
+
+
+def test_the_optimizer_lies_under_no_segment(train_step):
+    _lowered, text = train_step
+    # AdamW's denominators are the step's only square roots (the norms
+    # take rsqrt): optax names no scope, so the update is what is left.
+    roots = [p for p, _ in _paths(text) if p.endswith("/sqrt")]
+    assert roots and not any(_segments_on(p) for p in roots)
+
+
+def test_the_scopes_found_are_the_vocabulary(train_step):
+    _lowered, text = train_step
+    found = set().union(*(_segments_on(p) for p, _ in _paths(text)))
+    assert found == set(profiling.SEGMENTS)
+    # and in the source: every scope written is in the vocabulary or is
+    # one of the two finer scopes inside a segment
+    written = set()
+    for path in glob.glob(os.path.join(ROOT, "ray_tpu", "models", "*.py")) \
+            + glob.glob(os.path.join(ROOT, "ray_tpu", "ops", "*.py")):
+        with open(path) as f:
+            written |= set(re.findall(r'named_scope\("([^"]+)"\)', f.read()))
+    assert written == (set(profiling.SEGMENTS) | set(profiling.KERNELS)
+                       | {"norm", "rope"})
+
+
+@pytest.mark.parametrize("program", ["decode_step", "prefill_chunk"])
+def test_serving_programs_hold_the_segments(program):
+    cache = jax.eval_shape(functools.partial(init_kv_cache, CFG, 8, 4))
+    tables = _tokens(2, 4)
+    if program == "decode_step":
+        lowered = jax.jit(functools.partial(decode_step, CFG)).lower(
+            _params(), cache, _tokens(2), _tokens(2), tables)
+    else:
+        lowered = jax.jit(functools.partial(prefill_chunk, CFG)).lower(
+            _params(), cache, _tokens(2, 8), _tokens(2), _tokens(2), tables)
+    paths = _paths(lowered.compile().as_text())
+    found = set().union(*(_segments_on(p) for p, _ in paths))
+    assert found == set(profiling.SEGMENTS)
+    for path, is_matmul in paths:
+        if is_matmul:
+            assert len(_segments_on(path)) == 1, path
+
+
+def test_flash_kernels_carry_their_names():
+    """Interpreted here, so the kernels are no custom calls; the names are
+    on whatever the calls lower to (tests/test_tpu_aot.py has the v5e's)."""
+    q = jax.ShapeDtypeStruct((1, 2, 64, 16), jnp.float32)
+    grad = jax.grad(lambda *a: flash_attention(
+        *a, block_q=32, block_k=32, interpret=True).sum(), argnums=(0, 1, 2))
+    text = jax.jit(grad).lower(q, q, q).as_text(debug_info=True)
+    for kernel in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+        assert kernel in profiling.KERNELS
+        assert re.search(r'"[^"]*\b' + kernel + r'\b[^"]*"', text), kernel

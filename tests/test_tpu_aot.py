@@ -233,3 +233,30 @@ def test_train_step_compiles_with_the_flash_kernels(tpu):
     compiled = lowered.compile()
     assert _kernel_calls(compiled) == 3
     _fits(compiled)
+
+
+def test_train_step_names_its_kernels_and_its_fusions(tpu):
+    """What a profile of the step shows, read from the v5e-compiled text
+    as ``perfbench/segments.py`` reads it: the three flash kernels under
+    three names of the vocabulary, and nine in ten of the fusions under
+    one model segment or, with names and no segment, the update."""
+    import optax
+
+    from perfbench import segments
+    from ray_tpu.util import profiling
+
+    cfg = TransformerConfig(dtype=jnp.bfloat16, **chip_smoke.TRAIN_MODEL)
+    text = chip_smoke._lower_train_step(
+        cfg, optax.adamw(3e-4),
+        sharding=SingleDeviceSharding(tpu[0])).compile().as_text()
+    table = segments.attribute(text, profiling.SEGMENTS, profiling.KERNELS)
+    kernels = sorted(r["kernel"] for r in table.values() if r["kernel"])
+    assert kernels == ["flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"]
+    assert all(r["segment"] == "seg.attn_core" for r in table.values()
+               if r["kernel"])
+    fusions = [r["segment"] for name, r in table.items() if "fusion" in name]
+    named = [s for s in fusions if s != segments.UNATTRIBUTED]
+    assert len(fusions) > 50 and len(named) >= 0.9 * len(fusions), (
+        len(named), len(fusions))
+    assert set(profiling.SEGMENTS) <= {r["segment"] for r in table.values()}
+
