@@ -7,9 +7,28 @@ running max/sum accumulators carry the normalization (same recurrence the
 ring_attention layer uses across chips; this kernel is the within-chip
 block loop).
 
-Grid: (batch*heads, num_q_blocks); the k-loop runs inside the kernel via
-fori_loop over VMEM blocks. Shapes outside ``kernel_accepts`` take the
-dense ``jnp`` form; the backend never decides that (``ops/backend.py``).
+Three kernels, each a grid of (batch*heads, blocks) with the loop over
+the other axis inside (``fori_loop`` over slices of whole-head arrays in
+VMEM). What a program holds beside its own blocks:
+
+- ``flash_fwd`` / ``flash_fwd_grouped`` (grid over q-blocks): the head's
+  K and V; in scratch the f32 output accumulator ``[block_q, D]`` and the
+  running max and sum, ``[block_q, 128]`` each. Writes ``out`` and the
+  log-sum-exp, lane-major ``[8, Sq]``.
+- ``flash_bwd_dq`` (grid over q-blocks): the head's K and V; its blocks
+  of q, do, o and lse; in scratch the f32 dq accumulator.
+- ``flash_bwd_dkv`` (grid over k-blocks): the head's q, do, o and lse;
+  in scratch the f32 dk and dv accumulators. Its score tiles are
+  k-major, ``[block_k, block_q]``, so no tile is transposed.
+
+The accumulators are scratch, updated in place, and not values carried
+round the loop: the compiler copied every carried value at the head and
+the tail of each iteration with the MXU idle (its schedule for a v5e,
+read in PERF.md, PR 29).
+
+A causal grid skips the tiles that hold no unmasked position
+(``tile_counts``). Shapes outside ``kernel_accepts`` take the dense
+``jnp`` form; the backend never decides that (``ops/backend.py``).
 """
 
 from __future__ import annotations
@@ -30,10 +49,11 @@ NEG_INF = -1e30
 # v5e topology (tests/test_tpu_aot.py holds both sides of each limit):
 #  - the log-sum-exp store ``lse_ref[:, dslice(qi * block_q, block_q)]``
 #    needs a lane-aligned offset, so blocks are multiples of 128;
-#  - every program keeps whole per-head arrays resident in VMEM (K and V;
-#    q, dout and out in the dk/dv kernel) under a 16 MiB scoped limit.
-#    A per-head array (S x D x itemsize) of 3 MiB compiles in the forward
-#    and both backward kernels; 3.5 MiB is refused.
+#  - every program keeps whole per-head arrays resident in VMEM (K and V
+#    in the forward and dq kernels; q, dout and out in the dk/dv kernel)
+#    under a 16 MiB scoped limit. A per-head array (S x D x itemsize) of
+#    3 MiB compiles in the forward and both backward kernels; 3.5 MiB is
+#    refused.
 _LANE = 128
 _VMEM_HEAD_ARRAY_BYTES = 3 << 20
 
@@ -48,57 +68,138 @@ def _fallback(q, k, v, causal, scale):
     return jnp.einsum("bhqk,bhkd->bhqd", p, v)
 
 
-def _attn_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k: int,
-                 seq_k: int, causal: bool, scale: float, block_q: int):
+def _row_tiles(qi, block_q: int, block_k: int, num_kb: int, causal: bool):
+    """The k-tiles of q-block ``qi`` as ``(interior, run)``: tiles
+    ``[0, interior)`` lie wholly inside the causal region, ``[interior,
+    run)`` are crossed by the diagonal (a tile needs the mask iff its
+    last key position exceeds its first query position), the rest hold
+    no unmasked position. The kernels loop over ``[0, run)`` and mask
+    every tile: in the v5e schedule the mask's compare and select ride in
+    free ALU slots, and a second, unmasked loop bought nothing (PERF.md,
+    PR 29). ``qi`` is a ``program_id``, an int or an array of ints."""
+    if not causal:
+        return num_kb, num_kb
+    interior = jnp.minimum(num_kb, (qi * block_q + 1) // block_k)
+    run = jnp.minimum(num_kb, ((qi + 1) * block_q + block_k - 1) // block_k)
+    return interior, run
+
+
+def _col_tiles(ki, block_q: int, block_k: int, num_qb: int, causal: bool):
+    """The q-tiles of k-block ``ki`` as ``(start, interior)``, the same
+    rule read down a column: ``[0, start)`` hold no unmasked position,
+    ``[start, interior)`` are crossed by the diagonal, ``[interior,
+    num_qb)`` need no mask. The dk/dv kernel loops over ``[start,
+    num_qb)``."""
+    if not causal:
+        return 0, 0
+    start = jnp.minimum(num_qb, (ki * block_k) // block_q)
+    interior = jnp.minimum(
+        num_qb, ((ki + 1) * block_k - 1 + block_q - 1) // block_q)
+    return start, interior
+
+
+def tile_counts(sq: int, sk: int, block_q: int, block_k: int,
+                causal: bool) -> tuple:
+    """``(interior, diagonal, skipped)`` tiles of one head's grid, from
+    the bounds the kernels loop by: run needing no mask, run needing it,
+    not run. Shapes alone decide it: 28 / 8 / 28 at S 4096 with
+    512 x 512 tiles, so 36 of 64 tiles are run for 32.06 tiles' worth of
+    causal pairs."""
+    num_qb, num_kb = sq // block_q, sk // block_k
+    if not causal:
+        return num_qb * num_kb, 0, 0
+    interior, run = _row_tiles(jnp.arange(num_qb), block_q, block_k,
+                               num_kb, causal)
+    interior, run = int(jnp.sum(interior)), int(jnp.sum(run))
+    return interior, run - interior, num_qb * num_kb - run
+
+
+def _dot_nt(a, b):
+    """``a @ b.T`` as one contraction over the last axes: the MXU's NT
+    form, no transpose of an operand."""
+    return lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                           preferred_element_type=jnp.float32)
+
+
+def _causal_mask(shape, q_axis: int, q_start, k_start):
+    """True where the query position (along ``q_axis`` of a score tile
+    of ``shape``) is at or after the key position. Two 2-D iotas: the
+    compiler relayouts a 1-D ``arange`` broadcast to the tile on every
+    tile (a fifth of the forward's schedule for a v5e, PERF.md, PR 29)."""
+    q_pos = q_start + lax.broadcasted_iota(jnp.int32, shape, q_axis)
+    k_pos = k_start + lax.broadcasted_iota(jnp.int32, shape, 1 - q_axis)
+    return q_pos >= k_pos
+
+
+def _lanes(x, n: int):
+    """``x`` ``[rows, w]``, one value a row repeated along its lanes, at
+    ``n`` lanes: a slice or a concatenation of whole copies, neither of
+    which moves data between lanes."""
+    w = x.shape[1]
+    if n <= w:
+        return x[:, :n]
+    if n % w == 0:
+        return jnp.concatenate([x] * (n // w), axis=1)
+    return jnp.broadcast_to(x[:, :1], (x.shape[0], n))
+
+
+def _attn_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
+                 *, block_k: int, seq_k: int, causal: bool, scale: float,
+                 block_q: int):
+    """One q-block against the head's K and V. The running max ``m``, the
+    running sum ``l`` and the output accumulator live in VMEM scratch and
+    are updated in place; ``m`` and ``l`` are ``[block_q, w]`` with a row's
+    value in every lane (``l``: a row's partial sums, one a lane, added up
+    after the loop), so no tile reduces or relayouts them."""
     from jax.experimental import pallas as pl
 
     q = q_ref[...] * scale                      # [block_q, d]
     qi = pl.program_id(1)
-    m = jnp.full((block_q,), NEG_INF, jnp.float32)
-    l = jnp.zeros((block_q,), jnp.float32)
-    acc = jnp.zeros((block_q, q.shape[-1]), jnp.float32)
-
-    num_kb = seq_k // block_k
+    w = m_ref.shape[1]
+    d = q.shape[-1]
+    m_ref[...] = jnp.full(m_ref.shape, NEG_INF, jnp.float32)
+    l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+    acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
 
     def body(kb, carry):
-        m, l, acc = carry
         k = k_ref[pl.dslice(kb * block_k, block_k), :]     # [block_k, d]
         v = v_ref[pl.dslice(kb * block_k, block_k), :]
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)
+        s = _dot_nt(q, k)
         if causal:
-            q_pos = qi * block_q + jnp.arange(block_q)
-            k_pos = kb * block_k + jnp.arange(block_k)
-            s = jnp.where(q_pos[:, None] >= k_pos[None, :], s, NEG_INF)
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
-        p = jnp.exp(s - m_new[:, None])
-        alpha = jnp.exp(m - m_new)
-        l = l * alpha + jnp.sum(p, axis=-1)
-        acc = acc * alpha[:, None] + jnp.dot(
-            p.astype(v.dtype), v, preferred_element_type=jnp.float32)
-        return m_new, l, acc
+            s = jnp.where(_causal_mask(s.shape, 0, qi * block_q,
+                                       kb * block_k), s, NEG_INF)
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        m_ref[...] = m_new
+        # One lane-width of key columns at a time: each probability
+        # sub-tile goes from exp to the MXU without a round trip.
+        p_sum = pv = 0.0
+        for j in range(block_k // w):
+            p = jnp.exp(s[:, j * w:(j + 1) * w] - m_new)
+            p_sum = p_sum + p
+            pv = pv + jnp.dot(p.astype(v.dtype), v[j * w:(j + 1) * w, :],
+                              preferred_element_type=jnp.float32)
+        l_ref[...] = l_ref[...] * alpha + p_sum
+        acc_ref[...] = acc_ref[...] * _lanes(alpha, d) + pv
+        return carry
 
-    if causal:
-        # Only k-blocks at or before this q-block contribute.
-        last = (qi + 1) * block_q
-        num_needed = (last + block_k - 1) // block_k
-        num_kb_run = jnp.minimum(num_kb, num_needed)
-    else:
-        num_kb_run = num_kb
-    m, l, acc = lax.fori_loop(0, num_kb_run, body, (m, l, acc))
-    l = jnp.maximum(l, 1e-30)
-    o_ref[...] = (acc / l[:, None]).astype(o_ref.dtype)
+    _, run = _row_tiles(qi, block_q, block_k, seq_k // block_k, causal)
+    lax.fori_loop(0, run, body, 0)
+    l = jnp.maximum(jnp.sum(l_ref[...], axis=-1, keepdims=True), 1e-30)
+    o_ref[...] = (acc_ref[...] / l).astype(o_ref.dtype)
     # Log-sum-exp of the (scaled) scores: the backward kernels rebuild
     # each probability tile as exp(s - lse) without a second online pass.
     # Stored sublane-broadcast as [8, Sq] per head — TPU block specs
     # reject 1-D vectors, and 8 sublanes is the cheapest legal layout
     # (8x the payload vs the 128x a lane-broadcast would cost).
     lse_ref[:, pl.dslice(qi * block_q, block_q)] = lax.broadcast_in_dim(
-        m + jnp.log(l), (8, block_q), (1,))
+        (m_ref[...][:, :1] + jnp.log(l))[:, 0], (8, block_q), (1,))
 
 
 def _attn_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
-                        dq_ref, *, block_k: int, seq_k: int, causal: bool,
-                        scale: float, block_q: int):
+                        dq_ref, acc_ref, *, block_k: int, seq_k: int,
+                        causal: bool, scale: float, block_q: int):
     from jax.experimental import pallas as pl
 
     q = q_ref[...]                               # [block_q, d]
@@ -107,79 +208,83 @@ def _attn_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
     lse = lse_ref[...][0]                        # [block_q] f32
     delta = jnp.sum(do.astype(jnp.float32) * o_ref[...].astype(jnp.float32),
                     axis=-1)                     # [block_q] f32
-    dq = jnp.zeros((block_q, q.shape[-1]), jnp.float32)
+    acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
 
-    num_kb = seq_k // block_k
-
-    def body(kb, dq):
+    def body(kb, carry):
         k = k_ref[pl.dslice(kb * block_k, block_k), :]
         v = v_ref[pl.dslice(kb * block_k, block_k), :]
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
+        dp = _dot_nt(do, v)
+        s = _dot_nt(q, k) * scale
         if causal:
-            q_pos = qi * block_q + jnp.arange(block_q)
-            k_pos = kb * block_k + jnp.arange(block_k)
-            s = jnp.where(q_pos[:, None] >= k_pos[None, :], s, NEG_INF)
+            s = jnp.where(_causal_mask(s.shape, 0, qi * block_q,
+                                       kb * block_k), s, NEG_INF)
         p = jnp.exp(s - lse[:, None])            # masked lanes -> 0
-        dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
         ds = p * (dp - delta[:, None])
-        return dq + jnp.dot(ds.astype(q.dtype), k,
-                            preferred_element_type=jnp.float32)
+        acc_ref[...] += jnp.dot(ds.astype(q.dtype), k,
+                                preferred_element_type=jnp.float32)
+        return carry
 
-    if causal:
-        last = (qi + 1) * block_q
-        num_needed = (last + block_k - 1) // block_k
-        num_kb_run = jnp.minimum(num_kb, num_needed)
-    else:
-        num_kb_run = num_kb
-    dq = lax.fori_loop(0, num_kb_run, body, dq)
-    dq_ref[...] = (dq * scale).astype(dq_ref.dtype)
+    _, run = _row_tiles(qi, block_q, block_k, seq_k // block_k, causal)
+    lax.fori_loop(0, run, body, 0)
+    dq_ref[...] = (acc_ref[...] * scale).astype(dq_ref.dtype)
 
 
 def _attn_bwd_dkv_kernel(k_ref, v_ref, q_ref, do_ref, o_ref, lse_ref,
-                         dk_ref, dv_ref, *, block_q: int, seq_q: int,
-                         causal: bool, scale: float, block_k: int):
+                         dk_ref, dv_ref, dk_acc, dv_acc, *, block_q: int,
+                         seq_q: int, causal: bool, scale: float,
+                         block_k: int):
+    """Score tiles k-major, ``[block_k, block_q]``: every matmul is NN or
+    NT, so no score-sized tile is transposed, and ``lse`` (stored
+    lane-major) and ``delta`` are rows that broadcast down the sublanes."""
     from jax.experimental import pallas as pl
 
     k = k_ref[...]                               # [block_k, d]
     v = v_ref[...]
     ki = pl.program_id(1)
-    d = k.shape[-1]
-    dk = jnp.zeros((block_k, d), jnp.float32)
-    dv = jnp.zeros((block_k, d), jnp.float32)
-
-    num_qb = seq_q // block_q
+    dk_acc[...] = jnp.zeros(dk_acc.shape, jnp.float32)
+    dv_acc[...] = jnp.zeros(dv_acc.shape, jnp.float32)
 
     def body(qb, carry):
-        dk, dv = carry
-        q = q_ref[pl.dslice(qb * block_q, block_q), :]
-        do = do_ref[pl.dslice(qb * block_q, block_q), :]
-        lse = lse_ref[0, pl.dslice(qb * block_q, block_q)]
+        rows = pl.dslice(qb * block_q, block_q)
+        q = q_ref[rows, :]
+        do = do_ref[rows, :]
+        lse = lse_ref[0:1, rows]                 # [1, block_q]
         delta = jnp.sum(
-            do.astype(jnp.float32)
-            * o_ref[pl.dslice(qb * block_q, block_q), :].astype(jnp.float32),
-            axis=-1)
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
+            do.astype(jnp.float32) * o_ref[rows, :].astype(jnp.float32),
+            axis=-1)[None, :]
+        sT = _dot_nt(k, q) * scale               # [block_k, block_q]
         if causal:
-            q_pos = qb * block_q + jnp.arange(block_q)
-            k_pos = ki * block_k + jnp.arange(block_k)
-            s = jnp.where(q_pos[:, None] >= k_pos[None, :], s, NEG_INF)
-        p = jnp.exp(s - lse[:, None])            # [block_q, block_k]
-        pT = p.astype(do.dtype).T
-        dv = dv + jnp.dot(pT, do, preferred_element_type=jnp.float32)
-        dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
-        ds = p * (dp - delta[:, None])
-        dk = dk + jnp.dot(ds.astype(q.dtype).T, q,
-                          preferred_element_type=jnp.float32)
-        return dk, dv
+            sT = jnp.where(_causal_mask(sT.shape, 1, qb * block_q,
+                                        ki * block_k), sT, NEG_INF)
+        pT = jnp.exp(sT - lse)
+        dv_acc[...] += jnp.dot(pT.astype(do.dtype), do,
+                               preferred_element_type=jnp.float32)
+        dsT = pT * (_dot_nt(v, do) - delta)
+        dk_acc[...] += jnp.dot(dsT.astype(q.dtype), q,
+                               preferred_element_type=jnp.float32)
+        return carry
 
-    if causal:
-        # q-blocks strictly before this k-block are fully masked.
-        qb_start = (ki * block_k) // block_q
-    else:
-        qb_start = 0
-    dk, dv = lax.fori_loop(qb_start, num_qb, body, (dk, dv))
-    dk_ref[...] = (dk * scale).astype(dk_ref.dtype)
-    dv_ref[...] = dv.astype(dv_ref.dtype)
+    num_qb = seq_q // block_q
+    start, _ = _col_tiles(ki, block_q, block_k, num_qb, causal)
+    lax.fori_loop(start, num_qb, body, 0)
+    dk_ref[...] = (dk_acc[...] * scale).astype(dk_ref.dtype)
+    dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
+
+
+def _acc_scratch(block: int, d: int):
+    """An f32 accumulator in VMEM, updated in place by the tile loop."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    return pltpu.VMEM((block, d), jnp.float32)
+
+
+def _fwd_scratch(block_q: int, block_k: int, d: int) -> list:
+    """The forward's accumulator, running max and running sum; the two
+    statistics as wide as a vreg has lanes (narrower only for the
+    interpreter's small blocks)."""
+    w = math.gcd(block_k, _LANE)
+    return [_acc_scratch(block_q, d), _acc_scratch(block_q, w),
+            _acc_scratch(block_q, w)]
 
 
 def _fallback_grouped(q, k, v, causal, scale):
@@ -201,10 +306,9 @@ def _fallback_grouped(q, k, v, causal, scale):
 
 
 def _auto_block(seq: int, cap: int = 512) -> int:
-    """Largest power-of-2 divisor of `seq`, capped. Measured on TPU v5e
-    (seq 1024-4096, head dim 64/128): 512x512 tiles run the forward
-    2.3x and fwd+bwd 1.2-1.3x faster than 128x128 — bigger tiles keep
-    the MXU busy longer per VMEM round trip."""
+    """Largest power-of-2 divisor of `seq`, capped at 512: the largest
+    tile whose f32 scores (1 MiB) the kernels hold with their whole-head
+    arrays. Not measured against smaller tiles on the present harness."""
     b = 1
     while b < cap and seq % (b * 2) == 0:
         b *= 2
@@ -245,10 +349,12 @@ def flash_attention(
     block_k: Optional[int] = None,
     interpret: Optional[bool] = None,
 ) -> jax.Array:
-    """q/k/v: [B, H, S, D] -> [B, H, S, D]. GQA: repeat kv heads first.
+    """q/k/v: [B, H, S, D] -> [B, H, S, D], differentiable. K and V come
+    at the query heads' width (``flash_attention_grouped`` takes them
+    narrower, forward only).
 
-    Block sizes default to an autotuned schedule (see _auto_block); pass
-    explicit block_q/block_k to override."""
+    Block sizes default to ``_auto_block``'s; pass explicit
+    block_q/block_k to override."""
     if scale is None:
         scale = q.shape[-1] ** -0.5
     B, H, Sq, D = q.shape
@@ -331,6 +437,7 @@ def _flash_forward_grouped(q, k, v, causal, scale, block_q, block_k,
     call = pl.pallas_call(
         kernel,
         name="flash_fwd_grouped",
+        scratch_shapes=_fwd_scratch(block_q, block_k, D),
         grid=(B * Hq, Sq // block_q),
         in_specs=[
             pl.BlockSpec((None, block_q, D), lambda b, i: (b, i, 0)),
@@ -369,6 +476,7 @@ def _flash_forward(q, k, v, causal, scale, block_q, block_k, interpret):
     call = pl.pallas_call(
         kernel,
         name="flash_fwd",
+        scratch_shapes=_fwd_scratch(block_q, block_k, D),
         grid=(B * H, Sq // block_q),
         in_specs=[
             pl.BlockSpec((None, block_q, D), lambda b, i: (b, i, 0)),
@@ -407,10 +515,9 @@ def _flash_bwd_rule(causal, scale, block_q, block_k, interpret,
     """Flash-attention backward as two Pallas kernels (the FA2 split):
     a dq kernel gridded over q-blocks and a dk/dv kernel gridded over
     k-blocks, each rebuilding its probability tile in VMEM from the
-    forward's saved log-sum-exp. The [S, S] score matrix never touches
-    HBM — the old pure-jax fallback spilled every [Sq, block_k] tile,
-    which made the backward HBM-bound (~2 TFLOPS measured at seq 4096 on
-    TPU v5e vs ~15 TFLOPS for this version)."""
+    forward's saved log-sum-exp, so the [S, S] score matrix never touches
+    HBM. Each computes ``delta = rowsum(do * o)`` for itself: handing it
+    over cost more than it saved (PERF.md, PR 29)."""
     from jax.experimental import pallas as pl
 
     q, k, v, out, lse = res
@@ -431,6 +538,7 @@ def _flash_bwd_rule(causal, scale, block_q, block_k, interpret,
     dq_call = pl.pallas_call(
         dq_kernel,
         name="flash_bwd_dq",
+        scratch_shapes=[_acc_scratch(block_q, D)],
         grid=(BH, Sq // block_q),
         in_specs=[
             pl.BlockSpec((None, block_q, D), lambda b, i: (b, i, 0)),
@@ -453,6 +561,7 @@ def _flash_bwd_rule(causal, scale, block_q, block_k, interpret,
     dkv_call = pl.pallas_call(
         dkv_kernel,
         name="flash_bwd_dkv",
+        scratch_shapes=[_acc_scratch(block_k, D), _acc_scratch(block_k, D)],
         grid=(BH, Sk // block_k),
         in_specs=[
             pl.BlockSpec((None, block_k, D), lambda b, i: (b, i, 0)),

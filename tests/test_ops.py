@@ -130,3 +130,125 @@ def test_flash_attention_grads_match_dense():
     gd = jax.grad(loss_dense, argnums=(0, 1, 2))(q, k, v)
     for a, b in zip(gf, gd):
         np.testing.assert_allclose(a, b, rtol=2e-3, atol=2e-3)
+
+
+# (causal, sq, sk, block_q, block_k, head_dim, dtype): each case counts.
+# Between them: causal and not; block_q ==, > and < block_k; Sq == and !=
+# Sk; f32 and bf16; one tile (only a diagonal tile) and a grid of four a
+# side; a k-block of two lane-widths (two probability sub-tiles), and a
+# head wider than the forward's running statistics by a whole number of
+# copies (16 over 8) and not (24 over 16).
+_FLASH_CASES = [
+    (True, 32, 32, 32, 32, 16, jnp.float32),      # one tile, the diagonal's
+    (True, 128, 128, 32, 32, 16, jnp.float32),    # 4 x 4 tiles
+    (False, 128, 128, 32, 32, 16, jnp.float32),
+    (True, 128, 128, 64, 32, 16, jnp.float32),    # block_q > block_k
+    (True, 128, 128, 32, 64, 16, jnp.float32),    # block_q < block_k
+    (True, 64, 128, 32, 32, 16, jnp.float32),     # Sq < Sk
+    (True, 128, 64, 32, 16, 16, jnp.float32),     # Sq > Sk
+    (False, 64, 128, 32, 64, 16, jnp.float32),
+    (True, 256, 256, 128, 256, 8, jnp.float32),   # two sub-tiles a k-block
+    (True, 64, 64, 32, 8, 16, jnp.float32),       # head_dim 2 x the stats
+    (True, 64, 64, 16, 16, 24, jnp.float32),      # head_dim 1.5 x the stats
+    (True, 128, 128, 32, 32, 16, jnp.bfloat16),
+    (True, 128, 64, 64, 32, 16, jnp.bfloat16),
+    (False, 64, 128, 32, 64, 16, jnp.bfloat16),
+]
+
+
+@pytest.mark.parametrize(
+    "causal,sq,sk,block_q,block_k,d,dtype", _FLASH_CASES,
+    ids=[f"{'causal' if c else 'full'}-{sq}x{sk}-{bq}x{bk}-d{d}-{t.__name__}"
+         for c, sq, sk, bq, bk, d, t in _FLASH_CASES])
+def test_flash_forward_and_gradients_match_dense(causal, sq, sk, block_q,
+                                                 block_k, d, dtype):
+    """Forward, dq, dk and dv of the interpreted kernels against
+    ``_fallback`` on the same inputs, in units of the reference's
+    largest value: rounding of f32, and of bf16's probability tiles."""
+    from ray_tpu.ops.flash_attention import _fallback, flash_attention
+
+    keys = jax.random.split(jax.random.PRNGKey(sq + sk + block_q), 3)
+    q = jax.random.normal(keys[0], (1, 2, sq, d), dtype)
+    k = jax.random.normal(keys[1], (1, 2, sk, d), dtype)
+    v = jax.random.normal(keys[2], (1, 2, sk, d), dtype)
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, causal=causal, block_q=block_q,
+                               block_k=block_k, interpret=True)
+
+    def dense(q, k, v):
+        return _fallback(q, k, v, causal, d ** -0.5)
+
+    def loss(fn):
+        return lambda *a: jnp.sum(fn(*a).astype(jnp.float32) ** 2)
+
+    def with_grads(fn):
+        return (fn(q, k, v),) + jax.grad(loss(fn), argnums=(0, 1, 2))(q, k, v)
+
+    got, want = with_grads(flash), with_grads(dense)
+    tol = 2e-5 if dtype == jnp.float32 else 3e-2
+    for name, a, b in zip(("out", "dq", "dk", "dv"), got, want):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        assert a.shape == b.shape and np.isfinite(a).all(), name
+        assert np.abs(a - b).max() <= tol * np.abs(b).max(), name
+
+
+_TILE_SHAPES = [(sq, sk, bq, bk)
+                for sq, sk in ((32, 32), (64, 64), (96, 96), (64, 128),
+                               (128, 64), (96, 32))
+                for bq in (8, 16, 32) for bk in (8, 16, 32)]
+
+
+def test_flash_tile_counts_at_the_cell_and_sum_to_the_grid():
+    from ray_tpu.ops.flash_attention import tile_counts
+
+    assert tile_counts(4096, 4096, 512, 512, True) == (28, 8, 28)
+    assert tile_counts(4096, 4096, 512, 512, False) == (64, 0, 0)
+    assert tile_counts(512, 512, 512, 512, True) == (0, 1, 0)
+    for sq, sk, bq, bk in _TILE_SHAPES + [(12288, 12288, 512, 512),
+                                          (1024, 4096, 256, 512)]:
+        for causal in (True, False):
+            counts = tile_counts(sq, sk, bq, bk, causal)
+            assert sum(counts) == (sq // bq) * (sk // bk), (sq, sk, bq, bk)
+            assert min(counts) >= 0
+
+
+@pytest.mark.parametrize("sq,sk,bq,bk", _TILE_SHAPES[::3])
+def test_flash_tile_split_drops_no_position_and_masks_every_one(sq, sk,
+                                                                bq, bk):
+    """Brute force over small grids, by rows (forward, dq) and by columns
+    (dk/dv): a skipped tile holds no unmasked position, an interior tile
+    no masked one, and the two readings agree with ``tile_counts``."""
+    from ray_tpu.ops.flash_attention import _col_tiles, _row_tiles, tile_counts
+
+    allowed = np.arange(sq)[:, None] >= np.arange(sk)[None, :]
+    num_qb, num_kb = sq // bq, sk // bk
+
+    def tile(qi, ki):
+        return allowed[qi * bq:(qi + 1) * bq, ki * bk:(ki + 1) * bk]
+
+    kinds = {}
+    for qi in range(num_qb):
+        interior, run = (int(n) for n in _row_tiles(qi, bq, bk, num_kb, True))
+        assert 0 <= interior <= run <= num_kb
+        for ki in range(num_kb):
+            kinds[qi, ki] = ("interior" if ki < interior else
+                             "diagonal" if ki < run else "skipped")
+    for ki in range(num_kb):
+        start, interior = (int(n) for n in
+                           _col_tiles(ki, bq, bk, num_qb, True))
+        assert 0 <= start <= interior <= num_qb
+        for qi in range(num_qb):
+            assert kinds[qi, ki] == ("skipped" if qi < start else
+                                     "diagonal" if qi < interior else
+                                     "interior"), (qi, ki)
+    for (qi, ki), kind in kinds.items():
+        if kind == "skipped":
+            assert not tile(qi, ki).any(), (qi, ki)
+        elif kind == "interior":
+            assert tile(qi, ki).all(), (qi, ki)
+        else:       # the mask is needed: both kinds of position are there
+            assert tile(qi, ki).any() and not tile(qi, ki).all(), (qi, ki)
+    got = tuple(sum(k == name for k in kinds.values())
+                for name in ("interior", "diagonal", "skipped"))
+    assert got == tile_counts(sq, sk, bq, bk, True)

@@ -100,6 +100,41 @@ def test_flash_kernels_compile(tpu, s, d):
         *_qkv(tpu[0], s, d, heads=8, kv_heads=2)).compile()) == 1
 
 
+def _eqns(jaxpr):
+    """Every equation under ``jaxpr``, loop and kernel bodies included."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for param in eqn.params.values():
+            inner = getattr(param, "jaxpr", param)
+            if hasattr(inner, "eqns"):
+                yield from _eqns(inner)
+
+
+@pytest.mark.parametrize("s,d", [(1024, 64), (1024, 128), (4096, 64),
+                                 (4096, 128), (12288, 128)])
+def test_flash_backward_is_three_named_kernels_and_no_tile_transpose(
+        tpu, s, d):
+    """The gradient is the forward, dq and dk/dv kernels under their
+    names, up to the VMEM guard's edge, and the dk/dv kernel (k-major
+    score tiles) transposes no [block, block] operand."""
+    args = _qkv(tpu[0], s, d)
+    text = jax.jit(_flash_grad).lower(*args).compile().as_text()
+    assert text.count("tpu_custom_call") == 3
+    for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+        assert f"%{name}." in text or f"%{name} " in text, name
+    calls = {eqn.params["name"]: eqn.params["jaxpr"]
+             for eqn in _eqns(jax.make_jaxpr(_flash_grad)(*args).jaxpr)
+             if eqn.primitive.name == "pallas_call"}
+    assert sorted(calls) == ["flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"]
+    block = fa._auto_block(s)
+    dkv = [(eqn.primitive.name, [v.aval.shape for v in eqn.invars
+                                 if hasattr(v.aval, "shape")])
+           for eqn in _eqns(calls["flash_bwd_dkv"])]
+    assert any(name == "dot_general" for name, _ in dkv)
+    assert not [shapes for name, shapes in dkv
+                if name == "transpose" and (block, block) in shapes]
+
+
 @pytest.mark.parametrize("s", [64, 192])
 def test_flash_guard_keeps_unaligned_blocks_off_the_compiler(tpu, s):
     """A block that is not a multiple of 128 lanes is refused by Mosaic
