@@ -49,6 +49,7 @@ def draft_config(base: TransformerConfig, **overrides
         n_heads=max(1, base.n_heads // 2),
         n_kv_heads=max(1, base.n_kv_heads // 2),
         d_ff=max(32, base.d_ff // 2),
+        head_dim=None,      # its own d_model // n_heads, not the base's
     )
     small.update(overrides)
     return dataclasses.replace(base, **small)

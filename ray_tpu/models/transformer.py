@@ -19,6 +19,18 @@ SURVEY.md §2.6): everything here is built for the MXU and the Mesh:
 
 GQA attention with rotary embeddings, RMSNorm, SwiGLU MLP, optional MoE
 layers every ``moe_every``-th layer.
+
+**A layer pattern** (``forward``/``loss_fn``, the training body). A
+configuration with ``layer_types`` or ``router_experts`` is a stack of
+layers of several kinds: the sequence operator of a layer is causal
+attention or a gated short convolution, its feed-forward the dense SwiGLU
+(the ``num_dense_layers`` leading ones) or the routed experts as published
+(``parallel/moe.py``: sigmoid or softmax scores, top-k over scores plus a
+bias, renormalised gates, no token dropped, and only the experts this
+chip holds computed). The parameters are stacked per kind
+(``params["layers"][kind]``), and each run of equal layers in published
+order is one ``lax.scan`` (``layer_runs``). The cached serving bodies
+run one kind of layer and refuse such a configuration.
 """
 
 from __future__ import annotations
@@ -35,6 +47,7 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ray_tpu.parallel.mesh import mesh_shape
+from ray_tpu.parallel import moe
 from ray_tpu.parallel.moe import moe_dispatch_combine
 from ray_tpu.parallel.ring_attention import ring_attention
 from ray_tpu.parallel.sharding import ShardingRules
@@ -56,10 +69,135 @@ class TransformerConfig:
     capacity_factor: float = 1.25
     dtype: Any = jnp.bfloat16
     remat: bool = False
+    # The width of a head; None: d_model // n_heads.
+    head_dim: Optional[int] = None
+    norm_eps: float = 1e-6
+    # RMSNorm over each head of q and of k (own weights), before rope.
+    qk_norm: bool = False
+    # The head is the embedding table, transposed; no ``lm_head`` leaf.
+    tie_embeddings: bool = False
+    # The layer pattern (training body only). ``layer_types``: the
+    # sequence operator of each layer, "attention" or "conv" (None: all
+    # attention); a conv layer's causal depthwise kernel has
+    # ``conv_kernel`` taps.
+    layer_types: Optional[Tuple[str, ...]] = None
+    conv_kernel: int = 3
+    # Routed experts as published, in every layer after the
+    # ``num_dense_layers`` leading ones (0 experts: every layer dense).
+    # ``router_experts`` is the router's width, ``experts_held`` which of
+    # them live on this chip (None: all); a share computes its own
+    # experts' part of the layer and nothing stands in for the rest.
+    router_experts: int = 0
+    num_dense_layers: int = 0
+    experts_held: Optional[Tuple[int, ...]] = None
+    experts_per_token: int = 1
+    moe_d_ff: Optional[int] = None          # an expert's width; None: d_ff
+    router_score: str = "softmax"           # or "sigmoid"
+    norm_topk: bool = False                 # gates renormalised over the k
+    routed_scale: float = 1.0
+    expert_bias: bool = False               # added to the scores to select
+
+    def __post_init__(self):
+        if self.head_dim is None:
+            object.__setattr__(self, "head_dim", self.d_model // self.n_heads)
+        if self.layer_types is not None:
+            object.__setattr__(self, "layer_types", tuple(self.layer_types))
+            if (len(self.layer_types) != self.n_layers
+                    or set(self.layer_types) - {ATTENTION, CONV}):
+                raise ValueError(
+                    f"layer_types {self.layer_types}: one of {ATTENTION!r}, "
+                    f"{CONV!r} for each of the {self.n_layers} layers")
+        if self.router_experts:
+            if self.num_experts:
+                raise ValueError("router_experts (top-k, dropless) and "
+                                 "num_experts (top-1, capacity) exclude "
+                                 "each other")
+            held = tuple(range(self.router_experts)
+                         if self.experts_held is None else self.experts_held)
+            if (not held or len(set(held)) != len(held)
+                    or not set(held) <= set(range(self.router_experts))):
+                raise ValueError(f"experts_held {held}: distinct experts of "
+                                 f"the router's {self.router_experts}")
+            object.__setattr__(self, "experts_held", held)
+            if self.router_score not in ("softmax", "sigmoid"):
+                raise ValueError(f"router_score {self.router_score!r}")
+            if not 1 <= self.experts_per_token <= self.router_experts:
+                raise ValueError("experts_per_token out of the router's range")
 
     @property
-    def head_dim(self) -> int:
-        return self.d_model // self.n_heads
+    def patterned(self) -> bool:
+        """Layers of several kinds: parameters stacked per kind."""
+        return self.layer_types is not None or self.router_experts > 0
+
+
+ATTENTION, CONV = "attention", "conv"
+DENSE, MOE = "dense", "moe"
+
+
+def layer_kind(cfg: TransformerConfig, i: int) -> str:
+    """The kind of layer ``i``, ``<operator>_<feed-forward>``."""
+    op = cfg.layer_types[i] if cfg.layer_types is not None else ATTENTION
+    ffn = MOE if cfg.router_experts and i >= cfg.num_dense_layers else DENSE
+    return f"{op}_{ffn}"
+
+
+def layer_runs(cfg: TransformerConfig) -> Tuple[Tuple[str, int, int], ...]:
+    """(kind, start, count) of each run of equal layers in published
+    order; ``start`` counts within the kind's own stack."""
+    runs, seen = [], {}
+    for i in range(cfg.n_layers):
+        kind = layer_kind(cfg, i)
+        if runs and runs[-1][0] == kind:
+            runs[-1][2] += 1
+        else:
+            runs.append([kind, seen.get(kind, 0), 1])
+        seen[kind] = seen.get(kind, 0) + 1
+    return tuple(tuple(r) for r in runs)
+
+
+def _kind_leaves(cfg: TransformerConfig, kind: str) -> Dict[str, tuple]:
+    """name -> (shape, fan_in, PartitionSpec roles) of one layer of
+    ``kind``; fan_in None: ones (a norm), 0: zeros (the bias)."""
+    D, Hd = cfg.d_model, cfg.head_dim
+    nq, nkv = cfg.n_heads * Hd, cfg.n_kv_heads * Hd
+    op, ffn = kind.split("_")
+    if op == ATTENTION:
+        leaves = {"attn_norm": ((D,), None, (None,)),
+                  "wq": ((D, nq), D, ("fsdp", "tp")),
+                  "wk": ((D, nkv), D, ("fsdp", "tp")),
+                  "wv": ((D, nkv), D, ("fsdp", "tp")),
+                  "wo": ((nq, D), nq, ("tp", "fsdp"))}
+        if cfg.qk_norm:
+            leaves.update(q_norm=((Hd,), None, (None,)),
+                          k_norm=((Hd,), None, (None,)))
+    else:
+        K = cfg.conv_kernel
+        leaves = {"conv_norm": ((D,), None, (None,)),
+                  "conv_in": ((D, 3 * D), D, ("fsdp", "tp")),
+                  "conv_taps": ((D, K), K, (None, None)),
+                  "conv_out": ((D, D), D, ("tp", "fsdp"))}
+    leaves["mlp_norm"] = ((D,), None, (None,))
+    if ffn == DENSE:
+        F = cfg.d_ff
+        leaves.update(w_gate=((D, F), D, ("fsdp", "tp")),
+                      w_up=((D, F), D, ("fsdp", "tp")),
+                      w_down=((F, D), F, ("tp", "fsdp")))
+    else:
+        F, E = cfg.moe_d_ff or cfg.d_ff, len(cfg.experts_held)
+        leaves.update(router=((D, cfg.router_experts), D, (None, None)),
+                      e_gate=((E, D, F), D, ("expert", None, "tp")),
+                      e_up=((E, D, F), D, ("expert", None, "tp")),
+                      e_down=((E, F, D), F, ("expert", "tp", None)))
+        if cfg.expert_bias:
+            leaves["expert_bias"] = ((cfg.router_experts,), 0, (None,))
+    return leaves
+
+
+def _kind_counts(cfg: TransformerConfig) -> Dict[str, int]:
+    counts: Dict[str, int] = {}
+    for kind, _start, count in layer_runs(cfg):
+        counts[kind] = counts.get(kind, 0) + count
+    return counts
 
 
 def _dense_init(key, shape, fan_in):
@@ -68,7 +206,10 @@ def _dense_init(key, shape, fan_in):
 
 
 def init_params(cfg: TransformerConfig, key: jax.Array) -> Dict[str, Any]:
-    """Stacked-layer param pytree. Weights f32 (master copy)."""
+    """Stacked-layer param pytree. Weights f32 (master copy). A
+    patterned configuration stacks per kind: ``layers[kind][leaf]``."""
+    if cfg.patterned:
+        return _init_pattern_params(cfg, key)
     D, F, Hd = cfg.d_model, cfg.d_ff, cfg.head_dim
     nq, nkv, L = cfg.n_heads, cfg.n_kv_heads, cfg.n_layers
     # One distinct key per weight family: same-shaped families (wq/wk/wv,
@@ -99,13 +240,42 @@ def init_params(cfg: TransformerConfig, key: jax.Array) -> Dict[str, Any]:
             lambda k: _dense_init(k, (E, D, F), D))
         layers["e_up"] = stack(lambda k: _dense_init(k, (E, D, F), D))
         layers["e_down"] = stack(lambda k: _dense_init(k, (E, F, D), F))
-    return {
+    params = {
         "embed": jax.random.normal(ks[0], (cfg.vocab_size, D),
                                    jnp.float32) * 0.02,
         "layers": layers,
         "final_norm": jnp.ones((D,), jnp.float32),
-        "lm_head": _dense_init(ks[1], (D, cfg.vocab_size), D),
     }
+    if not cfg.tie_embeddings:
+        params["lm_head"] = _dense_init(ks[1], (D, cfg.vocab_size), D)
+    return params
+
+
+def _init_pattern_params(cfg: TransformerConfig, key: jax.Array
+                         ) -> Dict[str, Any]:
+    k_embed, k_head, k_layers = jax.random.split(key, 3)
+    layers = {}
+    for ki, (kind, n) in enumerate(sorted(_kind_counts(cfg).items())):
+        k_kind = jax.random.fold_in(k_layers, ki)
+        layers[kind] = {}
+        for li, (name, (shape, fan_in, _roles)) in enumerate(
+                _kind_leaves(cfg, kind).items()):
+            if fan_in:
+                layers[kind][name] = _dense_init(
+                    jax.random.fold_in(k_kind, li), (n,) + shape, fan_in)
+            else:
+                fill = jnp.ones if fan_in is None else jnp.zeros
+                layers[kind][name] = fill((n,) + shape, jnp.float32)
+    params = {
+        "embed": jax.random.normal(k_embed, (cfg.vocab_size, cfg.d_model),
+                                   jnp.float32) * 0.02,
+        "layers": layers,
+        "final_norm": jnp.ones((cfg.d_model,), jnp.float32),
+    }
+    if not cfg.tie_embeddings:
+        params["lm_head"] = _dense_init(
+            k_head, (cfg.d_model, cfg.vocab_size), cfg.d_model)
+    return params
 
 
 def param_specs(cfg: TransformerConfig,
@@ -118,6 +288,20 @@ def param_specs(cfg: TransformerConfig,
     """
     r = rules or ShardingRules()
     st, tp, fs = r.stage, r.mlp, r.fsdp_shard
+    if cfg.patterned:
+        # A kind's stack is no contiguous block of layers: no stage axis.
+        role = {"tp": tp, "fsdp": fs, "expert": r.expert, None: None}
+        specs = {
+            "embed": P(r.vocab, None),
+            "layers": {kind: {name: P(None, *(role[x] for x in roles))
+                              for name, (_s, _f, roles)
+                              in _kind_leaves(cfg, kind).items()}
+                       for kind in _kind_counts(cfg)},
+            "final_norm": P(None),
+        }
+        if not cfg.tie_embeddings:
+            specs["lm_head"] = P(fs, r.vocab)
+        return specs
     layers = {
         "attn_norm": P(st, None),
         "wq": P(st, fs, tp), "wk": P(st, fs, tp), "wv": P(st, fs, tp),
@@ -133,12 +317,14 @@ def param_specs(cfg: TransformerConfig,
             "e_up": P(st, r.expert, None, tp),
             "e_down": P(st, r.expert, tp, None),
         })
-    return {
+    specs = {
         "embed": P(r.vocab, None),
         "layers": layers,
         "final_norm": P(None),
-        "lm_head": P(fs, r.vocab),
     }
+    if not cfg.tie_embeddings:
+        specs["lm_head"] = P(fs, r.vocab)
+    return specs
 
 
 @jax.named_scope("norm")
@@ -228,10 +414,13 @@ def _project_qkv(cfg, lp, x, positions):
     dt = cfg.dtype
     B, S, _ = x.shape
     Hd = cfg.head_dim
-    h = rms_norm(x, lp["attn_norm"])
+    h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
     q = (h @ lp["wq"].astype(dt)).reshape(B, S, -1, Hd)
     k = (h @ lp["wk"].astype(dt)).reshape(B, S, -1, Hd)
     v = (h @ lp["wv"].astype(dt)).reshape(B, S, -1, Hd)
+    if cfg.qk_norm:
+        q = rms_norm(q, lp["q_norm"], cfg.norm_eps)
+        k = rms_norm(k, lp["k_norm"], cfg.norm_eps)
     q = rope(q, positions, cfg.rope_theta)
     k = rope(k, positions, cfg.rope_theta)
     return q, k, v
@@ -277,7 +466,7 @@ def _layer_fn(cfg: TransformerConfig, lp: Dict[str, jax.Array], x: jax.Array,
 @jax.named_scope("seg.mlp")
 def _mlp_residual(cfg, lp, x, layer_idx, tp_axis=None, ep_axis=None):
     """MLP norm, ``_mlp_block`` and the residual add, x [B, S, D]."""
-    h = rms_norm(x, lp["mlp_norm"])
+    h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
     return x + _mlp_block(cfg, lp, h, layer_idx,
                           tp_axis=tp_axis, ep_axis=ep_axis)
 
@@ -338,6 +527,108 @@ def _swiglu(cfg, lp, h, tp_axis):
     return out
 
 
+@jax.named_scope("seg.conv")
+def _conv_residual(cfg, lp, x):
+    """The gated short convolution as a layer's sequence operator, with
+    its norm and residual add, x [B, S, D]: ``[b, c, u] = split3(z W_in)``,
+    a causal depthwise convolution of ``b * u`` over ``conv_kernel`` taps
+    (zeros before the sequence, no bias), gated by ``c``, then ``W_out``.
+    No activation function. Three taps are three shifted multiply-adds."""
+    dt = cfg.dtype
+    S, K = x.shape[1], cfg.conv_kernel
+    z = rms_norm(x, lp["conv_norm"], cfg.norm_eps)
+    b, c, u = jnp.split(z @ lp["conv_in"].astype(dt), 3, axis=-1)
+    v = jnp.pad(b * u, ((0, 0), (K - 1, 0), (0, 0)))
+    taps = lp["conv_taps"].astype(dt)                       # [D, K]
+    y = sum(v[:, j:j + S] * taps[:, j] for j in range(K))   # tap K-1: now
+    return x + (c * y) @ lp["conv_out"].astype(dt)
+
+
+def _moe_residual(cfg, lp, x):
+    """The routed experts as a layer's feed-forward, with its norm and
+    residual add, x [B, S, D]: this chip's experts' part of the layer
+    (``parallel/moe.py``), and the tokens each held expert got. Two
+    segments, so not under ``seg.mlp``."""
+    B, S, D = x.shape
+    with jax.named_scope("seg.moe_route"):
+        h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps).reshape(B * S, D)
+        routing = moe.route(
+            h, lp["router"], lp.get("expert_bias"),
+            experts_held=cfg.experts_held, k=cfg.experts_per_token,
+            score=cfg.router_score, norm_topk=cfg.norm_topk,
+            scale=cfg.routed_scale)
+    with jax.named_scope("seg.moe_experts"):
+        dt = cfg.dtype
+        out = moe.held_experts(
+            h, routing, lp["e_gate"].astype(dt), lp["e_up"].astype(dt),
+            lp["e_down"].astype(dt))
+        return x + out.reshape(B, S, D), routing.group_sizes
+
+
+def _pattern_layer(cfg: TransformerConfig, kind: str, lp, x, positions):
+    """One layer of ``kind`` (``layer_kind``) of a patterned stack ->
+    (x, the tokens each held expert got; None for a dense layer)."""
+    op, ffn = kind.split("_")
+    if op == CONV:
+        x = _conv_residual(cfg, lp, x)
+    else:
+        q, k, v = _project_qkv(cfg, lp, x, positions)
+        x = _attn_out(cfg, lp, x, _attention_dense(q, k, v))
+    if ffn == MOE:
+        return _moe_residual(cfg, lp, x)
+    return _mlp_residual(cfg, lp, x, 0), None
+
+
+def _pattern_layers(cfg: TransformerConfig, layers, x, positions, constrain):
+    """The stack of a patterned configuration: one ``lax.scan`` for each
+    run of equal layers, over that run's slice of its kind's stack."""
+    counts = _kind_counts(cfg)
+    for kind, start, count in layer_runs(cfg):
+        stack = layers[kind]
+        if count != counts[kind]:
+            stack = jax.tree.map(lambda a: a[start:start + count], stack)
+
+        def body(x, lp, kind=kind):
+            run = partial(_pattern_layer, cfg, kind, lp,
+                          positions=positions)
+            x, _load = jax.checkpoint(run)(x) if cfg.remat else run(x)
+            return constrain(x, "batch", "sequence", "embed"), None
+
+        x, _ = lax.scan(body, x, stack)
+    return x
+
+
+def moe_load(cfg: TransformerConfig, params: Dict[str, Any],
+             tokens: jax.Array) -> Dict[str, jax.Array]:
+    """Tokens routed to each held expert in each expert layer of a forward
+    pass over ``tokens`` [B, S], from the layers' own routing:
+    ``{kind: int32 [layers of that kind, experts held]}``."""
+    B, S = tokens.shape
+    load: Dict[str, list] = {}
+    x = _embed(cfg, params, tokens)
+    positions = jnp.broadcast_to(jnp.arange(S), (B, S))
+    for kind, start, count in layer_runs(cfg):
+        for at in range(start, start + count):
+            lp = jax.tree.map(lambda a: a[at], params["layers"][kind])
+            x, sizes = _pattern_layer(cfg, kind, lp, x, positions)
+            if sizes is not None:
+                load.setdefault(kind, []).append(sizes)
+    return {kind: jnp.stack(sizes) for kind, sizes in load.items()}
+
+
+def _refuse_pattern(cfg: TransformerConfig, body: str) -> None:
+    """The cached serving bodies run one kind of layer: a conv layer's
+    state has no place in the paged cache yet, and an expert layer that
+    holds a share gives a partial result. A wrong answer is worse than
+    none."""
+    if cfg.patterned:
+        raise NotImplementedError(
+            f"{body} runs attention layers with one feed-forward kind; "
+            f"this configuration has a layer pattern "
+            f"({[k for k, _s, _n in layer_runs(cfg)]}), which only "
+            f"forward/loss_fn run")
+
+
 def forward(cfg: TransformerConfig, params: Dict[str, Any],
             tokens: jax.Array,
             mesh: Optional[Mesh] = None,
@@ -355,6 +646,10 @@ def forward(cfg: TransformerConfig, params: Dict[str, Any],
     x = _embed(cfg, params, tokens)
     x = constrain(x, "batch", "sequence", "embed")
     positions = jnp.broadcast_to(jnp.arange(S), (B, S))
+    if cfg.patterned:
+        x = _pattern_layers(cfg, params["layers"], x, positions, constrain)
+        return constrain(_final_logits(cfg, params, x),
+                         "batch", "sequence", "vocab")
 
     def body(carry, lp_with_idx):
         x = carry
@@ -376,12 +671,16 @@ def forward(cfg: TransformerConfig, params: Dict[str, Any],
 @jax.named_scope("seg.head_loss")
 def _lm_head(cfg, params, x):
     """f32 logits of final-normed hidden states."""
+    if cfg.tie_embeddings:
+        # One table, two uses: the gradient reaches it from both.
+        return (x @ params["embed"].astype(cfg.dtype).T).astype(jnp.float32)
     return (x @ params["lm_head"].astype(cfg.dtype)).astype(jnp.float32)
 
 
 @jax.named_scope("seg.head_loss")
 def _final_logits(cfg, params, x):
-    return _lm_head(cfg, params, rms_norm(x, params["final_norm"]))
+    return _lm_head(cfg, params,
+                    rms_norm(x, params["final_norm"], cfg.norm_eps))
 
 
 @jax.named_scope("seg.head_loss")
@@ -438,6 +737,7 @@ def make_spmd_train_step(cfg: TransformerConfig, mesh: Mesh, params,
     """
     import optax
 
+    _refuse_pattern(cfg, "make_spmd_train_step")
     if optimizer is None:
         optimizer = optax.adamw(3e-4)
     shape = mesh_shape(mesh)
@@ -632,6 +932,7 @@ def prefill_with_cache(cfg: TransformerConfig, params, cache,
     Causality makes the padded tail invisible to every real position, so
     the result is bit-identical to an unpadded per-sequence run.
     """
+    _refuse_pattern(cfg, "prefill_with_cache")
     B, S = tokens.shape
     block_size = cache["k"].shape[2]
     x = _embed(cfg, params, tokens)
@@ -657,7 +958,7 @@ def prefill_with_cache(cfg: TransformerConfig, params, cache,
     (x, ck, cv), _ = lax.scan(
         body, (x, cache["k"], cache["v"]), (params["layers"], idxs))
     with jax.named_scope("seg.head_loss"):
-        x = rms_norm(x, params["final_norm"])
+        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
         last = jnp.take_along_axis(
             x, (prompt_lens - 1)[:, None, None].clip(0), axis=1)[:, 0]
     return _lm_head(cfg, params, last), {"k": ck, "v": cv}
@@ -706,6 +1007,7 @@ def _chunk_scan(cfg: TransformerConfig, params, cache, tokens, start_pos,
     run the chunk through every layer against the paged cache, writing
     each position's K/V before it is attended, and return the final-
     normed hidden states ``[B, C, D]`` plus the updated K/V pools."""
+    _refuse_pattern(cfg, "_chunk_scan")
     C = tokens.shape[1]
     block_size = cache["k"].shape[2]
     M = block_tables.shape[1]
@@ -743,7 +1045,7 @@ def _chunk_scan(cfg: TransformerConfig, params, cache, tokens, start_pos,
     (x, ck, cv), _ = lax.scan(
         body, (x, cache["k"], cache["v"]), (params["layers"], idxs))
     with jax.named_scope("seg.head_loss"):
-        return rms_norm(x, params["final_norm"]), ck, cv
+        return rms_norm(x, params["final_norm"], cfg.norm_eps), ck, cv
 
 
 def verify_step(cfg: TransformerConfig, params, cache,
@@ -790,6 +1092,7 @@ def decode_step(cfg: TransformerConfig, params, cache,
 
     Returns (logits [B, vocab] f32, new cache).
     """
+    _refuse_pattern(cfg, "decode_step")
     block_size = cache["k"].shape[2]
     x = _embed(cfg, params, tokens[:, None])         # [B, 1, D]
     pos2 = positions[:, None]                        # [B, 1]

@@ -7,15 +7,31 @@ shard → expert MLP → ``all_to_all`` back → weighted combine. Dropped token
 semantics.
 
 Call inside ``shard_map`` over the ``ep`` axis with experts sharded on it.
+
+**The expert layer as published** (``route``, ``held_experts``; the training
+body of ``models/transformer.py``). Scores are a sigmoid or a softmax of a
+float32 router product; the k experts of a token are the top k of scores
+plus a selection bias, and their gates the scores themselves, renormalised
+over the k where the model says so. No token is dropped, under any
+imbalance, at static shapes: the T x k (token, expert) pairs are sorted by
+expert, the pairs of experts this chip does not hold after the held ones,
+and the three products of an expert run grouped over the held groups
+(``ops/grouped_matmul.py``), so their cost follows the pairs routed here. The
+layer is told which experts it holds, routes over all of them and returns
+its own experts' part of the result. On one chip nothing is exchanged, and
+nothing stands in for the absent chips.
 """
 
 from __future__ import annotations
 
-from typing import Callable, Tuple
+from typing import Callable, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
+
+from ray_tpu.ops.grouped_matmul import grouped_matmul
 
 
 def top1_router(logits: jax.Array) -> Tuple[jax.Array, jax.Array]:
@@ -88,3 +104,73 @@ def load_balancing_loss(router_logits: jax.Array, expert_idx: jax.Array,
     probs = jax.nn.softmax(router_logits, axis=-1)
     frac = jnp.mean(jax.nn.one_hot(expert_idx, num_experts), axis=0)
     return num_experts * jnp.sum(frac * jnp.mean(probs, axis=0))
+
+
+class Routing(NamedTuple):
+    """The (token, expert) pairs of one expert layer, sorted by expert with
+    the held experts' pairs first, in the order ``experts_held`` gives."""
+    token: jax.Array        # [T*k] int32: the token of each sorted pair
+    gate: jax.Array         # [T*k] float32: its gate
+    held: jax.Array         # [T*k] bool: the pair's expert lives here
+    group_sizes: jax.Array  # [experts held] int32: pairs of each
+    experts: jax.Array      # [T, k] int32: the experts each token chose
+    gates: jax.Array        # [T, k] float32: and their gates, unsorted
+
+
+def route(h: jax.Array, router: jax.Array, bias: Optional[jax.Array], *,
+          experts_held: Tuple[int, ...], k: int, score: str = "softmax",
+          norm_topk: bool = False, scale: float = 1.0) -> Routing:
+    """h [T, D], router [D, E] (E the router's published width), bias [E]
+    or None -> the layer's routing. The router product is float32 at
+    ``highest``: a bf16 product flips near-ties of the top k. The bias
+    selects and does not weigh, and takes no gradient."""
+    T, E = h.shape[0], router.shape[1]
+    logits = jnp.matmul(h.astype(jnp.float32), router.astype(jnp.float32),
+                        precision=lax.Precision.HIGHEST)
+    scores = (jax.nn.sigmoid(logits) if score == "sigmoid"
+              else jax.nn.softmax(logits, axis=-1))
+    select = scores if bias is None else \
+        scores + lax.stop_gradient(bias.astype(jnp.float32))
+    _, experts = lax.top_k(select, k)                           # [T, k]
+    gates = jnp.take_along_axis(scores, experts, axis=-1)
+    if norm_topk:
+        gates = gates / (jnp.sum(gates, axis=-1, keepdims=True) + 1e-6)
+    gates = gates * scale
+    # An expert's place among the held ones; len(held) for one not held.
+    n_held = len(experts_held)
+    place = np.full((E,), n_held, np.int32)
+    place[list(experts_held)] = np.arange(n_held, dtype=np.int32)
+    group = jnp.asarray(place)[experts].reshape(T * k)
+    group, pair = lax.sort_key_val(group, jnp.arange(T * k, dtype=jnp.int32))
+    group_sizes = jnp.sum(
+        group[:, None] == jnp.arange(n_held, dtype=jnp.int32)[None, :],
+        axis=0, dtype=jnp.int32)
+    return Routing(token=pair // k, gate=gates.reshape(T * k)[pair],
+                   held=group < n_held, group_sizes=group_sizes,
+                   experts=experts, gates=gates)
+
+
+@jax.checkpoint
+def held_experts(h: jax.Array, routing: Routing, e_gate: jax.Array,
+                 e_up: jax.Array, e_down: jax.Array) -> jax.Array:
+    """This chip's experts' part of the layer: h [T, D], the held experts'
+    SwiGLU weights [held, D, F], [held, D, F], [held, F, D] in the
+    activations' type -> [T, D], ``sum over a token's held experts of gate
+    * expert(h)``.
+
+    Every one of the T x k sorted pairs is a row here, so no pair is
+    dropped whatever the routing. Rows of pairs whose expert lives
+    elsewhere lie behind the groups; they enter as zeros and leave as
+    zeros whatever the grouped product writes there. The grouped products
+    cost the rows routed here; the gather and the scatter-add cost every
+    row. The rows are made again in the backward pass and not kept."""
+    T, D = h.shape
+    held = routing.held[:, None]
+    xs = jnp.where(held, h[routing.token], 0)
+    g = grouped_matmul(xs, e_gate, routing.group_sizes)
+    u = grouped_matmul(xs, e_up, routing.group_sizes)
+    ys = grouped_matmul(jax.nn.silu(g) * u, e_down, routing.group_sizes)
+    # Masked before the gate meets it: the gate's gradient reads these rows.
+    ys = jnp.where(held, ys, 0).astype(jnp.float32) * routing.gate[:, None]
+    out = jnp.zeros((T, D), jnp.float32).at[routing.token].add(ys)
+    return out.astype(h.dtype)

@@ -26,12 +26,14 @@ Division of labor between the two profilers:
 ``profile_trace`` of any step, training or serving, shows in the trace
 viewer's operation details (the ``op_name`` of each XLA operation) which
 part of the model the operation is: one of ``SEGMENTS`` (``seg.embed``,
-``seg.attn_proj``, ``seg.attn_core``, ``seg.mlp``, ``seg.head_loss``;
+``seg.attn_proj``, ``seg.attn_core``, ``seg.mlp``, ``seg.head_loss``, and
+in a patterned stack ``seg.conv``, ``seg.moe_route``, ``seg.moe_experts``;
 the outermost one on the path is the operation's segment, ``norm`` and
 ``rope`` are finer scopes inside), and on the flash kernels one of
 ``KERNELS`` (``flash_fwd``, ``flash_fwd_grouped``, ``flash_bwd_dq``,
 ``flash_bwd_dkv``), which is also the kernel instruction's own name
-(``%flash_fwd.6``). ``transpose(jvp(...))`` on the path marks the backward
+(``%flash_fwd.6``); on an expert layer's grouped products ``moe_gmm``
+or ``moe_tgmm``. ``transpose(jvp(...))`` on the path marks the backward
 pass; an operation with names and no segment is the optimizer's. The
 scopes are written in ``models/transformer.py`` and ``ops/``; they exist
 while a program is traced and cost nothing when it runs.
@@ -53,11 +55,17 @@ from typing import Iterator, Optional
 # gives an operation to the OUTERMOST of these on its ``op_name`` path;
 # the ``seg.`` prefix is one no JAX primitive or transform produces.
 SEGMENTS = ("seg.embed", "seg.attn_proj", "seg.attn_core", "seg.mlp",
-            "seg.head_loss")
+            "seg.head_loss",
+            # a patterned stack's layers (models/transformer.py): the gated
+            # short convolution with its norm and residual; an expert
+            # layer's norm, router, top-k, gates and sort; its gather,
+            # grouped products, weighted scatter-add and residual
+            "seg.conv", "seg.moe_route", "seg.moe_experts")
 # The Pallas kernels of ``ops/flash_attention.py``: each one's ``name=``
-# and the scope around its call.
+# and the scope around its call; and of ``ops/grouped_matmul.py``: the
+# scope around each call of JAX's own grouped-matmul kernels.
 KERNELS = ("flash_fwd", "flash_fwd_grouped", "flash_bwd_dq",
-           "flash_bwd_dkv")
+           "flash_bwd_dkv", "moe_gmm", "moe_tgmm")
 
 
 @contextlib.contextmanager

@@ -15,6 +15,7 @@ one place that answer is wrong, so the fixture overrides it.
 
 import functools
 import importlib
+import re
 
 import jax
 import jax.numpy as jnp
@@ -171,6 +172,46 @@ def test_flash_vmem_limit_is_a_limit(tpu):
     assert "tpu_custom_call" not in lowered.as_text()
 
 
+def test_flash_kernels_compile_at_heads_of_64_and_8192_tokens(tpu):
+    """The LFM2 cell's attention layer: 32 query heads of 64 over 8192
+    tokens, forward and both backward kernels, under their names."""
+    assert fa.use_flash(8192, 8192, 64, jnp.bfloat16)
+    text = jax.jit(_flash_grad).lower(
+        *_qkv(tpu[0], 8192, 64, heads=32)).compile().as_text()
+    assert text.count("tpu_custom_call") == 3
+    for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+        assert f"%{name}." in text or f"%{name} " in text, name
+
+
+@pytest.mark.parametrize("rows", [8192, 32768])
+def test_grouped_matmul_kernels_compile_under_their_names(tpu, rows):
+    """The held experts' grouped products at the LFM2 cell's widths (8
+    experts of 2048 x 1536), over every sorted pair of a layer and over a quarter of them: the
+    product and its two gradients are JAX's own kernels, each call under
+    the program's name for it."""
+    from ray_tpu.ops.grouped_matmul import grouped_matmul, kernel_accepts
+    from ray_tpu.util import profiling
+
+    one = SingleDeviceSharding(tpu[0])
+    assert kernel_accepts(rows, 2048, 1536)
+    lhs = jax.ShapeDtypeStruct((rows, 2048), jnp.bfloat16, sharding=one)
+    rhs = jax.ShapeDtypeStruct((8, 2048, 1536), jnp.bfloat16, sharding=one)
+    sizes = jax.ShapeDtypeStruct((8,), jnp.int32, sharding=one)
+    assert _kernel_calls(
+        jax.jit(grouped_matmul).lower(lhs, rhs, sizes).compile()) == 1
+    grad = jax.jit(jax.grad(lambda a, b, n: grouped_matmul(a, b, n).astype(
+        jnp.float32).sum(), argnums=(0, 1)))
+    text = grad.lower(lhs, rhs, sizes).compile().as_text()
+    assert text.count("tpu_custom_call") == 2     # a sum needs no forward
+    calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    named = sorted(m for line in calls for m in ("moe_gmm", "moe_tgmm")
+                   if re.search(r"(?<![\w.])" + m + r"(?![\w.])", line))
+    assert named == ["moe_gmm", "moe_tgmm"]
+    assert set(named) <= set(profiling.KERNELS)
+    # XLA's own expansion of ragged_dot would have dropped the name
+    assert "ragged-dot" not in text
+
+
 def test_rms_norm_fused_compiles(tpu):
     one = SingleDeviceSharding(tpu[0])
     x = jax.ShapeDtypeStruct((8, 1024, 2048), jnp.bfloat16, sharding=one)
@@ -293,5 +334,8 @@ def test_train_step_names_its_kernels_and_its_fusions(tpu):
     named = [s for s in fusions if s != segments.UNATTRIBUTED]
     assert len(fusions) > 50 and len(named) >= 0.9 * len(fusions), (
         len(named), len(fusions))
-    assert set(profiling.SEGMENTS) <= {r["segment"] for r in table.values()}
+    # a uniform stack: every segment but a layer pattern's three
+    pattern = {"seg.conv", "seg.moe_route", "seg.moe_experts"}
+    assert set(profiling.SEGMENTS) - pattern <= {
+        r["segment"] for r in table.values()}
 
