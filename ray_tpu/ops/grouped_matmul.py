@@ -6,8 +6,13 @@ multiplies the first ``group_sizes[0]`` rows of ``lhs`` with ``rhs[0]``, the
 next ``group_sizes[1]`` with ``rhs[1]``, and so on. The groups may cover
 fewer than M rows (an expert layer that holds a share of the experts sorts
 the other experts' pairs behind its own): rows behind the last group are
-UNDEFINED in the result, and in the gradient with respect to ``lhs``. The
-caller masks them (``parallel/moe.py``).
+UNDEFINED in the result, and in the gradient with respect to ``lhs``, and
+what ``lhs`` and the result's cotangent hold there is never read into a row
+of a group (the kernels select a tile's rows by its group, they do not
+weigh them). Nobody masks them: the expert layer's other passes
+(``moe_rows.py``) stop at the last row tile the groups touch, as these
+kernels do, and ``parallel/moe.py`` selects by the held pairs where a
+whole array is read.
 
 On an accelerator the products are the grouped-matmul Pallas kernels that
 ship with JAX (``jax.experimental.pallas.ops.tpu.megablox``: ``gmm`` for

@@ -15,15 +15,21 @@ plus a selection bias, and their gates the scores themselves, renormalised
 over the k where the model says so. No token is dropped, under any
 imbalance, at static shapes: the T x k (token, expert) pairs are sorted by
 expert, the pairs of experts this chip does not hold after the held ones,
-and the three products of an expert run grouped over the held groups
-(``ops/grouped_matmul.py``), so their cost follows the pairs routed here. The
-layer is told which experts it holds, routes over all of them and returns
-its own experts' part of the result. On one chip nothing is exchanged, and
-nothing stands in for the absent chips.
+and every pass over the sorted rows stops at the last row tile the held
+groups touch: the three products of an expert run grouped over the held
+groups (``ops/grouped_matmul.py``), and the gather before them, the
+row-wise stages between them and the weighted scatter-add after them take
+the same trip count from the same group sizes (``ops/moe_rows.py``,
+``rows_worked``). So the layer's cost follows the pairs routed here, from
+none to all T x k of them, as one program. The layer is told which experts
+it holds, routes over all of them and returns its own experts' part of the
+result. On one chip nothing is exchanged, and nothing stands in for the
+absent chips.
 """
 
 from __future__ import annotations
 
+import functools
 from typing import Callable, NamedTuple, Optional, Tuple
 
 import jax
@@ -31,7 +37,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from ray_tpu.ops.grouped_matmul import grouped_matmul
+from ray_tpu.ops import moe_rows
+from ray_tpu.ops.grouped_matmul import TILING, grouped_matmul
 
 
 def top1_router(logits: jax.Array) -> Tuple[jax.Array, jax.Array]:
@@ -150,27 +157,49 @@ def route(h: jax.Array, router: jax.Array, bias: Optional[jax.Array], *,
                    experts=experts, gates=gates)
 
 
-@jax.checkpoint
+def rows_worked(group_sizes: jax.Array, tile: int = TILING[0]) -> jax.Array:
+    """The sorted rows ``held_experts``' passes touch for these groups:
+    whole tiles over the pairs routed here, the trip count of every pass
+    times the tile."""
+    return moe_rows.worked_tiles(jnp.sum(group_sizes), tile) * tile
+
+
 def held_experts(h: jax.Array, routing: Routing, e_gate: jax.Array,
-                 e_up: jax.Array, e_down: jax.Array) -> jax.Array:
+                 e_up: jax.Array, e_down: jax.Array,
+                 tile: int = TILING[0]) -> jax.Array:
     """This chip's experts' part of the layer: h [T, D], the held experts'
     SwiGLU weights [held, D, F], [held, D, F], [held, F, D] in the
     activations' type -> [T, D], ``sum over a token's held experts of gate
     * expert(h)``.
 
-    Every one of the T x k sorted pairs is a row here, so no pair is
-    dropped whatever the routing. Rows of pairs whose expert lives
-    elsewhere lie behind the groups; they enter as zeros and leave as
-    zeros whatever the grouped product writes there. The grouped products
-    cost the rows routed here; the gather and the scatter-add cost every
-    row. The rows are made again in the backward pass and not kept."""
-    T, D = h.shape
-    held = routing.held[:, None]
-    xs = jnp.where(held, h[routing.token], 0)
-    g = grouped_matmul(xs, e_gate, routing.group_sizes)
-    u = grouped_matmul(xs, e_up, routing.group_sizes)
-    ys = grouped_matmul(jax.nn.silu(g) * u, e_down, routing.group_sizes)
-    # Masked before the gate meets it: the gate's gradient reads these rows.
-    ys = jnp.where(held, ys, 0).astype(jnp.float32) * routing.gate[:, None]
-    out = jnp.zeros((T, D), jnp.float32).at[routing.token].add(ys)
+    Every one of the T x k sorted pairs has a row here, so no pair is
+    dropped whatever the routing, and the rows of pairs whose expert lives
+    elsewhere lie behind the groups, where no pass goes: each one works on
+    ``rows_worked`` rows, in tiles of ``tile`` (the grouped products' row
+    tile). What a pass leaves behind the routed pairs is undefined, so
+    whatever reads a whole array selects by ``held`` first. The rows are
+    made again in the backward pass and not kept."""
+    return jax.checkpoint(functools.partial(_held_experts, tile))(
+        h, routing, e_gate, e_up, e_down)
+
+
+def _held_experts(tile, h, routing, e_gate, e_up, e_down):
+    n = jnp.sum(routing.group_sizes)
+    rows = functools.partial(moe_rows.map_rows, tile=tile)
+    xs = moe_rows.gather_rows(h, routing.token, n, tile=tile)
+    xs_gate, xs_up = moe_rows.twice(xs, n, tile=tile)
+    g = grouped_matmul(xs_gate, e_gate, routing.group_sizes)
+    u = grouped_matmul(xs_up, e_up, routing.group_sizes)
+    ys = grouped_matmul(rows(_swiglu_rows, n, g, u), e_down,
+                        routing.group_sizes)
+    # Masked before the gate meets ys: the gate's gradient reads ys' rows.
+    gate = jnp.where(routing.held, routing.gate, 0)[:, None]
+    ys = rows(lambda y, w: y.astype(jnp.float32) * w, n, ys, gate)
+    out = moe_rows.scatter_add_rows(ys, routing.token, n, h.shape[0],
+                                    tile=tile)
     return out.astype(h.dtype)
+
+
+def _swiglu_rows(g: jax.Array, u: jax.Array) -> jax.Array:
+    return (jax.nn.silu(g.astype(jnp.float32))
+            * u.astype(jnp.float32)).astype(g.dtype)

@@ -33,7 +33,9 @@ the outermost one on the path is the operation's segment, ``norm`` and
 ``KERNELS`` (``flash_fwd``, ``flash_fwd_grouped``, ``flash_bwd_dq``,
 ``flash_bwd_dkv``), which is also the kernel instruction's own name
 (``%flash_fwd.6``); on an expert layer's grouped products ``moe_gmm``
-or ``moe_tgmm``. ``transpose(jvp(...))`` on the path marks the backward
+or ``moe_tgmm``, on its passes over the rows routed here
+``moe_gather_rows``, ``moe_map_rows`` or ``moe_scatter_rows``.
+``transpose(jvp(...))`` on the path marks the backward
 pass; an operation with names and no segment is the optimizer's. The
 scopes are written in ``models/transformer.py`` and ``ops/``; they exist
 while a program is traced and cost nothing when it runs.
@@ -63,9 +65,13 @@ SEGMENTS = ("seg.embed", "seg.attn_proj", "seg.attn_core", "seg.mlp",
             "seg.conv", "seg.moe_route", "seg.moe_experts")
 # The Pallas kernels of ``ops/flash_attention.py``: each one's ``name=``
 # and the scope around its call; and of ``ops/grouped_matmul.py``: the
-# scope around each call of JAX's own grouped-matmul kernels.
+# scope around each call of JAX's own grouped-matmul kernels; and of
+# ``ops/moe_rows.py``: the scope around each pass over the sorted rows
+# an expert layer works on (the row-wise one a Pallas call of that name,
+# the gather and the scatter-add each a loop around XLA's own).
 KERNELS = ("flash_fwd", "flash_fwd_grouped", "flash_bwd_dq",
-           "flash_bwd_dkv", "moe_gmm", "moe_tgmm")
+           "flash_bwd_dkv", "moe_gmm", "moe_tgmm",
+           "moe_gather_rows", "moe_map_rows", "moe_scatter_rows")
 
 
 @contextlib.contextmanager

@@ -2,8 +2,9 @@
 layer against the LFM2 family's plain reference (the shares add up, no
 token is dropped under any imbalance, top-k selects by score plus bias and
 weighs by score), the program's load counter, the selection bias drawn from
-the seed and held, and the two readers that divide the family's cost. The model and
-the helpers are ``test_pattern_model.py``'s."""
+the seed and held, the two readers that divide the family's cost and the one
+that reads the rows the layer's passes work on. The model and the helpers are
+``test_pattern_model.py``'s."""
 
 import functools
 import os
@@ -182,6 +183,27 @@ def test_the_experts_roofline_readers_divide_the_familys_cost(monkeypatch):
     assert kernels(ctx) is None
     monkeypatch.setattr(segments, "segment_ms", lambda _ctx, _seg: None)
     assert whole(ctx) is None
+
+
+def test_the_rows_worked_reader_reads_the_programs_counter(monkeypatch):
+    """Whole tiles over the pairs routed to the held experts, over every
+    sorted pair of a layer, mean over the expert layers; nothing where
+    the family counts no load or the program has no ``rows_worked``."""
+    hp = {"batch": 2, "seq_len": 24}
+    FAMILY.make_params(MODEL, SEED + 1)
+    read = harness.reader(["perfbench"], "train.moe_rows_worked_share.lfm2")
+    ctx = {"family": FAMILY, "model": MODEL, "step_cfg": hp}
+    load = FAMILY.moe_load(MODEL, hp)
+    layers = [row for rows in load.values() for row in rows]
+    assert len(layers) == 4
+    tile, pairs = moe.TILING[0], 2 * 24 * MODEL["num_experts_per_tok"]
+    want = [-(-int(row.sum()) // tile) * tile / pairs for row in layers]
+    assert read(ctx) == pytest.approx(100 * sum(want) / 4)
+    assert read({}) is None
+    dense = harness.family(["perfbench"], "dense")
+    assert read(dict(ctx, family=dense)) is None
+    monkeypatch.delattr(moe, "rows_worked")     # the parent's program
+    assert read(ctx) is None
 
 
 @pytest.mark.parametrize("seed", [1, 2])

@@ -212,6 +212,44 @@ def test_grouped_matmul_kernels_compile_under_their_names(tpu, rows):
     assert "ragged-dot" not in text
 
 
+@pytest.mark.parametrize("rows", [8192, 32768])
+def test_moe_row_passes_compile_under_their_names(tpu, rows):
+    """The held experts' passes over the sorted rows at the LFM2 cell's
+    widths, over every sorted pair of a layer and over a quarter of them:
+    the gather and the scatter-add are loops with a trip count read on the
+    device, the row-wise pass one kernel with such a grid, each under the
+    program's name for it, forward and transposed."""
+    from ray_tpu.ops import moe_rows
+    from ray_tpu.parallel.moe import _swiglu_rows
+    from ray_tpu.util import profiling
+
+    one = SingleDeviceSharding(tpu[0])
+    tokens = rows // 4
+    assert moe_rows.rows_accept(rows, moe_rows.TILING[0], 2048, 1536)
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one)
+
+    def layer(h, token, n, g, u):
+        xs = moe_rows.gather_rows(h, token, n)
+        a = moe_rows.map_rows(_swiglu_rows, n, g, u)
+        out = moe_rows.scatter_add_rows(xs.astype(jnp.float32), token, n,
+                                        tokens)
+        return out.sum() + a.astype(jnp.float32).sum()
+
+    text = jax.jit(jax.grad(layer, argnums=(0, 3, 4))).lower(
+        shape((tokens, 2048), jnp.bfloat16), shape((rows,), jnp.int32),
+        shape((), jnp.int32), shape((rows, 1536), jnp.bfloat16),
+        shape((rows, 1536), jnp.bfloat16)).compile().as_text()
+    assert "conditional(" not in text
+    assert len(re.findall(r"\bwhile\(", text)) == 2     # a sum needs no forward
+    calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    assert sum("moe_map_rows" in line for line in calls) == 1
+    for name in ("moe_gather_rows", "moe_scatter_rows", "moe_map_rows"):
+        assert name in profiling.KERNELS
+        assert re.search(r"(?<![\w.])" + name + r"(?![\w.])", text), name
+
+
 def test_rms_norm_fused_compiles(tpu):
     one = SingleDeviceSharding(tpu[0])
     x = jax.ShapeDtypeStruct((8, 1024, 2048), jnp.bfloat16, sharding=one)
@@ -339,3 +377,50 @@ def test_train_step_names_its_kernels_and_its_fusions(tpu):
     assert set(profiling.SEGMENTS) - pattern <= {
         r["segment"] for r in table.values()}
 
+
+def _lower_lfm2_step(dev):
+    """The LFM2 cell's AdamW step as its family builds it, at the cell's
+    size, lowered for ``dev``."""
+    from perfbench import harness
+
+    cell = harness.load_cell("lfm2-24b-a2b-train.seq8k")
+    family = harness.family(cell["paths"], cell["config"]["family"])
+    step, init = family.build_step(cell["config"])
+    params = jax.eval_shape(lambda: family.make_params(
+        harness.run_model(cell["config"]), 0))
+    args = (params, jax.eval_shape(init, params),
+            jax.eval_shape(lambda: harness.seed_key(0)))
+    return step.lower(*_abstract(args, SingleDeviceSharding(dev)), 0)
+
+
+MOE_NAMES = ("moe_gmm", "moe_tgmm", "moe_gather_rows", "moe_map_rows",
+             "moe_scatter_rows")
+
+
+def test_the_lfm2_train_step_is_one_program_whatever_the_routing(tpu):
+    """Lowered for the v5e at the cell's size, the step holds the grouped
+    kernels and the row passes under their names and no branch: the
+    expert layer is the same instructions from no pair routed here to all
+    of them. (Lowered only: the compile, a minute of every core, is the
+    slow test below.)"""
+    text = _lower_lfm2_step(tpu[0]).as_text(debug_info=True)
+    assert "stablehlo.case" not in text and "stablehlo.if" not in text
+    assert text.count("stablehlo.while") >= 4      # scans and row loops
+    for name in MOE_NAMES + ("flash_fwd",):
+        assert re.search(r"(?<![\w.])" + name + r"(?![\w.])", text), name
+
+
+@pytest.mark.slow
+def test_the_lfm2_train_step_compiles_and_fits_the_chip(tpu):
+    """The same step through the v5e's compiler: it fits the chip and no
+    ``conditional`` came of it. Slow-marked because the compile keeps
+    every core of this sandbox busy for most of a minute, which timing
+    tests in the other workers do not survive; run it by hand before
+    spending chip time on the cell
+    (``pytest tests/test_tpu_aot.py -m slow``)."""
+    compiled = _lower_lfm2_step(tpu[0]).compile()
+    _fits(compiled)
+    text = compiled.as_text()
+    assert "conditional(" not in text
+    for name in MOE_NAMES:
+        assert re.search(r"(?<![\w.])" + name + r"(?![\w.])", text), name
