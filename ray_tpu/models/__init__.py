@@ -16,7 +16,6 @@ from ray_tpu.models.transformer import (
     make_spmd_train_step,
     param_specs,
     prefill_chunk,
-    prefill_with_cache,
     verify_step,
 )
 
@@ -31,7 +30,6 @@ __all__ = [
     "make_spmd_train_step",
     "param_specs",
     "prefill_chunk",
-    "prefill_with_cache",
     "shift_params",
     "verify_step",
 ]

@@ -8,29 +8,32 @@ SURVEY.md §2.6): everything here is built for the MXU and the Mesh:
 - Layers are **stacked** ([L, ...] leading axis) and run under ``lax.scan``
   → one compiled layer body regardless of depth, with optional
   ``jax.checkpoint`` rematerialisation for HBM.
-- Two execution paths:
+- Two execution paths over one layer (``_layer``):
   1. ``forward`` / ``loss_fn``: GSPMD path — logical sharding constraints
      (ShardingRules) and jit; XLA inserts the dp/fsdp/tp collectives.
   2. ``make_spmd_train_step``: manual path — ``jax.shard_map`` over the
      full (dp, pp, tp, sp, ep) mesh with explicit collectives: Megatron
-     column/row TP with psum, ring attention over sp, MoE all_to_all over
-     ep, GPipe ppermute over pp, gradient psum-mean over dp. This is the
-     multi-chip training step the driver dry-runs.
+     column/row TP with psum, ring attention over sp, GPipe ppermute over
+     pp, gradient psum-mean over dp. This is the multi-chip training step
+     the driver dry-runs.
 
-GQA attention with rotary embeddings, RMSNorm, SwiGLU MLP, optional MoE
-layers every ``moe_every``-th layer.
+GQA attention with rotary embeddings, RMSNorm, SwiGLU MLP.
 
-**A layer pattern** (``forward``/``loss_fn``, the training body). A
-configuration with ``layer_types`` or ``router_experts`` is a stack of
-layers of several kinds: the sequence operator of a layer is causal
-attention or a gated short convolution, its feed-forward the dense SwiGLU
-(the ``num_dense_layers`` leading ones) or the routed experts as published
-(``parallel/moe.py``: sigmoid or softmax scores, top-k over scores plus a
-bias, renormalised gates, no token dropped, and only the experts this
-chip holds computed). The parameters are stacked per kind
-(``params["layers"][kind]``), and each run of equal layers in published
-order is one ``lax.scan`` (``layer_runs``). The cached serving bodies
-run one kind of layer and refuse such a configuration.
+**Layer kinds.** A stack is layers of one or several kinds
+(``layer_kind``): the sequence operator of a layer is causal attention or
+a gated short convolution (``layer_types``), its feed-forward the dense
+SwiGLU (the ``num_dense_layers`` leading ones) or the routed experts as
+published (``router_experts``; ``parallel/moe.py``: sigmoid or softmax
+scores, top-k over scores plus a bias, renormalised gates, no token
+dropped, and only the experts this chip holds computed). ``_kind_leaves``
+describes a kind's parameters once; the tree, its specs and the manual
+step's specs are made from that. Each run of equal layers in published
+order is one ``lax.scan`` (``layer_runs``) over its kind's stack. A
+configuration with neither ``layer_types`` nor ``router_experts`` is one
+run of ``attention_dense`` and keeps the flat tree ``params["layers"]
+[leaf]``; every other stacks per kind, ``params["layers"][kind][leaf]``
+(``_stacks``). The cached serving bodies run the flat layout and refuse
+the other.
 """
 
 from __future__ import annotations
@@ -42,13 +45,11 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ray_tpu.parallel.mesh import mesh_shape
 from ray_tpu.parallel import moe
-from ray_tpu.parallel.moe import moe_dispatch_combine
 from ray_tpu.parallel.ring_attention import ring_attention
 from ray_tpu.parallel.sharding import ShardingRules
 
@@ -63,10 +64,6 @@ class TransformerConfig:
     d_ff: int = 1376
     max_seq_len: int = 2048
     rope_theta: float = 10000.0
-    # MoE: 0 = dense; otherwise every `moe_every`-th layer is MoE.
-    num_experts: int = 0
-    moe_every: int = 2
-    capacity_factor: float = 1.25
     dtype: Any = jnp.bfloat16
     remat: bool = False
     # The width of a head; None: d_model // n_heads.
@@ -76,7 +73,7 @@ class TransformerConfig:
     qk_norm: bool = False
     # The head is the embedding table, transposed; no ``lm_head`` leaf.
     tie_embeddings: bool = False
-    # The layer pattern (training body only). ``layer_types``: the
+    # The layer kinds (training body only). ``layer_types``: the
     # sequence operator of each layer, "attention" or "conv" (None: all
     # attention); a conv layer's causal depthwise kernel has
     # ``conv_kernel`` taps.
@@ -108,10 +105,6 @@ class TransformerConfig:
                     f"layer_types {self.layer_types}: one of {ATTENTION!r}, "
                     f"{CONV!r} for each of the {self.n_layers} layers")
         if self.router_experts:
-            if self.num_experts:
-                raise ValueError("router_experts (top-k, dropless) and "
-                                 "num_experts (top-1, capacity) exclude "
-                                 "each other")
             held = tuple(range(self.router_experts)
                          if self.experts_held is None else self.experts_held)
             if (not held or len(set(held)) != len(held)
@@ -124,14 +117,13 @@ class TransformerConfig:
             if not 1 <= self.experts_per_token <= self.router_experts:
                 raise ValueError("experts_per_token out of the router's range")
 
-    @property
-    def patterned(self) -> bool:
-        """Layers of several kinds: parameters stacked per kind."""
-        return self.layer_types is not None or self.router_experts > 0
-
 
 ATTENTION, CONV = "attention", "conv"
 DENSE, MOE = "dense", "moe"
+# Every kind of layer, ``<operator>_<feed-forward>``; the first is the flat
+# layout's one kind.
+KINDS = tuple(f"{op}_{ffn}" for op in (ATTENTION, CONV)
+              for ffn in (DENSE, MOE))
 
 
 def layer_kind(cfg: TransformerConfig, i: int) -> str:
@@ -200,82 +192,74 @@ def _kind_counts(cfg: TransformerConfig) -> Dict[str, int]:
     return counts
 
 
+def _flat(cfg: TransformerConfig) -> bool:
+    """The layout: one kind, ``layers[leaf]``; else ``layers[kind][leaf]``."""
+    return cfg.layer_types is None and not cfg.router_experts
+
+
+def _stacks(cfg: TransformerConfig, layers) -> Dict[str, Any]:
+    """{kind: that kind's stack} of a ``layers`` tree in either layout."""
+    return {KINDS[0]: layers} if _flat(cfg) else layers
+
+
+def _layers_tree(cfg: TransformerConfig, stack_of) -> Dict[str, Any]:
+    """The ``layers`` tree in the configuration's layout, each kind's stack
+    ``stack_of(kind, layers of that kind, _kind_leaves(cfg, kind))``."""
+    stacks = {kind: stack_of(kind, n, _kind_leaves(cfg, kind))
+              for kind, n in _kind_counts(cfg).items()}
+    return stacks[KINDS[0]] if _flat(cfg) else stacks
+
+
 def _dense_init(key, shape, fan_in):
     return (jax.random.normal(key, shape, jnp.float32)
             * (1.0 / math.sqrt(fan_in)))
 
 
 def init_params(cfg: TransformerConfig, key: jax.Array) -> Dict[str, Any]:
-    """Stacked-layer param pytree. Weights f32 (master copy). A
-    patterned configuration stacks per kind: ``layers[kind][leaf]``."""
-    if cfg.patterned:
-        return _init_pattern_params(cfg, key)
-    D, F, Hd = cfg.d_model, cfg.d_ff, cfg.head_dim
-    nq, nkv, L = cfg.n_heads, cfg.n_kv_heads, cfg.n_layers
+    """Stacked-layer param pytree, ``_stacks``' layout. Weights f32
+    (master copy)."""
     # One distinct key per weight family: same-shaped families (wq/wk/wv,
     # w_gate/w_up, e_gate/e_up) must not share init, or attention/MLP
     # branches start out identical and training silently degrades.
     ks = jax.random.split(key, 16)
-    _next_family = iter(range(2, 16))
 
-    def stack(initfn):
-        keys = jax.random.split(ks[next(_next_family)], L)
-        return jax.vmap(initfn)(keys)
-
-    layers = {
-        "attn_norm": jnp.ones((L, D), jnp.float32),
-        "wq": stack(lambda k: _dense_init(k, (D, nq * Hd), D)),
-        "wk": stack(lambda k: _dense_init(k, (D, nkv * Hd), D)),
-        "wv": stack(lambda k: _dense_init(k, (D, nkv * Hd), D)),
-        "wo": stack(lambda k: _dense_init(k, (nq * Hd, D), nq * Hd)),
-        "mlp_norm": jnp.ones((L, D), jnp.float32),
-        "w_gate": stack(lambda k: _dense_init(k, (D, F), D)),
-        "w_up": stack(lambda k: _dense_init(k, (D, F), D)),
-        "w_down": stack(lambda k: _dense_init(k, (F, D), F)),
-    }
-    if cfg.num_experts:
-        E = cfg.num_experts
-        layers["router"] = stack(lambda k: _dense_init(k, (D, E), D))
-        layers["e_gate"] = stack(
-            lambda k: _dense_init(k, (E, D, F), D))
-        layers["e_up"] = stack(lambda k: _dense_init(k, (E, D, F), D))
-        layers["e_down"] = stack(lambda k: _dense_init(k, (E, F, D), F))
-    params = {
-        "embed": jax.random.normal(ks[0], (cfg.vocab_size, D),
-                                   jnp.float32) * 0.02,
-        "layers": layers,
-        "final_norm": jnp.ones((D,), jnp.float32),
-    }
-    if not cfg.tie_embeddings:
-        params["lm_head"] = _dense_init(ks[1], (D, cfg.vocab_size), D)
-    return params
-
-
-def _init_pattern_params(cfg: TransformerConfig, key: jax.Array
-                         ) -> Dict[str, Any]:
-    k_embed, k_head, k_layers = jax.random.split(key, 3)
-    layers = {}
-    for ki, (kind, n) in enumerate(sorted(_kind_counts(cfg).items())):
-        k_kind = jax.random.fold_in(k_layers, ki)
-        layers[kind] = {}
-        for li, (name, (shape, fan_in, _roles)) in enumerate(
-                _kind_leaves(cfg, kind).items()):
+    def stack_of(kind, n, leaves):
+        # A seed means for the flat layout what it has meant: the drawn
+        # families of ``attention_dense`` take keys 2, 3, ... in the leaves'
+        # order; a further kind folds its place among ``KINDS`` into them.
+        family = iter(range(2, 16))
+        stack = {}
+        for name, (shape, fan_in, _roles) in leaves.items():
             if fan_in:
-                layers[kind][name] = _dense_init(
-                    jax.random.fold_in(k_kind, li), (n,) + shape, fan_in)
+                k = ks[next(family)]
+                if kind != KINDS[0]:
+                    k = jax.random.fold_in(k, KINDS.index(kind))
+                stack[name] = jax.vmap(
+                    lambda k: _dense_init(k, shape, fan_in))(
+                        jax.random.split(k, n))
             else:
                 fill = jnp.ones if fan_in is None else jnp.zeros
-                layers[kind][name] = fill((n,) + shape, jnp.float32)
+                stack[name] = fill((n,) + shape, jnp.float32)
+        return stack
+
     params = {
-        "embed": jax.random.normal(k_embed, (cfg.vocab_size, cfg.d_model),
+        "embed": jax.random.normal(ks[0], (cfg.vocab_size, cfg.d_model),
                                    jnp.float32) * 0.02,
-        "layers": layers,
+        "layers": _layers_tree(cfg, stack_of),
         "final_norm": jnp.ones((cfg.d_model,), jnp.float32),
     }
     if not cfg.tie_embeddings:
         params["lm_head"] = _dense_init(
-            k_head, (cfg.d_model, cfg.vocab_size), cfg.d_model)
+            ks[1], (cfg.d_model, cfg.vocab_size), cfg.d_model)
     return params
+
+
+def _layer_specs(cfg: TransformerConfig, lead, role) -> Dict[str, Any]:
+    """PartitionSpecs of the ``layers`` tree: ``lead`` on the stacked
+    axis, on each further axis the mesh axis ``role`` gives its role."""
+    return _layers_tree(cfg, lambda _kind, _n, leaves: {
+        name: P(lead, *(role[x] for x in roles))
+        for name, (_shape, _fan_in, roles) in leaves.items()})
 
 
 def param_specs(cfg: TransformerConfig,
@@ -287,44 +271,26 @@ def param_specs(cfg: TransformerConfig,
     2D weights shard wide-axis on tp, narrow on fsdp (ZeRO-3).
     """
     r = rules or ShardingRules()
-    st, tp, fs = r.stage, r.mlp, r.fsdp_shard
-    if cfg.patterned:
-        # A kind's stack is no contiguous block of layers: no stage axis.
-        role = {"tp": tp, "fsdp": fs, "expert": r.expert, None: None}
-        specs = {
-            "embed": P(r.vocab, None),
-            "layers": {kind: {name: P(None, *(role[x] for x in roles))
-                              for name, (_s, _f, roles)
-                              in _kind_leaves(cfg, kind).items()}
-                       for kind in _kind_counts(cfg)},
-            "final_norm": P(None),
-        }
-        if not cfg.tie_embeddings:
-            specs["lm_head"] = P(fs, r.vocab)
-        return specs
-    layers = {
-        "attn_norm": P(st, None),
-        "wq": P(st, fs, tp), "wk": P(st, fs, tp), "wv": P(st, fs, tp),
-        "wo": P(st, tp, fs),
-        "mlp_norm": P(st, None),
-        "w_gate": P(st, fs, tp), "w_up": P(st, fs, tp),
-        "w_down": P(st, tp, fs),
-    }
-    if cfg.num_experts:
-        layers.update({
-            "router": P(st, None, None),
-            "e_gate": P(st, r.expert, None, tp),
-            "e_up": P(st, r.expert, None, tp),
-            "e_down": P(st, r.expert, tp, None),
-        })
+    # Of several runs, a kind's stack is no contiguous block of layers: no
+    # stage axis.
+    stage = r.stage if len(layer_runs(cfg)) == 1 else None
     specs = {
         "embed": P(r.vocab, None),
-        "layers": layers,
+        "layers": _layer_specs(cfg, stage, {
+            "tp": r.mlp, "fsdp": r.fsdp_shard, "expert": r.expert,
+            None: None}),
         "final_norm": P(None),
     }
     if not cfg.tie_embeddings:
-        specs["lm_head"] = P(fs, r.vocab)
+        specs["lm_head"] = P(r.fsdp_shard, r.vocab)
     return specs
+
+
+def _stage_params_spec(cfg: TransformerConfig) -> Dict[str, P]:
+    """in_specs for the stacked layer tree inside ``make_spmd_train_step``'s
+    shard_map: leading layer axis sharded over pp, wide weight axes over
+    tp."""
+    return _layer_specs(cfg, "pp", {"tp": "tp", "fsdp": None, None: None})
 
 
 @jax.named_scope("norm")
@@ -437,84 +403,26 @@ def _attn_out(cfg, lp, x, o, tp_axis=None):
     return x + o
 
 
-def _layer_fn(cfg: TransformerConfig, lp: Dict[str, jax.Array], x: jax.Array,
-              positions: jax.Array, layer_idx: jax.Array,
-              sp_axis: Optional[str] = None,
-              ep_axis: Optional[str] = None,
-              tp_axis: Optional[str] = None) -> jax.Array:
-    """One transformer block. In manual mode the weights arriving here are
-    the local TP shard (wide axis pre-sliced) and attention/MoE take the
-    collective axes to use; in GSPMD mode all axes are None."""
-    q, k, v = _project_qkv(cfg, lp, x, positions)
-    if sp_axis is not None:
-        with jax.named_scope("seg.attn_core"):
-            Hq, Hkv = q.shape[2], k.shape[2]
-            if Hq != Hkv:
-                k = jnp.repeat(k, Hq // Hkv, axis=2)
-                v = jnp.repeat(v, Hq // Hkv, axis=2)
-            o = ring_attention(
-                q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
-                v.transpose(0, 2, 1, 3), axis_name=sp_axis, causal=True,
-            ).transpose(0, 2, 1, 3)
-    else:
-        o = _attention_dense(q, k, v)
-    x = _attn_out(cfg, lp, x, o, tp_axis)
-    return _mlp_residual(cfg, lp, x, layer_idx,
-                         tp_axis=tp_axis, ep_axis=ep_axis)
+@jax.named_scope("seg.attn_core")
+def _attention_ring(sp_axis, q, k, v):
+    """``_attention_dense``'s place in the manual step where the sequence
+    is sharded over ``sp_axis``: causal ring attention, K/V repeated to the
+    query heads."""
+    Hq, Hkv = q.shape[2], k.shape[2]
+    if Hq != Hkv:
+        k = jnp.repeat(k, Hq // Hkv, axis=2)
+        v = jnp.repeat(v, Hq // Hkv, axis=2)
+    return ring_attention(
+        q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
+        v.transpose(0, 2, 1, 3), axis_name=sp_axis, causal=True,
+    ).transpose(0, 2, 1, 3)
 
 
 @jax.named_scope("seg.mlp")
-def _mlp_residual(cfg, lp, x, layer_idx, tp_axis=None, ep_axis=None):
-    """MLP norm, ``_mlp_block`` and the residual add, x [B, S, D]."""
+def _mlp_residual(cfg, lp, x, tp_axis=None):
+    """MLP norm, SwiGLU and the residual add, x [B, S, D]."""
     h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
-    return x + _mlp_block(cfg, lp, h, layer_idx,
-                          tp_axis=tp_axis, ep_axis=ep_axis)
-
-
-def _mlp_block(cfg: TransformerConfig, lp: Dict[str, jax.Array],
-               h: jax.Array, layer_idx: jax.Array,
-               tp_axis: Optional[str] = None,
-               ep_axis: Optional[str] = None) -> jax.Array:
-    """Post-norm MLP/MoE for one layer over ``h`` [B, S, D] — shared
-    between the training layer body and the decode path (where S == 1)."""
-    dt = cfg.dtype
-    B, S, D = h.shape
-    if cfg.num_experts and "router" in lp:
-        is_moe = (layer_idx % cfg.moe_every) == (cfg.moe_every - 1)
-        logits = (h.astype(jnp.float32)
-                  @ lp["router"].astype(jnp.float32)).reshape(
-            B * S, cfg.num_experts)
-
-        def expert_fn(tok):  # [E_local, C, D]
-            g = jnp.einsum("ecd,edf->ecf", tok, lp["e_gate"].astype(dt))
-            u = jnp.einsum("ecd,edf->ecf", tok, lp["e_up"].astype(dt))
-            out = jnp.einsum(
-                "ecf,efd->ecd", jax.nn.silu(g) * u, lp["e_down"].astype(dt))
-            if tp_axis is not None:
-                out = lax.psum(out, tp_axis)  # row-parallel e_down
-            return out
-
-        if ep_axis is not None:
-            moe_out = moe_dispatch_combine(
-                h.reshape(B * S, D), logits, expert_fn,
-                num_experts=cfg.num_experts,
-                capacity_factor=cfg.capacity_factor,
-                axis_name=ep_axis).reshape(B, S, D)
-        else:
-            # Dense fallback: run all experts, weight by top-1 gate.
-            probs = jax.nn.softmax(logits, axis=-1)
-            top = jnp.argmax(probs, axis=-1)
-            gate = probs[jnp.arange(B * S), top].astype(dt)
-            toks = jnp.broadcast_to(
-                h.reshape(1, B * S, D), (cfg.num_experts, B * S, D))
-            outs = expert_fn(toks)
-            moe_out = (outs[top, jnp.arange(B * S)]
-                       * gate[:, None]).reshape(B, S, D)
-        if cfg.moe_every == 1:
-            return moe_out  # all layers MoE: skip the dense branch
-        dense_out = _swiglu(cfg, lp, h, tp_axis)
-        return jnp.where(is_moe, moe_out, dense_out)
-    return _swiglu(cfg, lp, h, tp_axis)
+    return x + _swiglu(cfg, lp, h, tp_axis)
 
 
 def _swiglu(cfg, lp, h, tp_axis):
@@ -565,36 +473,48 @@ def _moe_residual(cfg, lp, x):
         return x + out.reshape(B, S, D), routing.group_sizes
 
 
-def _pattern_layer(cfg: TransformerConfig, kind: str, lp, x, positions):
-    """One layer of ``kind`` (``layer_kind``) of a patterned stack ->
-    (x, the tokens each held expert got; None for a dense layer)."""
+def _layer(cfg: TransformerConfig, kind: str, lp, x, positions,
+           attention, tp_axis):
+    """One layer of ``kind`` (``layer_kind``) -> (x, the tokens each held
+    expert got; None for a dense feed-forward). ``attention(q, k, v)`` is
+    the attention to use. In the manual step the weights arriving here are
+    the local TP shard (wide axis pre-sliced) and ``tp_axis`` the axis the
+    row-parallel products sum over; in GSPMD mode it is None."""
     op, ffn = kind.split("_")
     if op == CONV:
         x = _conv_residual(cfg, lp, x)
     else:
         q, k, v = _project_qkv(cfg, lp, x, positions)
-        x = _attn_out(cfg, lp, x, _attention_dense(q, k, v))
+        x = _attn_out(cfg, lp, x, attention(q, k, v), tp_axis)
     if ffn == MOE:
         return _moe_residual(cfg, lp, x)
-    return _mlp_residual(cfg, lp, x, 0), None
+    return _mlp_residual(cfg, lp, x, tp_axis), None
 
 
-def _pattern_layers(cfg: TransformerConfig, layers, x, positions, constrain):
-    """The stack of a patterned configuration: one ``lax.scan`` for each
-    run of equal layers, over that run's slice of its kind's stack."""
-    counts = _kind_counts(cfg)
+def _scan_layers(cfg: TransformerConfig, kind: str, stack, x, positions,
+                 attention, tp_axis, constrain):
+    """A run of equal layers: one ``lax.scan`` of ``_layer`` over
+    ``stack``, whose leaves lead with the run's layers."""
+
+    def body(x, lp):
+        run = partial(_layer, cfg, kind, lp, positions=positions,
+                      attention=attention, tp_axis=tp_axis)
+        x, _load = jax.checkpoint(run)(x) if cfg.remat else run(x)
+        return constrain(x, "batch", "sequence", "embed"), None
+
+    return lax.scan(body, x, stack)[0]
+
+
+def _layers(cfg: TransformerConfig, layers, x, positions, constrain):
+    """The stack: each run of equal layers in published order over that
+    run's slice of its kind's stack."""
+    counts, stacks = _kind_counts(cfg), _stacks(cfg, layers)
     for kind, start, count in layer_runs(cfg):
-        stack = layers[kind]
+        stack = stacks[kind]
         if count != counts[kind]:
             stack = jax.tree.map(lambda a: a[start:start + count], stack)
-
-        def body(x, lp, kind=kind):
-            run = partial(_pattern_layer, cfg, kind, lp,
-                          positions=positions)
-            x, _load = jax.checkpoint(run)(x) if cfg.remat else run(x)
-            return constrain(x, "batch", "sequence", "embed"), None
-
-        x, _ = lax.scan(body, x, stack)
+        x = _scan_layers(cfg, kind, stack, x, positions, _attention_dense,
+                         None, constrain)
     return x
 
 
@@ -607,10 +527,12 @@ def moe_load(cfg: TransformerConfig, params: Dict[str, Any],
     load: Dict[str, list] = {}
     x = _embed(cfg, params, tokens)
     positions = jnp.broadcast_to(jnp.arange(S), (B, S))
+    stacks = _stacks(cfg, params["layers"])
     for kind, start, count in layer_runs(cfg):
         for at in range(start, start + count):
-            lp = jax.tree.map(lambda a: a[at], params["layers"][kind])
-            x, sizes = _pattern_layer(cfg, kind, lp, x, positions)
+            lp = jax.tree.map(lambda a: a[at], stacks[kind])
+            x, sizes = _layer(cfg, kind, lp, x, positions,
+                              _attention_dense, None)
             if sizes is not None:
                 load.setdefault(kind, []).append(sizes)
     return {kind: jnp.stack(sizes) for kind, sizes in load.items()}
@@ -621,7 +543,7 @@ def _refuse_pattern(cfg: TransformerConfig, body: str) -> None:
     state has no place in the paged cache yet, and an expert layer that
     holds a share gives a partial result. A wrong answer is worse than
     none."""
-    if cfg.patterned:
+    if not _flat(cfg):
         raise NotImplementedError(
             f"{body} runs attention layers with one feed-forward kind; "
             f"this configuration has a layer pattern "
@@ -646,24 +568,7 @@ def forward(cfg: TransformerConfig, params: Dict[str, Any],
     x = _embed(cfg, params, tokens)
     x = constrain(x, "batch", "sequence", "embed")
     positions = jnp.broadcast_to(jnp.arange(S), (B, S))
-    if cfg.patterned:
-        x = _pattern_layers(cfg, params["layers"], x, positions, constrain)
-        return constrain(_final_logits(cfg, params, x),
-                         "batch", "sequence", "vocab")
-
-    def body(carry, lp_with_idx):
-        x = carry
-        lp, idx = lp_with_idx
-
-        def run(x):
-            return _layer_fn(cfg, lp, x, positions, idx)
-
-        x = jax.checkpoint(run)(x) if cfg.remat else run(x)
-        x = constrain(x, "batch", "sequence", "embed")
-        return x, None
-
-    idxs = jnp.arange(cfg.n_layers)
-    x, _ = lax.scan(body, x, (params["layers"], idxs))
+    x = _layers(cfg, params["layers"], x, positions, constrain)
     return constrain(_final_logits(cfg, params, x),
                      "batch", "sequence", "vocab")
 
@@ -700,27 +605,6 @@ def loss_fn(cfg: TransformerConfig, params, tokens, targets,
 # Manual SPMD training step: shard_map over (dp, pp, tp, sp, ep).
 # ---------------------------------------------------------------------------
 
-def _stage_params_spec(cfg: TransformerConfig) -> Dict[str, P]:
-    """in_specs for the stacked layer tree inside shard_map: leading layer
-    axis sharded over pp, wide weight axes over tp, experts over ep."""
-    sp = {
-        "attn_norm": P("pp", None),
-        "wq": P("pp", None, "tp"), "wk": P("pp", None, "tp"),
-        "wv": P("pp", None, "tp"), "wo": P("pp", "tp", None),
-        "mlp_norm": P("pp", None),
-        "w_gate": P("pp", None, "tp"), "w_up": P("pp", None, "tp"),
-        "w_down": P("pp", "tp", None),
-    }
-    if cfg.num_experts:
-        sp.update({
-            "router": P("pp", None, None),
-            "e_gate": P("pp", "ep", None, "tp"),
-            "e_up": P("pp", "ep", None, "tp"),
-            "e_down": P("pp", "ep", "tp", None),
-        })
-    return sp
-
-
 def make_spmd_train_step(cfg: TransformerConfig, mesh: Mesh, params,
                          optimizer=None, n_microbatches: int = 2):
     """Build the manual multi-chip training step.
@@ -733,7 +617,7 @@ def make_spmd_train_step(cfg: TransformerConfig, mesh: Mesh, params,
     ``jax.eval_shape`` abstract values).
 
     Requires cfg.n_layers % pp == 0, heads % tp == 0, batch % (dp*mb) == 0,
-    seq % sp == 0, experts % ep == 0 (when MoE).
+    seq % sp == 0.
     """
     import optax
 
@@ -741,53 +625,35 @@ def make_spmd_train_step(cfg: TransformerConfig, mesh: Mesh, params,
     if optimizer is None:
         optimizer = optax.adamw(3e-4)
     shape = mesh_shape(mesh)
-    pp, tp, sp_n, ep_n = shape["pp"], shape["tp"], shape["sp"], shape["ep"]
+    pp, tp, sp_n = shape["pp"], shape["tp"], shape["sp"]
     if cfg.n_layers % pp:
         raise ValueError(f"n_layers {cfg.n_layers} % pp {pp} != 0")
     if cfg.n_heads % tp or cfg.n_kv_heads % tp:
         raise ValueError("heads must divide tp")
-    if cfg.num_experts and cfg.num_experts % ep_n:
-        raise ValueError("experts must divide ep")
-    layers_per_stage = cfg.n_layers // pp
 
-    lp_spec = _stage_params_spec(cfg)
     pspec = {
         "embed": P(None, None),
-        "layers": lp_spec,
+        "layers": _stage_params_spec(cfg),
         "final_norm": P(None),
         "lm_head": P(None, None),
     }
     data_spec = P(("dp", "fsdp"), "sp")
 
-    sp_axis = "sp" if sp_n > 1 else None
-    ep_axis = "ep" if ep_n > 1 else None
+    attention = (partial(_attention_ring, "sp") if sp_n > 1
+                 else _attention_dense)
     tp_axis = "tp" if tp > 1 else None
 
-    def stage_fn(stage_layers, act, stage_idx):
-        """Run this pp-shard's layers_per_stage layers over activation
+    def stage_fn(stage_layers, act):
+        """Run this pp-shard's n_layers // pp layers over activation
         bucket act = (x, positions)."""
         x, positions = act
-
-        def body(carry, lp_i):
-            lp, local_i = lp_i
-            gidx = stage_idx * layers_per_stage + local_i
-
-            def run(x):
-                return _layer_fn(cfg, lp, x, positions, gidx,
-                                 sp_axis=sp_axis, ep_axis=ep_axis,
-                                 tp_axis=tp_axis)
-
-            x = jax.checkpoint(run)(carry) if cfg.remat else run(carry)
-            return x, None
-
-        x, _ = lax.scan(
-            body, x, (stage_layers, jnp.arange(layers_per_stage)))
+        x = _scan_layers(cfg, KINDS[0], stage_layers, x, positions,
+                         attention, tp_axis, lambda x, *_logical: x)
         return x, positions
 
     def local_loss(params, tokens, targets):
         """Per-shard loss: tokens [B_local, S_local] (dp×sp sharded)."""
         B, S = tokens.shape
-        stage = lax.axis_index("pp")
         x = _embed(cfg, params, tokens)
         s_idx = lax.axis_index("sp") if sp_n > 1 else 0
         positions = jnp.broadcast_to(
@@ -801,12 +667,10 @@ def make_spmd_train_step(cfg: TransformerConfig, mesh: Mesh, params,
             xs = x.reshape(mb, B // mb, S, -1)
             pos_mb = jnp.broadcast_to(positions[: B // mb], xs.shape[:3])
             out, _ = pipeline_spmd(
-                lambda lp, act: stage_fn(lp, act, lax.axis_index("pp")),
-                params["layers"], (xs, pos_mb), axis_name="pp")
+                stage_fn, params["layers"], (xs, pos_mb), axis_name="pp")
             x = out.reshape(B, S, -1)
         else:
-            x, _ = stage_fn(params["layers"], (x, positions),
-                            jnp.zeros((), jnp.int32))
+            x, _ = stage_fn(params["layers"], (x, positions))
 
         return _next_token_nll(_final_logits(cfg, params, x), targets)
 
@@ -916,52 +780,35 @@ def init_kv_cache(cfg: TransformerConfig, num_blocks: int, block_size: int,
     return {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}
 
 
-def prefill_with_cache(cfg: TransformerConfig, params, cache,
-                       tokens: jax.Array, prompt_lens: jax.Array,
-                       block_tables: jax.Array
-                       ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-    """Process right-padded prompts, writing every position's K/V into
-    the paged cache, and return the last-real-position logits.
-
-    tokens [B, S] int32 (padded rows/tails may be anything);
-    prompt_lens [B]; block_tables [B, M] with M*block_size >= S (padded
-    entries point at the null block, so out-of-prompt writes are trash
-    writes into block 0 — never another sequence's block).
-
-    Returns (logits [B, vocab] f32 at position prompt_lens-1, new cache).
-    Causality makes the padded tail invisible to every real position, so
-    the result is bit-identical to an unpadded per-sequence run.
-    """
-    _refuse_pattern(cfg, "prefill_with_cache")
-    B, S = tokens.shape
-    block_size = cache["k"].shape[2]
-    x = _embed(cfg, params, tokens)
-    positions = jnp.broadcast_to(jnp.arange(S), (B, S))
-    # Physical slot of every position: (block_tables[b, s//bs], s % bs).
-    blk = jnp.take_along_axis(block_tables, positions // block_size,
-                              axis=1)                       # [B, S]
-    off = positions % block_size
+def _cached_layers(cfg: TransformerConfig, params, cache, x, positions,
+                   attend, mesh, rules):
+    """The cached stack: x [B, S, D] at ``positions`` [B, S] through every
+    layer against the paged pools -> (x, the new cache).
+    ``attend(idx, q, k, v, ck, cv) -> (o, ck, cv)`` writes layer ``idx``'s
+    K/V into the pools and attends over them; the layer index is threaded
+    for the pools alone. Projections and feed-forward are the training
+    layer's own."""
 
     def body(carry, lp_idx):
         x, ck, cv = carry
         lp, idx = lp_idx
         q, k, v = _project_qkv(cfg, lp, x, positions)
         with jax.named_scope("seg.attn_core"):
-            ck = ck.at[idx, blk, off].set(k)
-            cv = cv.at[idx, blk, off].set(v)
-            o = _attention_dense(q, k, v, causal=True, grad=False)
+            q = _infer_constrain(q, mesh, rules, None, None, "heads",
+                                 "head_dim")
+            k = _infer_constrain(k, mesh, rules, None, None, "kv_heads",
+                                 "head_dim")
+            v = _infer_constrain(v, mesh, rules, None, None, "kv_heads",
+                                 "head_dim")
+            o, ck, cv = attend(idx, q, k, v, ck, cv)
         x = _attn_out(cfg, lp, x, o)
-        x = _mlp_residual(cfg, lp, x, idx)
+        x = _mlp_residual(cfg, lp, x)
         return (x, ck, cv), None
 
     idxs = jnp.arange(cfg.n_layers)
     (x, ck, cv), _ = lax.scan(
         body, (x, cache["k"], cache["v"]), (params["layers"], idxs))
-    with jax.named_scope("seg.head_loss"):
-        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-        last = jnp.take_along_axis(
-            x, (prompt_lens - 1)[:, None, None].clip(0), axis=1)[:, 0]
-    return _lm_head(cfg, params, last), {"k": ck, "v": cv}
+    return x, {"k": ck, "v": cv}
 
 
 def prefill_chunk(cfg: TransformerConfig, params, cache,
@@ -993,12 +840,12 @@ def prefill_chunk(cfg: TransformerConfig, params, cache,
     meaningful only for rows whose chunk completes the prompt — and the
     new cache).
     """
-    x, ck, cv = _chunk_scan(cfg, params, cache, tokens, start_pos,
-                            block_tables, mesh, rules)
+    x, cache = _chunk_scan(cfg, params, cache, tokens, start_pos,
+                           block_tables, mesh, rules)
     with jax.named_scope("seg.head_loss"):
         last = jnp.take_along_axis(
             x, (chunk_lens - 1)[:, None, None].clip(0), axis=1)[:, 0]
-    return _lm_head(cfg, params, last), {"k": ck, "v": cv}
+    return _lm_head(cfg, params, last), cache
 
 
 def _chunk_scan(cfg: TransformerConfig, params, cache, tokens, start_pos,
@@ -1006,7 +853,7 @@ def _chunk_scan(cfg: TransformerConfig, params, cache, tokens, start_pos,
     """Shared multi-token body of ``prefill_chunk`` and ``verify_step``:
     run the chunk through every layer against the paged cache, writing
     each position's K/V before it is attended, and return the final-
-    normed hidden states ``[B, C, D]`` plus the updated K/V pools."""
+    normed hidden states ``[B, C, D]`` plus the updated cache."""
     _refuse_pattern(cfg, "_chunk_scan")
     C = tokens.shape[1]
     block_size = cache["k"].shape[2]
@@ -1020,32 +867,19 @@ def _chunk_scan(cfg: TransformerConfig, params, cache, tokens, start_pos,
 
     from ray_tpu.ops.paged_attention import paged_attention_prefill
 
-    def body(carry, lp_idx):
-        x, ck, cv = carry
-        lp, idx = lp_idx
-        q, k, v = _project_qkv(cfg, lp, x, positions)
-        with jax.named_scope("seg.attn_core"):
-            q = _infer_constrain(q, mesh, rules, None, None, "heads",
-                                 "head_dim")
-            k = _infer_constrain(k, mesh, rules, None, None, "kv_heads",
-                                 "head_dim")
-            v = _infer_constrain(v, mesh, rules, None, None, "kv_heads",
-                                 "head_dim")
-            # Write the chunk's K/V, then attend over [0, position] per
-            # token — each new slot is part of its own context.
-            ck = ck.at[idx, blk, off].set(k)
-            cv = cv.at[idx, blk, off].set(v)
-            o = paged_attention_prefill(q, ck[idx], cv[idx], block_tables,
-                                        positions, mesh=mesh, rules=rules)
-        x = _attn_out(cfg, lp, x, o)
-        x = _mlp_residual(cfg, lp, x, idx)
-        return (x, ck, cv), None
+    def attend(idx, q, k, v, ck, cv):
+        # Write the chunk's K/V, then attend over [0, position] per
+        # token — each new slot is part of its own context.
+        ck = ck.at[idx, blk, off].set(k)
+        cv = cv.at[idx, blk, off].set(v)
+        o = paged_attention_prefill(q, ck[idx], cv[idx], block_tables,
+                                    positions, mesh=mesh, rules=rules)
+        return o, ck, cv
 
-    idxs = jnp.arange(cfg.n_layers)
-    (x, ck, cv), _ = lax.scan(
-        body, (x, cache["k"], cache["v"]), (params["layers"], idxs))
+    x, cache = _cached_layers(cfg, params, cache, x, positions, attend,
+                              mesh, rules)
     with jax.named_scope("seg.head_loss"):
-        return rms_norm(x, params["final_norm"], cfg.norm_eps), ck, cv
+        return rms_norm(x, params["final_norm"], cfg.norm_eps), cache
 
 
 def verify_step(cfg: TransformerConfig, params, cache,
@@ -1072,9 +906,9 @@ def verify_step(cfg: TransformerConfig, params, cache,
     position's slot (with the corrected token's K/V) before any
     subsequent step attends over it.
     """
-    x, ck, cv = _chunk_scan(cfg, params, cache, tokens, start_pos,
-                            block_tables, mesh, rules)
-    return _lm_head(cfg, params, x), {"k": ck, "v": cv}
+    x, cache = _chunk_scan(cfg, params, cache, tokens, start_pos,
+                           block_tables, mesh, rules)
+    return _lm_head(cfg, params, x), cache
 
 
 def decode_step(cfg: TransformerConfig, params, cache,
@@ -1103,29 +937,16 @@ def decode_step(cfg: TransformerConfig, params, cache,
 
     from ray_tpu.ops.paged_attention import paged_attention_decode
 
-    def body(carry, lp_idx):
-        x, ck, cv = carry
-        lp, idx = lp_idx
-        q, k, v = _project_qkv(cfg, lp, x, pos2)
-        with jax.named_scope("seg.attn_core"):
-            q = _infer_constrain(q, mesh, rules, None, None, "heads",
-                                 "head_dim")
-            k = _infer_constrain(k, mesh, rules, None, None, "kv_heads",
-                                 "head_dim")
-            v = _infer_constrain(v, mesh, rules, None, None, "kv_heads",
-                                 "head_dim")
-            # Write THIS token's k/v, then attend over [0, positions] —
-            # the new slot is part of its own context (self-attention).
-            ck = ck.at[idx, blk, off].set(k[:, 0])
-            cv = cv.at[idx, blk, off].set(v[:, 0])
-            o = paged_attention_decode(
-                q[:, 0], ck[idx], cv[idx], block_tables, context_lens,
-                mesh=mesh, rules=rules)
-        x = _attn_out(cfg, lp, x, o)
-        x = _mlp_residual(cfg, lp, x, idx)
-        return (x, ck, cv), None
+    def attend(idx, q, k, v, ck, cv):
+        # Write THIS token's k/v, then attend over [0, positions] —
+        # the new slot is part of its own context (self-attention).
+        ck = ck.at[idx, blk, off].set(k[:, 0])
+        cv = cv.at[idx, blk, off].set(v[:, 0])
+        o = paged_attention_decode(
+            q[:, 0], ck[idx], cv[idx], block_tables, context_lens,
+            mesh=mesh, rules=rules)
+        return o, ck, cv
 
-    idxs = jnp.arange(cfg.n_layers)
-    (x, ck, cv), _ = lax.scan(
-        body, (x, cache["k"], cache["v"]), (params["layers"], idxs))
-    return _final_logits(cfg, params, x[:, 0]), {"k": ck, "v": cv}
+    x, cache = _cached_layers(cfg, params, cache, x, pos2, attend, mesh,
+                              rules)
+    return _final_logits(cfg, params, x[:, 0]), cache

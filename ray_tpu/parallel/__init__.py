@@ -22,7 +22,6 @@ from ray_tpu.parallel.sharding import (
 )
 from ray_tpu.parallel.ring_attention import ring_attention
 from ray_tpu.parallel.ulysses import ulysses_attention
-from ray_tpu.parallel.moe import moe_dispatch_combine
 from ray_tpu.parallel.pipeline import pipeline_spmd
 from ray_tpu.parallel import distributed
 from ray_tpu.parallel.distributed import (
@@ -37,7 +36,6 @@ __all__ = [
     "logical_sharding",
     "make_mesh",
     "mesh_context",
-    "moe_dispatch_combine",
     "pipeline_spmd",
     "ring_attention",
     "shard_params",

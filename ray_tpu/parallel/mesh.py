@@ -7,7 +7,7 @@ Axis vocabulary (fixed across the framework):
 - ``pp``   pipeline parallel (layer stages; activations ppermute'd)
 - ``tp``   tensor parallel (hidden/head sharding inside matmuls)
 - ``sp``   sequence/context parallel (ring attention / Ulysses over tokens)
-- ``ep``   expert parallel (MoE token all_to_all)
+- ``ep``   expert parallel (an expert layer's weights shard over it)
 
 Reference role: replaces Ray Train's torch process-group setup
 (python/ray/train/torch/config.py [unverified]) and the NCCL group bootstrap

@@ -1,14 +1,4 @@
-"""Expert parallelism: MoE token dispatch/combine over the ``ep`` axis.
-
-Absent from the reference (SURVEY.md §2.4). Top-k router → capacity-bucketed
-dense dispatch (static shapes for XLA) → ``all_to_all`` to the expert's
-shard → expert MLP → ``all_to_all`` back → weighted combine. Dropped tokens
-(over capacity) pass through the residual, standard switch-transformer
-semantics.
-
-Call inside ``shard_map`` over the ``ep`` axis with experts sharded on it.
-
-**The expert layer as published** (``route``, ``held_experts``; the training
+"""The expert layer as published (``route``, ``held_experts``; the training
 body of ``models/transformer.py``). Scores are a sigmoid or a softmax of a
 float32 router product; the k experts of a token are the top k of scores
 plus a selection bias, and their gates the scores themselves, renormalised
@@ -30,7 +20,7 @@ absent chips.
 from __future__ import annotations
 
 import functools
-from typing import Callable, NamedTuple, Optional, Tuple
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -39,78 +29,6 @@ from jax import lax
 
 from ray_tpu.ops import moe_rows
 from ray_tpu.ops.grouped_matmul import TILING, grouped_matmul
-
-
-def top1_router(logits: jax.Array) -> Tuple[jax.Array, jax.Array]:
-    """logits [T, E] -> (expert_idx [T], gate [T])."""
-    idx = jnp.argmax(logits, axis=-1)
-    gate = jax.nn.softmax(logits, axis=-1)[jnp.arange(logits.shape[0]), idx]
-    return idx, gate
-
-
-def moe_dispatch_combine(
-    x: jax.Array,
-    router_logits: jax.Array,
-    expert_fn: Callable[[jax.Array], jax.Array],
-    *,
-    num_experts: int,
-    capacity_factor: float = 1.25,
-    axis_name: str = "ep",
-) -> jax.Array:
-    """x per-shard [T, D]; router_logits [T, E_global]. ``expert_fn`` maps
-    [E_local, C_total, D] -> [E_local, C_total, D] (vmapped expert MLP over
-    this shard's experts). Returns [T, D] combined output."""
-    n = lax.axis_size(axis_name)
-    T, D = x.shape
-    E = num_experts
-    if E % n:
-        raise ValueError(f"experts {E} not divisible by {axis_name} size {n}")
-    e_local = E // n
-    cap = max(1, int(capacity_factor * T / E))
-
-    idx, gate = top1_router(router_logits)
-    # Position of each token within its expert's capacity bucket.
-    onehot = jax.nn.one_hot(idx, E, dtype=jnp.int32)        # [T, E]
-    pos = jnp.cumsum(onehot, axis=0) * onehot                # 1-based
-    pos_in_expert = jnp.sum(pos, axis=-1) - 1                # [T]
-    keep = pos_in_expert < cap
-    gate = jnp.where(keep, gate, 0.0)
-
-    # Dense dispatch buffer [E, cap, D] on this shard.
-    disp = jnp.zeros((E, cap, D), x.dtype)
-    safe_pos = jnp.clip(pos_in_expert, 0, cap - 1)
-    disp = disp.at[idx, safe_pos].add(
-        jnp.where(keep[:, None], x, 0.0))
-
-    # all_to_all: every shard sends its [e_local, cap, D] slab for each peer.
-    # [E, cap, D] -> [n, e_local, cap, D] -> exchange over axis ->
-    # [n, e_local, cap, D] where leading axis is now source shard.
-    disp = disp.reshape(n, e_local, cap, D)
-    disp = lax.all_to_all(disp, axis_name, split_axis=0, concat_axis=0,
-                          tiled=True)
-    disp = disp.reshape(n, e_local, cap, D)
-    # Merge source shards into the capacity axis: [e_local, n*cap, D].
-    disp = disp.transpose(1, 0, 2, 3).reshape(e_local, n * cap, D)
-
-    out = expert_fn(disp)                                    # [e_local, n*cap, D]
-
-    # Inverse route: split capacity back per source, all_to_all home.
-    out = out.reshape(e_local, n, cap, D).transpose(1, 0, 2, 3)
-    out = out.reshape(n, e_local, cap, D)
-    out = lax.all_to_all(out, axis_name, split_axis=0, concat_axis=0,
-                         tiled=True)
-    out = out.reshape(E, cap, D)
-
-    combined = out[idx, safe_pos] * gate[:, None]
-    return jnp.where(keep[:, None], combined, 0.0)
-
-
-def load_balancing_loss(router_logits: jax.Array, expert_idx: jax.Array,
-                        num_experts: int) -> jax.Array:
-    """Switch-transformer auxiliary loss: E * <fraction routed> · <router prob>."""
-    probs = jax.nn.softmax(router_logits, axis=-1)
-    frac = jnp.mean(jax.nn.one_hot(expert_idx, num_experts), axis=0)
-    return num_experts * jnp.sum(frac * jnp.mean(probs, axis=0))
 
 
 class Routing(NamedTuple):

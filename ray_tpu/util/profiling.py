@@ -27,7 +27,7 @@ Division of labor between the two profilers:
 viewer's operation details (the ``op_name`` of each XLA operation) which
 part of the model the operation is: one of ``SEGMENTS`` (``seg.embed``,
 ``seg.attn_proj``, ``seg.attn_core``, ``seg.mlp``, ``seg.head_loss``, and
-in a patterned stack ``seg.conv``, ``seg.moe_route``, ``seg.moe_experts``;
+of the further layer kinds ``seg.conv``, ``seg.moe_route``, ``seg.moe_experts``;
 the outermost one on the path is the operation's segment, ``norm`` and
 ``rope`` are finer scopes inside), and on the flash kernels one of
 ``KERNELS`` (``flash_fwd``, ``flash_fwd_grouped``, ``flash_bwd_dq``,
@@ -58,7 +58,7 @@ from typing import Iterator, Optional
 # the ``seg.`` prefix is one no JAX primitive or transform produces.
 SEGMENTS = ("seg.embed", "seg.attn_proj", "seg.attn_core", "seg.mlp",
             "seg.head_loss",
-            # a patterned stack's layers (models/transformer.py): the gated
+            # the further layer kinds (models/transformer.py): the gated
             # short convolution with its norm and residual; an expert
             # layer's norm, router, top-k, gates and sort; its gather,
             # grouped products, weighted scatter-add and residual

@@ -1,6 +1,7 @@
 """Actor semantics (reference: python/ray/tests/test_actor.py role)."""
 
 import asyncio
+import threading
 import time
 
 import pytest
@@ -138,17 +139,28 @@ def test_async_actor(ray_start_regular):
 def test_threaded_actor(ray_start_regular):
     @ray_tpu.remote(max_concurrency=4)
     class Concurrent:
+        def __init__(self):
+            self.lock = threading.Lock()
+            self.in_flight = self.most_in_flight = 0
+
         def slow(self):
+            with self.lock:
+                self.in_flight += 1
+                self.most_in_flight = max(self.most_in_flight,
+                                          self.in_flight)
             time.sleep(0.2)
+            with self.lock:
+                self.in_flight -= 1
             return 1
 
+        def most(self):
+            return self.most_in_flight
+
     a = Concurrent.remote()
-    start = time.monotonic()
     refs = [a.slow.remote() for _ in range(4)]
     assert sum(ray_tpu.get(refs, timeout=30)) == 4
-    # 4 concurrent 0.2s sleeps must beat the 0.8s+dispatch a sequential
-    # execution needs; 0.78 keeps headroom for 1-core scheduler jitter.
-    assert time.monotonic() - start < 0.78
+    # The calls overlapped: sequential execution never has two in flight.
+    assert ray_tpu.get(a.most.remote(), timeout=30) >= 2
 
 
 def test_method_num_returns(ray_start_regular):

@@ -31,7 +31,7 @@ from ray_tpu.models import (
     forward,
     init_kv_cache,
     init_params,
-    prefill_with_cache,
+    prefill_chunk,
 )
 from ray_tpu.models.transformer import decode_step
 
@@ -130,9 +130,9 @@ def test_prefill_and_decode_match_forward(params):
     table[0, :3] = [7, 2, 11]  # deliberately non-contiguous
     toks = np.zeros((1, 8), np.int32)
     toks[0, :5] = prompt
-    logits, cache = prefill_with_cache(
-        MODEL, params, cache, jnp.asarray(toks), jnp.asarray([5]),
-        jnp.asarray(table))
+    logits, cache = prefill_chunk(
+        MODEL, params, cache, jnp.asarray(toks), jnp.asarray([0]),
+        jnp.asarray([5]), jnp.asarray(table))
     ref = forward(MODEL, params, jnp.asarray([prompt]))[0, -1]
     np.testing.assert_allclose(np.asarray(logits[0]), np.asarray(ref),
                                atol=1e-5)

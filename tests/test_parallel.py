@@ -15,7 +15,6 @@ from jax.sharding import PartitionSpec as P
 from ray_tpu.parallel import (
     MeshConfig,
     make_mesh,
-    moe_dispatch_combine,
     pipeline_spmd,
     ring_attention,
     ulysses_attention,
@@ -58,51 +57,6 @@ def test_ulysses_matches_dense(eight_device_mesh):
         mesh=mesh, in_specs=(P(None, None, "sp", None),) * 3,
         out_specs=P(None, None, "sp", None), check_vma=False))
     assert jnp.allclose(f(q, k, v), ref, atol=1e-4)
-
-
-def test_moe_dispatch_matches_dense(eight_device_mesh):
-    mesh = make_mesh(ep=8)
-    T, D, E = 64, 16, 8
-    x = jax.random.normal(jax.random.PRNGKey(0), (T, D))
-    logits = jax.random.normal(jax.random.PRNGKey(1), (T, E))
-    W = jax.random.normal(jax.random.PRNGKey(2), (E, D, D)) * 0.1
-
-    def run(x, logits, W_local):
-        return moe_dispatch_combine(
-            x, logits,
-            lambda tok: jnp.einsum("ecd,edf->ecf", tok, W_local),
-            num_experts=E, capacity_factor=float(E), axis_name="ep")
-
-    f = jax.jit(jax.shard_map(
-        run, mesh=mesh, in_specs=(P(), P(), P("ep", None, None)),
-        out_specs=P(), check_vma=False))
-    out = f(x, logits, W)
-    idx = jnp.argmax(logits, axis=-1)
-    gate = jax.nn.softmax(logits, axis=-1)[jnp.arange(T), idx]
-    want = jnp.einsum("td,tdf->tf", x, W[idx]) * gate[:, None]
-    assert jnp.allclose(out, want, atol=1e-4)
-
-
-def test_moe_drops_over_capacity(eight_device_mesh):
-    # With capacity_factor small, overflowing tokens must combine to zero
-    # (residual passthrough), not garbage.
-    mesh = make_mesh(ep=2)
-    T, D, E = 16, 4, 2
-    x = jnp.ones((T, D))
-    logits = jnp.stack([jnp.full((T,), 5.0), jnp.zeros(T)], -1)  # all -> e0
-
-    def run(x, logits, W_local):
-        return moe_dispatch_combine(
-            x, logits, lambda tok: tok, num_experts=E,
-            capacity_factor=0.25, axis_name="ep")  # cap=2/expert
-
-    f = jax.jit(jax.shard_map(
-        run, mesh=mesh, in_specs=(P(), P(), P()), out_specs=P(),
-        check_vma=False))
-    out = f(x, logits, jnp.zeros(()))
-    # first 2 tokens kept, rest dropped -> zeros
-    assert jnp.all(out[2:] == 0.0)
-    assert jnp.all(out[:2] != 0.0)
 
 
 def test_pipeline_matches_sequential_and_grads(eight_device_mesh):

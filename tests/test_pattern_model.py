@@ -28,7 +28,6 @@ from ray_tpu.models import (
     loss_fn,
     make_spmd_train_step,
     prefill_chunk,
-    prefill_with_cache,
     verify_step,
 )
 from ray_tpu.models import transformer
@@ -151,9 +150,8 @@ def test_conv_operator_matches_the_reference_and_is_causal():
     assert bool((moved[16:] == got[16:]).all())    # three taps reach t + 2
 
 
-@pytest.mark.parametrize("body", ["prefill_with_cache", "prefill_chunk",
-                                  "verify_step", "decode_step",
-                                  "make_spmd_train_step"])
+@pytest.mark.parametrize("body", ["prefill_chunk", "verify_step",
+                                  "decode_step", "make_spmd_train_step"])
 def test_the_serving_bodies_refuse_a_layer_pattern(body):
     cfg = _cfg()
     params = jax.eval_shape(functools.partial(init_params, cfg),
@@ -163,8 +161,6 @@ def test_the_serving_bodies_refuse_a_layer_pattern(body):
     cache = jax.eval_shape(functools.partial(init_kv_cache, plain, 8, 4))
     ints = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
     calls = {
-        "prefill_with_cache": lambda: prefill_with_cache(
-            cfg, params, cache, ints(2, 8), ints(2), ints(2, 4)),
         "prefill_chunk": lambda: prefill_chunk(
             cfg, params, cache, ints(2, 8), ints(2), ints(2), ints(2, 4)),
         "verify_step": lambda: verify_step(

@@ -1,7 +1,7 @@
 """Flagship-model tests: GSPMD forward + manual SPMD train-step parity.
 
 The strongest correctness statement in the suite: one optimizer step of the
-fully-sharded (dp/fsdp/pp/tp/sp/ep) shard_map training step must match a
+fully-sharded (dp/fsdp/pp/tp/sp) shard_map training step must match a
 single-device step bit-for-bit-ish (fp32 tolerance) — collective-by-
 collective parity with the unsharded math.
 """
@@ -24,10 +24,6 @@ from ray_tpu.parallel import make_mesh
 DENSE = TransformerConfig(
     vocab_size=64, d_model=32, n_layers=4, n_heads=4, n_kv_heads=4,
     d_ff=64, dtype=jnp.float32)
-MOE = TransformerConfig(
-    vocab_size=64, d_model=32, n_layers=2, n_heads=4, n_kv_heads=4,
-    d_ff=64, num_experts=4, moe_every=2, capacity_factor=16.0,
-    dtype=jnp.float32)
 
 
 def _data(cfg, B, S):
@@ -52,9 +48,8 @@ def test_forward_shapes_and_loss_finite():
     [
         (DENSE, dict(dp=2, tp=2, sp=2), 4, 1),
         (DENSE, dict(dp=2, fsdp=2, pp=2), 8, 2),
-        (MOE, dict(ep=2, tp=2, dp=2), 4, 1),
     ],
-    ids=["dp-tp-sp", "dp-fsdp-pp", "moe-ep-tp-dp"],
+    ids=["dp-tp-sp", "dp-fsdp-pp"],
 )
 def test_spmd_step_matches_single_device(eight_device_mesh, cfg, mesh_kw,
                                          B, mb):
