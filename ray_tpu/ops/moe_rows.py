@@ -45,7 +45,12 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ray_tpu.ops import backend
-from ray_tpu.ops.grouped_matmul import TILING
+
+# Rows of one trip of the gather and scatter-add loops and of one worked
+# tile of the row-wise call: a trip costs 48-67 us on the v5e whatever it
+# holds, so the tile is as tall as a group at even routing. The grouped
+# products' own row tile (``grouped_matmul.TM``) divides it.
+ROW_TILE = 512
 
 # Bytes of the widest block ``map_rows`` hands ``fn``, counted at 32 bits
 # an element: a handful of blocks, twice over for the pipeline, share the
@@ -53,7 +58,7 @@ from ray_tpu.ops.grouped_matmul import TILING
 _BLOCK_BYTES = 512 * 1024
 
 
-def worked_tiles(n_rows: jax.Array, tile: int = TILING[0]) -> jax.Array:
+def worked_tiles(n_rows: jax.Array, tile: int = ROW_TILE) -> jax.Array:
     """Row tiles the first ``n_rows`` rows touch: every pass's trip count."""
     return (n_rows + (tile - 1)) // tile
 
@@ -69,7 +74,7 @@ def _live(first: jax.Array, count: int, n_rows: jax.Array) -> jax.Array:
 
 
 def gather_rows(src: jax.Array, token: jax.Array, n_rows: jax.Array, *,
-                tile: int = TILING[0]) -> jax.Array:
+                tile: int = ROW_TILE) -> jax.Array:
     m = token.shape[0]
     if not rows_accept(m, tile):
         return jnp.where(_live(0, m, n_rows), src[token], 0)
@@ -77,7 +82,7 @@ def gather_rows(src: jax.Array, token: jax.Array, n_rows: jax.Array, *,
 
 
 def scatter_add_rows(rows: jax.Array, token: jax.Array, n_rows: jax.Array,
-                     num_tokens: int, *, tile: int = TILING[0]) -> jax.Array:
+                     num_tokens: int, *, tile: int = ROW_TILE) -> jax.Array:
     m = token.shape[0]
     if not rows_accept(m, tile):
         return jnp.zeros((num_tokens, rows.shape[1]), rows.dtype).at[
@@ -87,7 +92,7 @@ def scatter_add_rows(rows: jax.Array, token: jax.Array, n_rows: jax.Array,
 
 
 def map_rows(fn, n_rows: jax.Array, *operands: jax.Array,
-             tile: int = TILING[0]):
+             tile: int = ROW_TILE):
     """``fn`` maps blocks ``(rows, width_i)`` of the operands to one block
     or a tuple of blocks ``(rows, width_o)``, each row from its own row
     (an operand of width 1 holds a number a row)."""
@@ -97,7 +102,7 @@ def map_rows(fn, n_rows: jax.Array, *operands: jax.Array,
     return _mapped(fn, tile, jnp.asarray(n_rows, jnp.int32), *operands)
 
 
-def twice(x: jax.Array, n_rows: jax.Array, *, tile: int = TILING[0]):
+def twice(x: jax.Array, n_rows: jax.Array, *, tile: int = ROW_TILE):
     """``(x, x)`` for two readers of ``x [M, width]``. JAX would add their
     cotangents over all M rows; this adds them over the worked tiles."""
     if not rows_accept(x.shape[0], tile, x.shape[1]):

@@ -28,7 +28,8 @@ import numpy as np
 from jax import lax
 
 from ray_tpu.ops import moe_rows
-from ray_tpu.ops.grouped_matmul import TILING, grouped_matmul
+from ray_tpu.ops.grouped_matmul import grouped_matmul
+from ray_tpu.ops.moe_rows import ROW_TILE
 
 
 class Routing(NamedTuple):
@@ -75,7 +76,7 @@ def route(h: jax.Array, router: jax.Array, bias: Optional[jax.Array], *,
                    experts=experts, gates=gates)
 
 
-def rows_worked(group_sizes: jax.Array, tile: int = TILING[0]) -> jax.Array:
+def rows_worked(group_sizes: jax.Array, tile: int = ROW_TILE) -> jax.Array:
     """The sorted rows ``held_experts``' passes touch for these groups:
     whole tiles over the pairs routed here, the trip count of every pass
     times the tile."""
@@ -84,7 +85,7 @@ def rows_worked(group_sizes: jax.Array, tile: int = TILING[0]) -> jax.Array:
 
 def held_experts(h: jax.Array, routing: Routing, e_gate: jax.Array,
                  e_up: jax.Array, e_down: jax.Array,
-                 tile: int = TILING[0]) -> jax.Array:
+                 tile: int = ROW_TILE) -> jax.Array:
     """This chip's experts' part of the layer: h [T, D], the held experts'
     SwiGLU weights [held, D, F], [held, D, F], [held, F, D] in the
     activations' type -> [T, D], ``sum over a token's held experts of gate
@@ -93,10 +94,10 @@ def held_experts(h: jax.Array, routing: Routing, e_gate: jax.Array,
     Every one of the T x k sorted pairs has a row here, so no pair is
     dropped whatever the routing, and the rows of pairs whose expert lives
     elsewhere lie behind the groups, where no pass goes: each one works on
-    ``rows_worked`` rows, in tiles of ``tile`` (the grouped products' row
-    tile). What a pass leaves behind the routed pairs is undefined, so
-    whatever reads a whole array selects by ``held`` first. The rows are
-    made again in the backward pass and not kept."""
+    ``rows_worked`` rows, in tiles of ``tile`` (a multiple of the grouped
+    products' row tile). What a pass leaves behind the routed pairs is
+    undefined, so whatever reads a whole array selects by ``held`` first.
+    The rows are made again in the backward pass and not kept."""
     return jax.checkpoint(functools.partial(_held_experts, tile))(
         h, routing, e_gate, e_up, e_down)
 

@@ -64,9 +64,9 @@ SEGMENTS = ("seg.embed", "seg.attn_proj", "seg.attn_core", "seg.mlp",
             # grouped products, weighted scatter-add and residual
             "seg.conv", "seg.moe_route", "seg.moe_experts")
 # The Pallas kernels of ``ops/flash_attention.py``: each one's ``name=``
-# and the scope around its call; and of ``ops/grouped_matmul.py``: the
-# scope around each call of JAX's own grouped-matmul kernels; and of
-# ``ops/moe_rows.py``: the scope around each pass over the sorted rows
+# and the scope around its call; and of ``ops/grouped_matmul.py``, its
+# two kernels named the same way; and of ``ops/moe_rows.py``: the scope
+# around each pass over the sorted rows
 # an expert layer works on (the row-wise one a Pallas call of that name,
 # the gather and the scatter-add each a loop around XLA's own).
 KERNELS = ("flash_fwd", "flash_fwd_grouped", "flash_bwd_dq",

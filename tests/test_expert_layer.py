@@ -196,13 +196,45 @@ def test_the_rows_worked_reader_reads_the_programs_counter(monkeypatch):
     load = FAMILY.moe_load(MODEL, hp)
     layers = [row for rows in load.values() for row in rows]
     assert len(layers) == 4
-    tile, pairs = moe.TILING[0], 2 * 24 * MODEL["num_experts_per_tok"]
+    tile, pairs = moe.ROW_TILE, 2 * 24 * MODEL["num_experts_per_tok"]
     want = [-(-int(row.sum()) // tile) * tile / pairs for row in layers]
     assert read(ctx) == pytest.approx(100 * sum(want) / 4)
     assert read({}) is None
     dense = harness.family(["perfbench"], "dense")
     assert read(dict(ctx, family=dense)) is None
     monkeypatch.delattr(moe, "rows_worked")     # the parent's program
+    assert read(ctx) is None
+
+
+def test_the_rows_multiplied_reader_reads_the_kernels_counter(monkeypatch):
+    """The rows the grouped kernels' visits multiply over the pairs routed
+    to the held experts, summed over the expert layers; nothing where the
+    family counts no load or the program has no ``rows_multiplied``."""
+    from ray_tpu.ops import grouped_matmul
+
+    hp = {"batch": 2, "seq_len": 24}
+    FAMILY.make_params(MODEL, SEED + 1)
+    name = "kernel.moe_gmm_rows_multiplied_x.lfm2"
+    read = harness.reader(["perfbench"], name)
+    ctx = {"family": FAMILY, "model": MODEL, "step_cfg": hp}
+    layers = [row for rows in FAMILY.moe_load(MODEL, hp).values()
+              for row in rows]
+    tile, visits = grouped_matmul.TM, 0
+    for row in layers:
+        ends = np.cumsum(row).astype(int)
+        visits += sum(-(-end // tile) - (end - n) // tile
+                      for end, n in zip(ends, row.astype(int)) if n)
+    routed = sum(int(row.sum()) for row in layers)
+    assert read(ctx) == pytest.approx(visits * tile / routed)
+    assert read(ctx) >= 1.0
+    assert read({}) is None
+    dense = harness.family(["perfbench"], "dense")
+    assert read(dict(ctx, family=dense)) is None
+    cell = harness.load_cell("lfm2-24b-a2b-train.seq8k")
+    assert name in [m["name"] for m in cell["per_layer"]]
+    assert name not in [m["name"] for m in harness.load_cell(
+        "mistral7b-train.seq4k")["per_layer"]]
+    monkeypatch.delattr(grouped_matmul, "rows_multiplied")  # the parent's
     assert read(ctx) is None
 
 

@@ -21,7 +21,8 @@ import pytest
 
 from ray_tpu.models import loss_fn
 from ray_tpu.ops import moe_rows
-from ray_tpu.ops.grouped_matmul import TILING, grouped_matmul
+from ray_tpu.ops.grouped_matmul import grouped_matmul
+from ray_tpu.ops.moe_rows import ROW_TILE
 from ray_tpu.parallel import moe
 from test_pattern_model import FAMILY, MODEL, _batch, _cfg
 
@@ -207,7 +208,7 @@ def test_held_experts_is_the_plain_formulation_under_any_routing(sizes):
 def test_rows_worked_is_whole_tiles_over_the_pairs_routed_here(n):
     sizes = jnp.asarray([n // 3, n - n // 3], jnp.int32)
     assert int(moe.rows_worked(sizes, TILE)) == _worked(n)
-    assert int(moe.rows_worked(sizes)) == -(-n // TILING[0]) * TILING[0]
+    assert int(moe.rows_worked(sizes)) == -(-n // ROW_TILE) * ROW_TILE
     assert int(moe_rows.worked_tiles(n, TILE)) * TILE == _worked(n)
 
 
@@ -215,7 +216,7 @@ def test_the_expert_layer_is_one_program_with_no_branch():
     """Whatever the routing, the same instructions: no ``cond`` anywhere
     under ``seg.moe_experts`` of the patterned model's loss, forward or
     backward, and its row passes are there under their names."""
-    tokens, targets = _batch(batch=2, seq_len=TILING[0] // 2)
+    tokens, targets = _batch(batch=2, seq_len=ROW_TILE // 2)
     cfg = _cfg()
     params = jax.eval_shape(lambda: FAMILY.make_params(MODEL, 0))
     jaxpr = jax.make_jaxpr(jax.grad(functools.partial(loss_fn, cfg)))(

@@ -183,19 +183,23 @@ def test_flash_kernels_compile_at_heads_of_64_and_8192_tokens(tpu):
         assert f"%{name}." in text or f"%{name} " in text, name
 
 
+@pytest.mark.parametrize("k,n", [(2048, 1536), (1536, 2048)])
 @pytest.mark.parametrize("rows", [8192, 32768])
-def test_grouped_matmul_kernels_compile_under_their_names(tpu, rows):
+def test_grouped_matmul_kernels_compile_under_their_names(tpu, rows, k, n):
     """The held experts' grouped products at the LFM2 cell's widths (8
-    experts of 2048 x 1536), over every sorted pair of a layer and over a quarter of them: the
-    product and its two gradients are JAX's own kernels, each call under
-    the program's name for it."""
+    experts of 2048 x 1536, gate and up, and of 1536 x 2048, down), over
+    every sorted pair of a layer and over a quarter of them: the product
+    and its two gradients are the repo's two kernels at the tiles these
+    shapes give (whole weights of a group, a
+    whole output tile: more VMEM than the compiler grants unasked), each
+    call under the program's name for it."""
     from ray_tpu.ops.grouped_matmul import grouped_matmul, kernel_accepts
     from ray_tpu.util import profiling
 
     one = SingleDeviceSharding(tpu[0])
-    assert kernel_accepts(rows, 2048, 1536)
-    lhs = jax.ShapeDtypeStruct((rows, 2048), jnp.bfloat16, sharding=one)
-    rhs = jax.ShapeDtypeStruct((8, 2048, 1536), jnp.bfloat16, sharding=one)
+    assert kernel_accepts(rows, k, n)
+    lhs = jax.ShapeDtypeStruct((rows, k), jnp.bfloat16, sharding=one)
+    rhs = jax.ShapeDtypeStruct((8, k, n), jnp.bfloat16, sharding=one)
     sizes = jax.ShapeDtypeStruct((8,), jnp.int32, sharding=one)
     assert _kernel_calls(
         jax.jit(grouped_matmul).lower(lhs, rhs, sizes).compile()) == 1
@@ -225,7 +229,7 @@ def test_moe_row_passes_compile_under_their_names(tpu, rows):
 
     one = SingleDeviceSharding(tpu[0])
     tokens = rows // 4
-    assert moe_rows.rows_accept(rows, moe_rows.TILING[0], 2048, 1536)
+    assert moe_rows.rows_accept(rows, moe_rows.ROW_TILE, 2048, 1536)
 
     def shape(dims, dtype):
         return jax.ShapeDtypeStruct(dims, dtype, sharding=one)
