@@ -166,6 +166,13 @@ def _log_spacing(gaps: list, log) -> None:
     saw 2.2 s lost there in one run of the parent): the line after this
     one says when that step was back and how long the slowest call of
     the step held the host."""
+    if len(gaps) >= 20:
+        # A step that grows through the window (a router that moves, PR 38)
+        # shows here and in no median of the whole.
+        first, last = (sorted(g for g, _ in part)[5]
+                       for part in (gaps[:10], gaps[-10:]))
+        log(f"the window's first 10 steps came back {first * 1e3:.2f} ms "
+            f"apart at the median, its last 10 {last * 1e3:.2f}")
     gaps = sorted(gaps)
     if gaps:
         median = gaps[len(gaps) // 2][0]

@@ -114,9 +114,14 @@ def swiglu(z, w_gate, w_up, w_down, mm):
     return mm(jax.nn.silu(mm(z, w_gate)) * mm(z, w_up), w_down)
 
 
+def router_scores(lp: dict, z, mm):
+    """The router's sigmoid scores [S, E] of normed z [S, D]."""
+    return jax.nn.sigmoid(mm(z, lp["router"]))
+
+
 def routing(model: dict, lp: dict, z, mm):
     """(experts [S, k] of the router's published width, gates [S, k])."""
-    scores = jax.nn.sigmoid(mm(z, lp["router"]))
+    scores = router_scores(lp, z, mm)
     select = scores + lax.stop_gradient(lp["expert_bias"]) \
         if model["use_expert_bias"] else scores
     _, experts = lax.top_k(select, model["num_experts_per_tok"])

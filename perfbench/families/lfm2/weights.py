@@ -9,22 +9,47 @@ at the program's own scales: normal / sqrt(fan_in), embedding 0.02, norms
 same arrays; it never sees anything the program made.
 
 The expert bias (``use_expert_bias``: added to the scores to select, never
-to weigh) is drawn small from the seed, normal * ``expert_bias_scale``, and
-held: no gradient reaches it, and its published per-step update is not in
-the config.
+to weigh) is balanced once, at set-up (``balanced_bias``), by the rule the
+router's form comes from: DeepSeek-V3's auxiliary-loss-free balancing
+(arXiv:2408.15664; arXiv:2412.19437, section 2.1.2), ``b_i += u * sign(mean
+load - load_i)`` over all the router's experts, iterated with the weights
+frozen on the seed's batch of index 0. What to iterate with is the
+configuration's (``assumed.expert_bias.run``: ``u``, the stop, the rows).
+No gradient reaches the bias, and the timed step holds it: the published
+per-step update is not in the step yet. A model without that entry (the
+tests of the program's layer pattern, outside the benchmark's own) still
+draws it from the seed, normal * ``expert_bias_scale``.
 """
 
 from __future__ import annotations
 
+import json
 import math
+import sys
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from perfbench.harness import seed_key
+from perfbench.reference.numerics import mm_highest
 from perfbench.reference.train_check import layerwise
 
-from . import counts
+from . import counts, reference
+
+# (the model as it is run, the seed) -> {kind: [layers, router]}: the
+# balanced bias on the host, made once a process. A run asks for the seed's
+# tree three times (the program, the load's reader, the reference).
+_BALANCED = {}
+
+
+def _remembered_as(model: dict, seed: int) -> tuple:
+    return json.dumps(model, sort_keys=True), int(seed)
+
+
+def _balances(model: dict) -> bool:
+    """Whether ``model`` carries the rule its bias is balanced by."""
+    return bool(model["use_expert_bias"]) and "expert_bias" in model
 
 
 def kind_counts(model: dict) -> dict:
@@ -82,6 +107,8 @@ def _leaf(model: dict, key: jax.Array, name: str) -> jax.Array:
     shape, fan_in = kind_leaves(model, kind)[leaf]
     shape = (kind_counts(model)[kind],) + shape
     if leaf == "expert_bias":
+        if _balances(model):            # balanced_bias fills it in
+            return jnp.zeros(shape, jnp.float32)
         return jax.random.normal(k, shape, jnp.float32) \
             * model["expert_bias_scale"]
     if fan_in is None:
@@ -109,10 +136,94 @@ def batch_of(key, index, batch: int, seq_len: int, vocab: int):
     return rows[:, :-1], rows[:, 1:]
 
 
+def balance(scores, k: int, rule: dict):
+    """One router's bias by the auxiliary-loss-free rule: from zero,
+    ``b += u * sign(mean load - load)`` over every expert of ``scores``
+    [T, E], a load being how many of the T * k pairs the top-k of ``scores
+    + b`` gives an expert, until the fullest expert is at most
+    ``max_over_mean`` times the mean or ``iterations`` have run.
+    -> (bias [E], iterations run, fullest over mean)."""
+    t, e = scores.shape
+    mean = t * k / e
+
+    def load(b):
+        _, experts = lax.top_k(scores + b, k)
+        return jnp.sum(experts[..., None] == jnp.arange(e), axis=(0, 1),
+                       dtype=jnp.float32)
+
+    def full(state):
+        _, loads, i = state
+        return (jnp.max(loads) > rule["max_over_mean"] * mean) \
+            & (i < rule["iterations"])
+
+    def move(state):
+        b, loads, i = state
+        b = b + rule["u"] * jnp.sign(mean - loads)
+        return b, load(b), i + 1
+
+    zero = jnp.zeros((e,), jnp.float32)
+    b, loads, i = lax.while_loop(full, move, (zero, load(zero), 0))
+    return b, i, jnp.max(loads) / mean
+
+
+def balanced_bias(model: dict, params: dict, key, mm=mm_highest):
+    """Every expert layer's bias, layer by layer in depth order: a layer's
+    scores come of the routing before it and of what the experts held
+    here gave (the plain reference's layers, float32). The rows are one
+    sequence of the rule's ``seq_len`` drawn as ``batch_of`` draws the
+    batch of index 0. -> ({kind: [layers of that kind, router]},
+    iterations a layer, fullest over mean a layer)."""
+    rule = model["expert_bias"]
+    tokens, _ = batch_of(key, 0, 1, rule["seq_len"], model["vocab_size"])
+    x = params["embed"][tokens[0]]
+    bias, seen, ran, fullest = {}, {}, [], []
+    for kind in counts.kinds(model):
+        at = seen.get(kind, 0)
+        seen[kind] = at + 1
+        lp = jax.tree.map(lambda a: a[at], params["layers"][kind])
+        if kind.endswith("dense"):
+            x = reference.layer(model, kind, lp, x, mm)
+            continue
+        x = reference.operator(model, kind, lp, x, mm)
+        z = reference.rms_norm(x, lp["mlp_norm"], model["norm_eps"])
+        b, i, worst = balance(reference.router_scores(lp, z, mm),
+                              model["num_experts_per_tok"], rule)
+        x = x + reference.held_experts(model, {**lp, "expert_bias": b}, z, mm)
+        bias.setdefault(kind, []).append(b)
+        ran.append(i)
+        fullest.append(worst)
+    return ({kind: jnp.stack(rows) for kind, rows in bias.items()},
+            jnp.stack(ran), jnp.stack(fullest))
+
+
 def make_params(model: dict, seed: int) -> dict:
-    """The whole tree in one jitted program."""
-    return jax.jit(lambda key: _tree(
-        {n: _leaf(model, key, n) for n in leaf_names(model)}))(seed_key(seed))
+    """The whole tree in one jitted program, then the balanced bias in a
+    second, once a process for a seed."""
+    key = seed_key(seed)
+    params = jax.jit(lambda key: _tree(
+        {n: _leaf(model, key, n) for n in leaf_names(model)}))(key)
+    if not _balances(model):
+        return params
+    memo = _remembered_as(model, seed)
+    if memo not in _BALANCED:
+        bias, ran, fullest = jax.jit(
+            lambda p, k: balanced_bias(model, p, k))(params, key)
+        if isinstance(ran, jax.core.Tracer):        # shapes only
+            return _with_bias(params, bias)
+        print(f"perfbench lfm2: bias balanced in {ran.tolist()} iterations "
+              f"a layer, fullest over mean "
+              f"{[round(float(w), 4) for w in fullest]}",
+              file=sys.stderr, flush=True)
+        _BALANCED[memo] = jax.device_get(bias)
+    return _with_bias(params, _BALANCED[memo])
+
+
+def _with_bias(params: dict, bias: dict) -> dict:
+    """A fresh device array each time: the step donates its parameters."""
+    bias = jax.tree.map(jnp.asarray, bias)
+    layers = {kind: {**leaves, "expert_bias": bias[kind]} if kind in bias
+              else leaves for kind, leaves in params["layers"].items()}
+    return {**params, "layers": layers}
 
 
 def flat(params: dict) -> dict:
@@ -144,10 +255,19 @@ def change_norms(model: dict, seed: int, params: dict) -> dict:
     the initial leaf is made again inside the program that reduces it, one
     leaf at a time, so no second tree is ever held."""
     key = seed_key(seed)
+    memo = _remembered_as(model, seed)
+    if _balances(model) and memo not in _BALANCED:
+        make_params(model, seed)
+    balanced = _BALANCED.get(memo, {})
     out = {}
     for name, arr in flat(params).items():
-        fn = jax.jit(lambda a, k, name=name: jnp.sqrt(jnp.sum(
-            jnp.square(a - _leaf(model, k, name)),
+        # The balanced bias cannot be made again from the key alone; it is
+        # an argument, not a constant: one program for every seed.
+        kind, _, leaf = name.partition(".")
+        start = balanced.get(kind) if leaf == "expert_bias" else None
+        fn = jax.jit(lambda a, k, start, name=name: jnp.sqrt(jnp.sum(
+            jnp.square(a - (_leaf(model, k, name) if start is None
+                            else start)),
             axis=_layer_axes(name, a))))
-        out[name] = fn(arr, key)
+        out[name] = fn(arr, key, start)
     return layerwise({n: jax.device_get(v) for n, v in out.items()})

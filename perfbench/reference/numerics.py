@@ -54,9 +54,20 @@ mm_int8.defvjp(_mm_int8_fwd, _mm_int8_bwd)
 MATMULS = {"highest": mm_highest, "int8": mm_int8}
 
 
+def learning_rate(hp, count):
+    """The rate of update number ``count`` (from 1): the configuration's,
+    or under ``warmup_steps`` its linear warm-up from 0, which update
+    ``warmup_steps + 1`` is the first to take whole."""
+    if "warmup_steps" not in hp:
+        return hp["learning_rate"]
+    return hp["learning_rate"] * jnp.minimum(
+        1.0, (count - 1) / hp["warmup_steps"])
+
+
 def adamw_step(params, mu, nu, grads, count, hp):
     """One AdamW update as published (decoupled weight decay, bias
     correction), count = the number of this update, from 1."""
+    rate = learning_rate(hp, count)
     b1, b2 = hp["b1"], hp["b2"]
     mu = jax.tree.map(lambda m, g: b1 * m + (1 - b1) * g, mu, grads)
     nu = jax.tree.map(lambda n, g: b2 * n + (1 - b2) * g * g, nu, grads)
@@ -64,6 +75,6 @@ def adamw_step(params, mu, nu, grads, count, hp):
 
     def upd(p, m, n):
         step = (m / c1) / (jnp.sqrt(n / c2) + hp["eps"])
-        return p - hp["learning_rate"] * (step + hp["weight_decay"] * p)
+        return p - rate * (step + hp["weight_decay"] * p)
 
     return jax.tree.map(upd, params, mu, nu), mu, nu

@@ -22,7 +22,7 @@ def adamw_step(loss, batch_of, hp: dict):
 
     from ray_tpu.ops import backend
 
-    opt = optax.adamw(hp["learning_rate"], b1=hp["b1"], b2=hp["b2"],
+    opt = optax.adamw(learning_rate(hp), b1=hp["b1"], b2=hp["b2"],
                       eps=hp["eps"], weight_decay=hp["weight_decay"])
 
     def step(params, opt_state, key, index):
@@ -42,3 +42,17 @@ def first_moment(opt_state):
         if hasattr(part, "mu"):
             return part.mu
     raise ValueError("no Adam state in the optimizer's state")
+
+
+def learning_rate(hp: dict):
+    """What ``optax.adamw`` is handed: the rate as a float, or, where the
+    configuration's ``step`` names ``warmup_steps``, the linear warm-up
+    from 0 to it (DeepSeek-V3's report, arXiv:2412.19437, section 4.2: 2 K
+    steps, linear from 0) as a function of optax's count of updates so
+    far."""
+    if "warmup_steps" not in hp:
+        return hp["learning_rate"]
+    import jax.numpy as jnp
+
+    return lambda count: hp["learning_rate"] * jnp.minimum(
+        1.0, count / hp["warmup_steps"])
