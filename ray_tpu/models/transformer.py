@@ -20,12 +20,18 @@ SURVEY.md §2.6): everything here is built for the MXU and the Mesh:
 GQA attention with rotary embeddings, RMSNorm, SwiGLU MLP.
 
 **Layer kinds.** A stack is layers of one or several kinds
-(``layer_kind``): the sequence operator of a layer is causal attention or
-a gated short convolution (``layer_types``), its feed-forward the dense
-SwiGLU (the ``num_dense_layers`` leading ones) or the routed experts as
-published (``router_experts``; ``parallel/moe.py``: sigmoid or softmax
-scores, top-k over scores plus a bias, renormalised gates, no token
-dropped, and only the experts this chip holds computed). ``_kind_leaves``
+(``layer_kind``): the sequence operator of a layer is causal attention, a
+gated short convolution, Kimi Delta Attention (``kda``: a linear-attention
+layer, its recurrence a chunked scan, ``ops/kda.py``) or latent attention
+(``mla``: keys and values expanded from one low-rank latent, a rotary part
+of the keys shared by the heads, values narrower than keys)
+(``layer_types``), its feed-forward the dense SwiGLU (the
+``num_dense_layers`` leading ones) or the routed experts as published
+(``router_experts``; ``parallel/moe.py``: sigmoid or softmax scores, top-k
+over scores plus a bias, among the groups kept where the router limits its
+choice, renormalised gates, no token dropped, and only the experts this
+chip holds computed), with a shared expert beside them where the model has
+one (``shared_d_ff``). ``_kind_leaves``
 describes a kind's parameters once; the tree, its specs and the manual
 step's specs are made from that. Each run of equal layers in published
 order is one ``lax.scan`` (``layer_runs``) over its kind's stack. A
@@ -39,6 +45,7 @@ the other.
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import math
 from functools import partial
 from typing import Any, Dict, Optional, Tuple
@@ -74,11 +81,22 @@ class TransformerConfig:
     # The head is the embedding table, transposed; no ``lm_head`` leaf.
     tie_embeddings: bool = False
     # The layer kinds (training body only). ``layer_types``: the
-    # sequence operator of each layer, "attention" or "conv" (None: all
-    # attention); a conv layer's causal depthwise kernel has
-    # ``conv_kernel`` taps.
+    # sequence operator of each layer, "attention", "conv", "kda" or "mla"
+    # (None: all attention); the causal depthwise kernels of a conv layer
+    # and of a KDA layer's q, k and v have ``conv_kernel`` taps.
     layer_types: Optional[Tuple[str, ...]] = None
     conv_kernel: int = 3
+    # A KDA layer: heads of ``head_dim`` for keys and values alike; its
+    # log-decay is ``kda_gate_floor * sigmoid(.)``, so it lies in
+    # (``kda_gate_floor``, 0) (``ops/kda.py`` is safe down to -5).
+    kda_gate_floor: float = -5.0
+    # An MLA layer: the latent's rank, a head's widths of the keys'
+    # position-free and rotary parts and of the values (None: head_dim,
+    # none, head_dim).
+    kv_lora_rank: int = 512
+    qk_nope_dim: Optional[int] = None
+    qk_rope_dim: int = 0
+    v_head_dim: Optional[int] = None
     # Routed experts as published, in every layer after the
     # ``num_dense_layers`` leading ones (0 experts: every layer dense).
     # ``router_experts`` is the router's width, ``experts_held`` which of
@@ -93,17 +111,28 @@ class TransformerConfig:
     norm_topk: bool = False                 # gates renormalised over the k
     routed_scale: float = 1.0
     expert_bias: bool = False               # added to the scores to select
+    # The choice limited to the experts of the ``router_groups_kept`` best
+    # of ``router_groups`` groups of consecutive experts (1: no limit).
+    router_groups: int = 1
+    router_groups_kept: int = 1
+    # One expert every token goes through, added to the routed result
+    # (0: none); replicated over the chips that share the routed ones.
+    shared_d_ff: int = 0
 
     def __post_init__(self):
         if self.head_dim is None:
             object.__setattr__(self, "head_dim", self.d_model // self.n_heads)
+        if self.qk_nope_dim is None:
+            object.__setattr__(self, "qk_nope_dim", self.head_dim)
+        if self.v_head_dim is None:
+            object.__setattr__(self, "v_head_dim", self.head_dim)
         if self.layer_types is not None:
             object.__setattr__(self, "layer_types", tuple(self.layer_types))
             if (len(self.layer_types) != self.n_layers
-                    or set(self.layer_types) - {ATTENTION, CONV}):
+                    or set(self.layer_types) - set(OPERATORS)):
                 raise ValueError(
-                    f"layer_types {self.layer_types}: one of {ATTENTION!r}, "
-                    f"{CONV!r} for each of the {self.n_layers} layers")
+                    f"layer_types {self.layer_types}: one of {OPERATORS} "
+                    f"for each of the {self.n_layers} layers")
         if self.router_experts:
             held = tuple(range(self.router_experts)
                          if self.experts_held is None else self.experts_held)
@@ -116,14 +145,24 @@ class TransformerConfig:
                 raise ValueError(f"router_score {self.router_score!r}")
             if not 1 <= self.experts_per_token <= self.router_experts:
                 raise ValueError("experts_per_token out of the router's range")
+            groups, kept = self.router_groups, self.router_groups_kept
+            if (self.router_experts % groups or not 1 <= kept <= groups
+                    or groups > 1 and (
+                        self.router_experts // groups < 2
+                        or self.experts_per_token
+                        > kept * (self.router_experts // groups))):
+                raise ValueError(
+                    f"router_groups {groups}, kept {kept}: groups of at "
+                    f"least two experts that divide the router's "
+                    f"{self.router_experts}, the kept ones holding the "
+                    f"{self.experts_per_token} a token takes")
 
 
-ATTENTION, CONV = "attention", "conv"
+ATTENTION, CONV, KDA, MLA = OPERATORS = ("attention", "conv", "kda", "mla")
 DENSE, MOE = "dense", "moe"
 # Every kind of layer, ``<operator>_<feed-forward>``; the first is the flat
 # layout's one kind.
-KINDS = tuple(f"{op}_{ffn}" for op in (ATTENTION, CONV)
-              for ffn in (DENSE, MOE))
+KINDS = tuple(f"{op}_{ffn}" for op in OPERATORS for ffn in (DENSE, MOE))
 
 
 def layer_kind(cfg: TransformerConfig, i: int) -> str:
@@ -162,12 +201,40 @@ def _kind_leaves(cfg: TransformerConfig, kind: str) -> Dict[str, tuple]:
         if cfg.qk_norm:
             leaves.update(q_norm=((Hd,), None, (None,)),
                           k_norm=((Hd,), None, (None,)))
-    else:
+    elif op == CONV:
         K = cfg.conv_kernel
         leaves = {"conv_norm": ((D,), None, (None,)),
                   "conv_in": ((D, 3 * D), D, ("fsdp", "tp")),
                   "conv_taps": ((D, K), K, (None, None)),
                   "conv_out": ((D, D), D, ("tp", "fsdp"))}
+    elif op == KDA:
+        H, K = cfg.n_heads, cfg.conv_kernel
+        wide, taps = ((D, nq), D, ("fsdp", "tp")), ((nq, K), K, ("tp", None))
+        leaves = {"kda_norm": ((D,), None, (None,)),
+                  "kda_q": wide, "kda_k": wide, "kda_v": wide,
+                  "kda_q_taps": taps, "kda_k_taps": taps, "kda_v_taps": taps,
+                  # the decay: a = z W_a + dt_bias, one value a channel,
+                  # and one rate a head (both drawn as zeros here: a seeded
+                  # tree gives them their published distributions itself)
+                  "kda_a": wide, "kda_dt_bias": ((nq,), 0, ("tp",)),
+                  "kda_a_log": ((H,), 0, ("tp",)),
+                  "kda_beta": ((D, H), D, ("fsdp", "tp")),
+                  "kda_gate": wide,
+                  "kda_o_norm": ((Hd,), None, (None,)),
+                  "kda_out": ((nq, D), nq, ("tp", "fsdp"))}
+    else:
+        H, R = cfg.n_heads, cfg.kv_lora_rank
+        qk, rot, dv = cfg.qk_nope_dim + cfg.qk_rope_dim, cfg.qk_rope_dim, \
+            cfg.v_head_dim
+        leaves = {"mla_norm": ((D,), None, (None,)),
+                  "mla_q": ((D, H * qk), D, ("fsdp", "tp")),
+                  # the latent and the keys' rotary part, one for all heads
+                  "mla_kv_a": ((D, R + rot), D, ("fsdp", None)),
+                  "mla_kv_norm": ((R,), None, (None,)),
+                  "mla_kv_b": ((R, H * (cfg.qk_nope_dim + dv)), R,
+                               (None, "tp")),
+                  "mla_gate": ((D, H), D, ("fsdp", "tp")),
+                  "mla_out": ((H * dv, D), H * dv, ("tp", "fsdp"))}
     leaves["mlp_norm"] = ((D,), None, (None,))
     if ffn == DENSE:
         F = cfg.d_ff
@@ -182,6 +249,11 @@ def _kind_leaves(cfg: TransformerConfig, kind: str) -> Dict[str, tuple]:
                       e_down=((E, F, D), F, ("expert", "tp", None)))
         if cfg.expert_bias:
             leaves["expert_bias"] = ((cfg.router_experts,), 0, (None,))
+        if cfg.shared_d_ff:
+            Fs = cfg.shared_d_ff
+            leaves.update(s_gate=((D, Fs), D, ("fsdp", "tp")),
+                          s_up=((D, Fs), D, ("fsdp", "tp")),
+                          s_down=((Fs, D), Fs, ("tp", "fsdp")))
     return leaves
 
 
@@ -227,11 +299,14 @@ def init_params(cfg: TransformerConfig, key: jax.Array) -> Dict[str, Any]:
         # A seed means for the flat layout what it has meant: the drawn
         # families of ``attention_dense`` take keys 2, 3, ... in the leaves'
         # order; a further kind folds its place among ``KINDS`` into them.
-        family = iter(range(2, 16))
+        # (a kind with more drawn families than ``ks`` has keys left
+        # folds the family's number into the seed's key).
+        family = itertools.count(2)
         stack = {}
         for name, (shape, fan_in, _roles) in leaves.items():
             if fan_in:
-                k = ks[next(family)]
+                at = next(family)
+                k = ks[at] if at < len(ks) else jax.random.fold_in(key, at)
                 if kind != KINDS[0]:
                     k = jax.random.fold_in(k, KINDS.index(kind))
                 stack[name] = jax.vmap(
@@ -317,7 +392,8 @@ def rope(x, positions, theta):
 
 @jax.named_scope("seg.attn_core")
 def _attention_dense(q, k, v, causal=True, grad=True):
-    """q [B,S,Hq,Dh], k/v [B,S,Hkv,Dh] -> [B,S,Hq,Dh].
+    """q [B,S,Hq,Dh], k [B,S,Hkv,Dh], v [B,S,Hkv,Dv] -> [B,S,Hq,Dv]
+    (``Dv`` is ``Dh`` but for latent attention).
 
     Where ``ops.flash_attention.use_flash`` accepts the shape (never on
     the CPU backend) this dispatches to the Pallas flash
@@ -341,8 +417,8 @@ def _attention_dense(q, k, v, causal=True, grad=True):
     )
 
     B, S, Hq, Dh = q.shape
-    Hkv = k.shape[2]
-    if use_flash(S, S, Dh, q.dtype):
+    Hkv, Dv = k.shape[2], v.shape[3]
+    if use_flash(S, S, Dh, q.dtype, dv=Dv):
         if Hq != Hkv and not grad:
             o = flash_attention_grouped(q.transpose(0, 2, 1, 3),
                                         k.transpose(0, 2, 1, 3),
@@ -364,7 +440,7 @@ def _attention_dense(q, k, v, causal=True, grad=True):
         s = jnp.where(mask[None, None, None], s, -1e30)
     p = jax.nn.softmax(s.astype(jnp.float32), axis=-1).astype(q.dtype)
     o = jnp.einsum("bhgqk,bkhd->bqhgd", p, v)
-    return o.reshape(B, S, Hq, Dh)
+    return o.reshape(B, S, Hq, Dv)
 
 
 @jax.named_scope("seg.embed")
@@ -443,20 +519,136 @@ def _conv_residual(cfg, lp, x):
     (zeros before the sequence, no bias), gated by ``c``, then ``W_out``.
     No activation function. Three taps are three shifted multiply-adds."""
     dt = cfg.dtype
-    S, K = x.shape[1], cfg.conv_kernel
     z = rms_norm(x, lp["conv_norm"], cfg.norm_eps)
     b, c, u = jnp.split(z @ lp["conv_in"].astype(dt), 3, axis=-1)
-    v = jnp.pad(b * u, ((0, 0), (K - 1, 0), (0, 0)))
-    taps = lp["conv_taps"].astype(dt)                       # [D, K]
-    y = sum(v[:, j:j + S] * taps[:, j] for j in range(K))   # tap K-1: now
+    y = _causal_taps(b * u, lp["conv_taps"])                # taps [D, K]
     return x + (c * y) @ lp["conv_out"].astype(dt)
+
+
+def _causal_taps(x, taps):
+    """A causal depthwise convolution: x [B, S, C], taps [C, K] (cast to
+    x's type here) -> ``y_t = sum_j taps[:, j] * x_{t-(K-1)+j}``, zeros
+    before the sequence, no bias. K shifted multiply-adds."""
+    S, K = x.shape[1], taps.shape[1]
+    v = jnp.pad(x, ((0, 0), (K - 1, 0), (0, 0)))
+    taps = taps.astype(x.dtype)
+    return sum(v[:, j:j + S] * taps[:, j] for j in range(K))   # tap K-1: now
+
+
+def _l2_heads(x, eps=1e-6):
+    """x [..., Dh] over the L2 norm of its last axis, in float32."""
+    x32 = x.astype(jnp.float32)
+    return (x32 * lax.rsqrt(jnp.sum(x32 * x32, axis=-1, keepdims=True)
+                            + eps)).astype(x.dtype)
+
+
+def _kda_residual(cfg, lp, x):
+    """Kimi Delta Attention as a layer's sequence operator (Kimi Linear,
+    arXiv:2510.26692, section 3), with its norm and residual add,
+    x [B, S, D]. q, k and v go through causal taps and a SiLU each; q and k
+    are L2-normalised a head (q scaled ``head_dim ** -0.5``); the log-decay
+    is one value a channel, ``kda_gate_floor * sigmoid(exp(A_log) * (z W_a
+    + dt_bias))``; beta a sigmoid a head; the heads' outputs are RMS-normed
+    (one weight ``[head_dim]``), gated by ``sigmoid(z W_g)`` and projected.
+    No rope: the decay carries position. Two segments: the recurrence
+    itself is ``seg.kda_core``. Of what surrounds it the backward pass is
+    left the six projections' outputs and makes the elementwise chains
+    behind them again (some twenty ``[S, H * head_dim]`` arrays a layer
+    otherwise)."""
+    from ray_tpu.ops.kda import kda_chunk
+
+    keep_products = partial(
+        jax.checkpoint, policy=jax.checkpoint_policies.dots_saveable)
+    with jax.named_scope("seg.kda_proj"):
+        q, k, v, g, beta, gate = keep_products(
+            partial(_kda_inputs, cfg))(lp, x)
+    with jax.named_scope("seg.kda_core"):
+        o = kda_chunk(q, k, v, g, beta)
+    with jax.named_scope("seg.kda_proj"):
+        return keep_products(partial(_kda_out, cfg))(lp, x, o, gate)
+
+
+def _kda_inputs(cfg, lp, x):
+    """x [B, S, D] -> q, k, v [B, S, H, Dh], the log-decay [B, S, H, Dh]
+    and beta [B, S, H] in float32, and the output gate's logits
+    [B, S, H * Dh]."""
+    dt = cfg.dtype
+    B, S, _ = x.shape
+    H, Hd = cfg.n_heads, cfg.head_dim
+    z = rms_norm(x, lp["kda_norm"], cfg.norm_eps)
+
+    def mixed(name):
+        y = _causal_taps(z @ lp[f"kda_{name}"].astype(dt),
+                         lp[f"kda_{name}_taps"])
+        return jax.nn.silu(y).reshape(B, S, H, Hd)
+
+    q = _l2_heads(mixed("q")) * (Hd ** -0.5)
+    k, v = _l2_heads(mixed("k")), mixed("v")
+    # The decay's and beta's logits leave their products in float32: a
+    # head's rate multiplies the decay's by up to 16 before the sigmoid,
+    # and rounded to bfloat16 first they move its gradient by percents.
+    f32 = partial(jnp.matmul, preferred_element_type=jnp.float32)
+    a = f32(z, lp["kda_a"].astype(dt)) + lp["kda_dt_bias"]
+    rate = jnp.exp(lp["kda_a_log"].astype(jnp.float32))[:, None]
+    g = cfg.kda_gate_floor * jax.nn.sigmoid(rate * a.reshape(B, S, H, Hd))
+    beta = jax.nn.sigmoid(f32(z, lp["kda_beta"].astype(dt)))
+    return q, k, v, g, beta, z @ lp["kda_gate"].astype(dt)
+
+
+def _kda_out(cfg, lp, x, o, gate):
+    """The heads' outputs o [B, S, H, Dh], normed a head, under the gate
+    (its logits [B, S, H * Dh]), through ``W_o``, and the residual add."""
+    B, S, _ = x.shape
+    o = rms_norm(o, lp["kda_o_norm"], cfg.norm_eps).reshape(B, S, -1)
+    return x + (o * jax.nn.sigmoid(gate)) @ lp["kda_out"].astype(cfg.dtype)
+
+
+@jax.named_scope("seg.attn_proj")
+def _project_mla(cfg, lp, x, positions):
+    """Latent attention's projections in training form (DeepSeek-V2,
+    arXiv:2405.04434, section 2.1, no query latent; no absorbed weights,
+    no cache), x [B, S, D] -> q [B,S,H,nope+rope], the keys' position-free
+    part [B,S,H,nope], their rotary part [B,S,1,rope] that every head
+    shares, v [B,S,H,Dv], and the heads' output gate [B,S,H]."""
+    dt = cfg.dtype
+    B, S, _ = x.shape
+    H, nope, R = cfg.n_heads, cfg.qk_nope_dim, cfg.kv_lora_rank
+    h = rms_norm(x, lp["mla_norm"], cfg.norm_eps)
+    q = (h @ lp["mla_q"].astype(dt)).reshape(B, S, H, -1)
+    kv_a = h @ lp["mla_kv_a"].astype(dt)
+    latent = rms_norm(kv_a[..., :R], lp["mla_kv_norm"], cfg.norm_eps)
+    kv = (latent @ lp["mla_kv_b"].astype(dt)).reshape(B, S, H, -1)
+    q = jnp.concatenate(
+        [q[..., :nope], rope(q[..., nope:], positions, cfg.rope_theta)], -1)
+    k_rope = rope(kv_a[..., None, R:], positions, cfg.rope_theta)
+    gate = jax.nn.sigmoid(h @ lp["mla_gate"].astype(dt))
+    return q, kv[..., :nope], k_rope, kv[..., nope:], gate
+
+
+@jax.named_scope("seg.attn_core")
+def _mla_keys(k_nope, k_rope):
+    """A head's keys: its own position-free part beside the rotary part
+    all heads share."""
+    return jnp.concatenate(
+        [k_nope, jnp.broadcast_to(k_rope, k_nope.shape[:3]
+                                  + k_rope.shape[3:])], axis=-1)
+
+
+@jax.named_scope("seg.attn_proj")
+def _mla_out(cfg, lp, x, o, gate):
+    """The heads' outputs o [B, S, H, Dv], each under its gate, through
+    ``W_o``, and the residual add."""
+    B, S, _ = x.shape
+    o = (o * gate[..., None]).reshape(B, S, -1)
+    return x + o @ lp["mla_out"].astype(cfg.dtype)
 
 
 def _moe_residual(cfg, lp, x):
     """The routed experts as a layer's feed-forward, with its norm and
     residual add, x [B, S, D]: this chip's experts' part of the layer
-    (``parallel/moe.py``), and the tokens each held expert got. Two
-    segments, so not under ``seg.mlp``."""
+    (``parallel/moe.py``), and the tokens each held expert got; where the
+    model has a shared expert, every token's pass through it besides.
+    Segments of their own, so not under ``seg.mlp``."""
     B, S, D = x.shape
     with jax.named_scope("seg.moe_route"):
         h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps).reshape(B * S, D)
@@ -464,7 +656,13 @@ def _moe_residual(cfg, lp, x):
             h, lp["router"], lp.get("expert_bias"),
             experts_held=cfg.experts_held, k=cfg.experts_per_token,
             score=cfg.router_score, norm_topk=cfg.norm_topk,
-            scale=cfg.routed_scale)
+            scale=cfg.routed_scale, n_group=cfg.router_groups,
+            topk_group=cfg.router_groups_kept)
+    if cfg.shared_d_ff:
+        with jax.named_scope("seg.moe_shared"):
+            x = x + _swiglu(cfg, {"w_gate": lp["s_gate"], "w_up": lp["s_up"],
+                                  "w_down": lp["s_down"]}, h, None
+                            ).reshape(B, S, D)
     with jax.named_scope("seg.moe_experts"):
         dt = cfg.dtype
         out = moe.held_experts(
@@ -483,6 +681,12 @@ def _layer(cfg: TransformerConfig, kind: str, lp, x, positions,
     op, ffn = kind.split("_")
     if op == CONV:
         x = _conv_residual(cfg, lp, x)
+    elif op == KDA:
+        x = _kda_residual(cfg, lp, x)
+    elif op == MLA:
+        q, k_nope, k_rope, v, gate = _project_mla(cfg, lp, x, positions)
+        x = _mla_out(cfg, lp, x,
+                     attention(q, _mla_keys(k_nope, k_rope), v), gate)
     else:
         q, k, v = _project_qkv(cfg, lp, x, positions)
         x = _attn_out(cfg, lp, x, attention(q, k, v), tp_axis)
@@ -539,10 +743,10 @@ def moe_load(cfg: TransformerConfig, params: Dict[str, Any],
 
 
 def _refuse_pattern(cfg: TransformerConfig, body: str) -> None:
-    """The cached serving bodies run one kind of layer: a conv layer's
-    state has no place in the paged cache yet, and an expert layer that
-    holds a share gives a partial result. A wrong answer is worse than
-    none."""
+    """The cached serving bodies run one kind of layer: the state of a
+    conv or a KDA layer and an MLA layer's latent have no place in the
+    paged cache yet, and an expert layer that holds a share gives a partial
+    result. A wrong answer is worse than none."""
     if not _flat(cfg):
         raise NotImplementedError(
             f"{body} runs attention layers with one feed-forward kind; "

@@ -26,6 +26,11 @@ round the loop: the compiler copied every carried value at the head and
 the tail of each iteration with the MXU idle (its schedule for a v5e,
 read in PERF.md, PR 29).
 
+The values may be narrower or wider than the queries and keys (latent
+attention: 192 against 128): ``v``, ``o``, ``do``, ``dv`` and their
+accumulators take the value width, the score products the query-key width,
+and nothing is padded. At equal widths the kernels are what they were.
+
 A causal grid skips the tiles that hold no unmasked position
 (``tile_counts``). Shapes outside ``kernel_accepts`` take the dense
 ``jnp`` form; the backend never decides that (``ops/backend.py``).
@@ -156,7 +161,7 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
     q = q_ref[...] * scale                      # [block_q, d]
     qi = pl.program_id(1)
     w = m_ref.shape[1]
-    d = q.shape[-1]
+    d = acc_ref.shape[1]                        # the value width
     m_ref[...] = jnp.full(m_ref.shape, NEG_INF, jnp.float32)
     l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
     acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
@@ -279,7 +284,8 @@ def _acc_scratch(block: int, d: int):
 
 
 def _fwd_scratch(block_q: int, block_k: int, d: int) -> list:
-    """The forward's accumulator, running max and running sum; the two
+    """The forward's accumulator (``d``: the value width), running max
+    and running sum; the two
     statistics as wide as a vreg has lanes (narrower only for the
     interpreter's small blocks)."""
     w = math.gcd(block_k, _LANE)
@@ -316,26 +322,32 @@ def _auto_block(seq: int, cap: int = 512) -> int:
 
 
 def kernel_accepts(sq: int, sk: int, d: int, itemsize: int,
-                   block_q: int, block_k: int, *, interpret: bool) -> bool:
-    """The one statement of which shapes run the kernels. The tiling
+                   block_q: int, block_k: int, *, interpret: bool,
+                   dv: Optional[int] = None) -> bool:
+    """The one statement of which shapes run the kernels (``d``: the
+    query-key width, ``dv``: the values', where it differs; the wider of
+    the two is what a whole-head array in VMEM is bounded by). The tiling
     rules hold in both modes; the lane and VMEM limits are the TPU
     compiler's, so the interpreter (CPU tests, small tiles) skips them."""
-    if sq < 8 or sk < 8 or d % 8 or sq % block_q or sk % block_k:
+    dv = d if dv is None else dv
+    if (sq < 8 or sk < 8 or d % 8 or dv % 8 or sq % block_q
+            or sk % block_k):
         return False
     if interpret:
         return True
     if block_q % _LANE or block_k % _LANE:
         return False
-    return max(sq, sk) * d * itemsize <= _VMEM_HEAD_ARRAY_BYTES
+    return max(sq, sk) * max(d, dv) * itemsize <= _VMEM_HEAD_ARRAY_BYTES
 
 
-def use_flash(sq: int, sk: int, d: int, dtype) -> bool:
+def use_flash(sq: int, sk: int, d: int, dtype,
+              dv: Optional[int] = None) -> bool:
     """Model-path dispatch: the compiled kernel wherever it is accepted,
     the model's dense ``jnp`` attention on the CPU backend and for the
     shapes the guard refuses."""
     return not backend.on_cpu() and kernel_accepts(
         sq, sk, d, jnp.dtype(dtype).itemsize, _auto_block(sq),
-        _auto_block(sk), interpret=False)
+        _auto_block(sk), interpret=False, dv=dv)
 
 
 def flash_attention(
@@ -349,9 +361,9 @@ def flash_attention(
     block_k: Optional[int] = None,
     interpret: Optional[bool] = None,
 ) -> jax.Array:
-    """q/k/v: [B, H, S, D] -> [B, H, S, D], differentiable. K and V come
-    at the query heads' width (``flash_attention_grouped`` takes them
-    narrower, forward only).
+    """q/k: [B, H, S, D], v: [B, H, S, Dv] -> [B, H, S, Dv],
+    differentiable. K and V come at the query heads' count
+    (``flash_attention_grouped`` takes fewer, forward only).
 
     Block sizes default to ``_auto_block``'s; pass explicit
     block_q/block_k to override."""
@@ -364,7 +376,7 @@ def flash_attention(
     block_q = min(block_q or _auto_block(Sq), Sq)
     block_k = min(block_k or _auto_block(Sk), Sk)
     if not kernel_accepts(Sq, Sk, D, q.dtype.itemsize, block_q, block_k,
-                          interpret=bool(interpret)):
+                          interpret=bool(interpret), dv=v.shape[-1]):
         return _fallback(q, k, v, causal, scale)
     return _flash_core(q, k, v, causal, scale, block_q, block_k,
                        bool(interpret))
@@ -460,42 +472,42 @@ def _flash_forward_grouped(q, k, v, causal, scale, block_q, block_k,
 
 
 def _flash_forward(q, k, v, causal, scale, block_q, block_k, interpret):
-    """Returns (out [B,H,Sq,D], lse [B,H,Sq])."""
+    """Returns (out [B,H,Sq,Dv], lse [B,H,8,Sq])."""
     from jax.experimental import pallas as pl
 
     B, H, Sq, D = q.shape
-    Sk = k.shape[2]
+    Sk, Dv = k.shape[2], v.shape[3]
     kernel = functools.partial(
         _attn_kernel, block_k=block_k, seq_k=Sk, causal=causal,
         scale=scale, block_q=block_q)
 
     qr = q.reshape(B * H, Sq, D)
     kr = k.reshape(B * H, Sk, D)
-    vr = v.reshape(B * H, Sk, D)
+    vr = v.reshape(B * H, Sk, Dv)
 
     call = pl.pallas_call(
         kernel,
         name="flash_fwd",
-        scratch_shapes=_fwd_scratch(block_q, block_k, D),
+        scratch_shapes=_fwd_scratch(block_q, block_k, Dv),
         grid=(B * H, Sq // block_q),
         in_specs=[
             pl.BlockSpec((None, block_q, D), lambda b, i: (b, i, 0)),
             pl.BlockSpec((None, Sk, D), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((None, Sk, D), lambda b, i: (b, 0, 0)),
+            pl.BlockSpec((None, Sk, Dv), lambda b, i: (b, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((None, block_q, D), lambda b, i: (b, i, 0)),
+            pl.BlockSpec((None, block_q, Dv), lambda b, i: (b, i, 0)),
             pl.BlockSpec((None, 8, Sq), lambda b, i: (b, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B * H, Sq, D), q.dtype),
+            jax.ShapeDtypeStruct((B * H, Sq, Dv), q.dtype),
             jax.ShapeDtypeStruct((B * H, 8, Sq), jnp.float32),
         ],
         interpret=interpret,
     )
     with jax.named_scope("flash_fwd"):
         out, lse = call(qr, kr, vr)
-    return out.reshape(B, H, Sq, D), lse.reshape(B, H, 8, Sq)
+    return out.reshape(B, H, Sq, Dv), lse.reshape(B, H, 8, Sq)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
@@ -522,14 +534,14 @@ def _flash_bwd_rule(causal, scale, block_q, block_k, interpret,
 
     q, k, v, out, lse = res
     B, H, Sq, D = q.shape
-    Sk = k.shape[2]
+    Sk, Dv = k.shape[2], v.shape[3]
     BH = B * H
 
     qr = q.reshape(BH, Sq, D)
     kr = k.reshape(BH, Sk, D)
-    vr = v.reshape(BH, Sk, D)
-    outr = out.reshape(BH, Sq, D)
-    dor = dout.reshape(BH, Sq, D).astype(q.dtype)
+    vr = v.reshape(BH, Sk, Dv)
+    outr = out.reshape(BH, Sq, Dv)
+    dor = dout.reshape(BH, Sq, Dv).astype(q.dtype)
     lser = lse.reshape(BH, 8, Sq)
 
     dq_kernel = functools.partial(
@@ -543,9 +555,9 @@ def _flash_bwd_rule(causal, scale, block_q, block_k, interpret,
         in_specs=[
             pl.BlockSpec((None, block_q, D), lambda b, i: (b, i, 0)),
             pl.BlockSpec((None, Sk, D), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((None, Sk, D), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((None, block_q, D), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((None, block_q, D), lambda b, i: (b, i, 0)),
+            pl.BlockSpec((None, Sk, Dv), lambda b, i: (b, 0, 0)),
+            pl.BlockSpec((None, block_q, Dv), lambda b, i: (b, i, 0)),
+            pl.BlockSpec((None, block_q, Dv), lambda b, i: (b, i, 0)),
             pl.BlockSpec((None, 8, block_q), lambda b, i: (b, 0, i)),
         ],
         out_specs=pl.BlockSpec((None, block_q, D), lambda b, i: (b, i, 0)),
@@ -561,23 +573,23 @@ def _flash_bwd_rule(causal, scale, block_q, block_k, interpret,
     dkv_call = pl.pallas_call(
         dkv_kernel,
         name="flash_bwd_dkv",
-        scratch_shapes=[_acc_scratch(block_k, D), _acc_scratch(block_k, D)],
+        scratch_shapes=[_acc_scratch(block_k, D), _acc_scratch(block_k, Dv)],
         grid=(BH, Sk // block_k),
         in_specs=[
             pl.BlockSpec((None, block_k, D), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((None, block_k, D), lambda b, i: (b, i, 0)),
+            pl.BlockSpec((None, block_k, Dv), lambda b, i: (b, i, 0)),
             pl.BlockSpec((None, Sq, D), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((None, Sq, D), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((None, Sq, D), lambda b, i: (b, 0, 0)),
+            pl.BlockSpec((None, Sq, Dv), lambda b, i: (b, 0, 0)),
+            pl.BlockSpec((None, Sq, Dv), lambda b, i: (b, 0, 0)),
             pl.BlockSpec((None, 8, Sq), lambda b, i: (b, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((None, block_k, D), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((None, block_k, D), lambda b, i: (b, i, 0)),
+            pl.BlockSpec((None, block_k, Dv), lambda b, i: (b, i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((BH, Sk, D), k.dtype),
-            jax.ShapeDtypeStruct((BH, Sk, D), v.dtype),
+            jax.ShapeDtypeStruct((BH, Sk, Dv), v.dtype),
         ],
         interpret=interpret,
     )

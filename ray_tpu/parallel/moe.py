@@ -1,10 +1,12 @@
 """The expert layer as published (``route``, ``held_experts``; the training
 body of ``models/transformer.py``). Scores are a sigmoid or a softmax of a
 float32 router product; the k experts of a token are the top k of scores
-plus a selection bias, and their gates the scores themselves, renormalised
-over the k where the model says so. No token is dropped, under any
-imbalance, at static shapes: the T x k (token, expert) pairs are sorted by
-expert, the pairs of experts this chip does not hold after the held ones,
+plus a selection bias (among the groups of experts kept for the token,
+where the router limits its choice to some), and their gates the scores
+themselves, renormalised over the k where the model says so. No token is
+dropped, under any imbalance, at static shapes: the T x k (token, expert)
+pairs are sorted by expert, the pairs of experts this chip does not hold
+after the held ones,
 and every pass over the sorted rows stops at the last row tile the held
 groups touch: the three products of an expert run grouped over the held
 groups (``ops/grouped_matmul.py``), and the gather before them, the
@@ -45,11 +47,13 @@ class Routing(NamedTuple):
 
 def route(h: jax.Array, router: jax.Array, bias: Optional[jax.Array], *,
           experts_held: Tuple[int, ...], k: int, score: str = "softmax",
-          norm_topk: bool = False, scale: float = 1.0) -> Routing:
+          norm_topk: bool = False, scale: float = 1.0, n_group: int = 1,
+          topk_group: int = 1) -> Routing:
     """h [T, D], router [D, E] (E the router's published width), bias [E]
     or None -> the layer's routing. The router product is float32 at
     ``highest``: a bf16 product flips near-ties of the top k. The bias
-    selects and does not weigh, and takes no gradient."""
+    selects and does not weigh, and takes no gradient. With ``n_group``
+    above 1 the choice is group-limited (``_kept_groups``)."""
     T, E = h.shape[0], router.shape[1]
     logits = jnp.matmul(h.astype(jnp.float32), router.astype(jnp.float32),
                         precision=lax.Precision.HIGHEST)
@@ -57,6 +61,8 @@ def route(h: jax.Array, router: jax.Array, bias: Optional[jax.Array], *,
               else jax.nn.softmax(logits, axis=-1))
     select = scores if bias is None else \
         scores + lax.stop_gradient(bias.astype(jnp.float32))
+    if n_group > 1:
+        select = _kept_groups(select, n_group, topk_group)
     _, experts = lax.top_k(select, k)                           # [T, k]
     gates = jnp.take_along_axis(scores, experts, axis=-1)
     if norm_topk:
@@ -74,6 +80,22 @@ def route(h: jax.Array, router: jax.Array, bias: Optional[jax.Array], *,
     return Routing(token=pair // k, gate=gates.reshape(T * k)[pair],
                    held=group < n_held, group_sizes=group_sizes,
                    experts=experts, gates=gates)
+
+
+def _kept_groups(select: jax.Array, n_group: int, topk_group: int
+                 ) -> jax.Array:
+    """The group-limited choice of DeepSeek-V3's router (arXiv:2412.19437,
+    section 2.1.2; ``noaux_tc``): the E experts are ``n_group`` groups of
+    consecutive ones, a group's score is the sum of its two largest of
+    ``select`` [T, E] (scores plus bias), the ``topk_group`` best groups
+    are kept, and an expert of another group cannot be chosen: its entry
+    comes back as -inf."""
+    T, E = select.shape
+    grouped = select.reshape(T, n_group, E // n_group)
+    group_score = jnp.sum(lax.top_k(grouped, 2)[0], axis=-1)    # [T, groups]
+    _, kept = lax.top_k(group_score, topk_group)
+    keep = jnp.any(kept[:, :, None] == jnp.arange(n_group), axis=1)
+    return jnp.where(keep[:, :, None], grouped, -jnp.inf).reshape(T, E)
 
 
 def rows_worked(group_sizes: jax.Array, tile: int = ROW_TILE) -> jax.Array:

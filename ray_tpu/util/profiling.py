@@ -27,7 +27,8 @@ Division of labor between the two profilers:
 viewer's operation details (the ``op_name`` of each XLA operation) which
 part of the model the operation is: one of ``SEGMENTS`` (``seg.embed``,
 ``seg.attn_proj``, ``seg.attn_core``, ``seg.mlp``, ``seg.head_loss``, and
-of the further layer kinds ``seg.conv``, ``seg.moe_route``, ``seg.moe_experts``;
+of the further layer kinds ``seg.conv``, ``seg.moe_route``, ``seg.moe_experts``,
+``seg.moe_shared``, ``seg.kda_proj``, ``seg.kda_core``;
 the outermost one on the path is the operation's segment, ``norm`` and
 ``rope`` are finer scopes inside), and on the flash kernels one of
 ``KERNELS`` (``flash_fwd``, ``flash_fwd_grouped``, ``flash_bwd_dq``,
@@ -62,13 +63,19 @@ SEGMENTS = ("seg.embed", "seg.attn_proj", "seg.attn_core", "seg.mlp",
             # short convolution with its norm and residual; an expert
             # layer's norm, router, top-k, gates and sort; its gather,
             # grouped products, weighted scatter-add and residual
-            "seg.conv", "seg.moe_route", "seg.moe_experts")
+            "seg.conv", "seg.moe_route", "seg.moe_experts",
+            # a KDA layer's norm, projections, taps, gates, output norm,
+            # ``W_o`` and residual; its chunked scan (``ops/kda.py``); an
+            # expert layer's shared expert
+            "seg.kda_proj", "seg.kda_core", "seg.moe_shared")
 # The Pallas kernels of ``ops/flash_attention.py``: each one's ``name=``
 # and the scope around its call; and of ``ops/grouped_matmul.py``, its
 # two kernels named the same way; and of ``ops/moe_rows.py``: the scope
 # around each pass over the sorted rows
 # an expert layer works on (the row-wise one a Pallas call of that name,
-# the gather and the scatter-add each a loop around XLA's own).
+# the gather and the scatter-add each a loop around XLA's own). The
+# chunked scan of ``ops/kda.py`` is plain XLA and has no name here: it is
+# all of ``seg.kda_core``.
 KERNELS = ("flash_fwd", "flash_fwd_grouped", "flash_bwd_dq",
            "flash_bwd_dkv", "moe_gmm", "moe_tgmm",
            "moe_gather_rows", "moe_map_rows", "moe_scatter_rows")

@@ -42,13 +42,26 @@ PATTERN = TransformerConfig(
     router_experts=4, experts_held=(1, 2), experts_per_token=2, moe_d_ff=16,
     router_score="sigmoid", norm_topk=True, expert_bias=True, qk_norm=True,
     norm_eps=1e-5, tie_embeddings=True, dtype=jnp.bfloat16)
+# Another: a KDA layer with the dense MLP, then a KDA and an MLA layer with
+# routed experts (the choice limited to 2 of 4 groups) and a shared expert.
+HYBRID = TransformerConfig(
+    vocab_size=64, d_model=32, n_layers=3, n_heads=2, n_kv_heads=2, d_ff=64,
+    head_dim=16, layer_types=("kda", "kda", "mla"), conv_kernel=4,
+    kv_lora_rank=16, qk_nope_dim=16, qk_rope_dim=8, v_head_dim=16,
+    num_dense_layers=1, router_experts=16, experts_held=(1, 2),
+    experts_per_token=4, moe_d_ff=16, router_score="sigmoid", norm_topk=True,
+    expert_bias=True, router_groups=4, router_groups_kept=2, shared_d_ff=16,
+    dtype=jnp.bfloat16)
 # The model's train programs, and the segments each one holds: a dense
-# stack has no conv or expert layer, and the union is the vocabulary.
-TRAIN = {"dense": CFG, "pattern": PATTERN}
-OF_A_PATTERN = ("seg.conv", "seg.moe_route", "seg.moe_experts")
+# stack has no conv, KDA or expert layer, a pattern holds its own kinds',
+# and the union is the vocabulary.
+TRAIN = {"dense": CFG, "pattern": PATTERN, "hybrid": HYBRID}
+OF_A_HYBRID = ("seg.kda_proj", "seg.kda_core", "seg.moe_shared")
+OF_A_PATTERN = ("seg.conv", "seg.moe_route", "seg.moe_experts") + OF_A_HYBRID
 SEGMENTS_OF = {
     "dense": tuple(s for s in profiling.SEGMENTS if s not in OF_A_PATTERN),
-    "pattern": profiling.SEGMENTS}
+    "pattern": tuple(s for s in profiling.SEGMENTS if s not in OF_A_HYBRID),
+    "hybrid": tuple(s for s in profiling.SEGMENTS if s != "seg.conv")}
 
 
 def _params(cfg=CFG):
@@ -134,11 +147,22 @@ def test_every_matmul_of_the_train_step_lies_under_one_segment(program):
         assert len(matmuls) == lowered.as_text().count(
             "stablehlo.dot_general") == 30
         assert by_segment == DENSE_MATMULS
-    else:
+    elif program == "pattern":
         # the conv operator's two products, the router's, the experts'
         assert all(by_segment[s] > 0 for s in by_segment if s != "seg.embed")
         assert by_segment["seg.conv"] == 12 and by_segment["seg.mlp"] == 9
         assert by_segment["seg.moe_route"] == 2 * 3    # one a layer, and back
+    else:
+        assert all(by_segment[s] > 0 for s in by_segment if s != "seg.embed")
+        # a KDA layer's seven products (six in, one out), forward and twice
+        # backward, in each of the two runs of KDA layers: the backward pass
+        # makes the elementwise chains between them anew and no product; an
+        # MLA layer's five; a shared expert's three in each of two runs
+        assert by_segment["seg.kda_proj"] == 2 * 7 * 3
+        assert by_segment["seg.attn_proj"] == 5 * 3
+        assert by_segment["seg.moe_shared"] == 2 * 9
+        assert by_segment["seg.moe_route"] == 2 * 3
+        assert by_segment["seg.mlp"] == 9
 
 
 @pytest.mark.parametrize("program", sorted(TRAIN))

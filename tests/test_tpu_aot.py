@@ -376,18 +376,19 @@ def test_train_step_names_its_kernels_and_its_fusions(tpu):
     named = [s for s in fusions if s != segments.UNATTRIBUTED]
     assert len(fusions) > 50 and len(named) >= 0.9 * len(fusions), (
         len(named), len(fusions))
-    # a uniform stack: every segment but a layer pattern's three
-    pattern = {"seg.conv", "seg.moe_route", "seg.moe_experts"}
+    # a uniform stack: every segment but a layer pattern's
+    pattern = {"seg.conv", "seg.moe_route", "seg.moe_experts",
+               "seg.kda_proj", "seg.kda_core", "seg.moe_shared"}
     assert set(profiling.SEGMENTS) - pattern <= {
         r["segment"] for r in table.values()}
 
 
-def _lower_lfm2_step(dev):
-    """The LFM2 cell's AdamW step as its family builds it, at the cell's
+def _lower_cell_step(dev, workload):
+    """A training cell's AdamW step as its family builds it, at the cell's
     size, lowered for ``dev``."""
     from perfbench import harness
 
-    cell = harness.load_cell("lfm2-24b-a2b-train.seq8k")
+    cell = harness.load_cell(workload)
     family = harness.family(cell["paths"], cell["config"]["family"])
     step, init = family.build_step(cell["config"])
     params = jax.eval_shape(lambda: family.make_params(
@@ -395,6 +396,10 @@ def _lower_lfm2_step(dev):
     args = (params, jax.eval_shape(init, params),
             jax.eval_shape(lambda: harness.seed_key(0)))
     return step.lower(*_abstract(args, SingleDeviceSharding(dev)), 0)
+
+
+def _lower_lfm2_step(dev):
+    return _lower_cell_step(dev, "lfm2-24b-a2b-train.seq8k")
 
 
 MOE_NAMES = ("moe_gmm", "moe_tgmm", "moe_gather_rows", "moe_map_rows",
@@ -427,4 +432,56 @@ def test_the_lfm2_train_step_compiles_and_fits_the_chip(tpu):
     text = compiled.as_text()
     assert "conditional(" not in text
     for name in MOE_NAMES:
+        assert re.search(r"(?<![\w.])" + name + r"(?![\w.])", text), name
+
+
+def _flash_call_widths(lowered_text):
+    """The last axis of every bf16 ``[heads, S, width]`` operand and result
+    of the step's flash kernels."""
+    calls = [line for line in lowered_text.splitlines()
+             if "tpu_custom_call" in line and "flash_" in line]
+    assert len(calls) == 3 or len(calls) % 3 == 0 and calls
+    return {int(w) for line in calls
+            for w in re.findall(r"tensor<\d+x\d+x(\d+)xbf16>", line)}
+
+
+@pytest.mark.parametrize("workload,width", [
+    ("mistral7b-train.seq4k", 128), ("lfm2-24b-a2b-train.seq8k", 64)])
+def test_the_other_cells_flash_kernels_keep_their_one_width(tpu, workload,
+                                                            width):
+    """A value width of its own is the Ling cell's alone: in the Mistral and
+    LFM2 steps every operand of the three kernels is as wide as a head, as
+    at the parent (whose lowered steps these are, text for text outside the
+    kernels' source locations: PERF.md section 6, PR 39)."""
+    text = _lower_cell_step(tpu[0], workload).as_text(debug_info=True)
+    assert _flash_call_widths(text) == {width}
+
+
+LING_NAMES = MOE_NAMES + ("seg.kda_core", "flash_fwd", "flash_bwd_dq",
+                          "flash_bwd_dkv")
+
+
+def test_the_ling_train_step_holds_its_kernels_at_two_widths(tpu):
+    """Lowered for the v5e at the cell's size: the KDA scan under its segment,
+    the held experts' passes, and the three flash kernels on operands 192
+    wide for queries and keys and 128 for values, none padded. (Lowered
+    only: the compile is the slow test below.)"""
+    text = _lower_cell_step(tpu[0], "ling3-flash-train.seq4k").as_text(
+        debug_info=True)
+    assert "stablehlo.case" not in text and "stablehlo.if" not in text
+    for name in LING_NAMES:
+        assert re.search(r"(?<![\w.])" + name + r"(?![\w.])", text), name
+    assert _flash_call_widths(text) == {192, 128}
+
+
+@pytest.mark.slow
+def test_the_ling_train_step_compiles_and_fits_the_chip(tpu):
+    """The Ling cell's step through the v5e's compiler: 577.9 M parameters
+    with their AdamW state and a 1 x 4096 step's temporaries fit the chip.
+    Slow-marked as the LFM2 step's compile above, for the same reason."""
+    compiled = _lower_cell_step(tpu[0], "ling3-flash-train.seq4k").compile()
+    _fits(compiled)
+    text = compiled.as_text()
+    assert "conditional(" not in text
+    for name in LING_NAMES:
         assert re.search(r"(?<![\w.])" + name + r"(?![\w.])", text), name
