@@ -7,7 +7,18 @@ SURVEY.md §2.6): everything here is built for the MXU and the Mesh:
   einsums that tile onto the systolic array; static shapes throughout.
 - Layers are **stacked** ([L, ...] leading axis) and run under ``lax.scan``
   → one compiled layer body regardless of depth, with optional
-  ``jax.checkpoint`` rematerialisation for HBM.
+  ``jax.checkpoint`` rematerialisation for HBM. The masters are float32;
+  ``_scan_layers`` casts the matmuls' operands to ``cfg.dtype`` once, a
+  whole stack before its scan, and the scan runs over that copy: the
+  backward pass reads the same copy (none is kept a layer), and those
+  leaves' gradients leave the loop in ``cfg.dtype`` and become float32 at
+  the cast's transpose, outside it. Which leaves: what ``_kind_leaves``
+  says the layer reads in ``cfg.dtype`` (the projections, the SwiGLUs',
+  the conv's two, the KDA and MLA matrices, the experts'). The others
+  stay float32 in the scan because the layer reads them so, or rounds
+  them itself: the router (a float32 product at ``highest``), the
+  selection bias, a KDA layer's ``dt_bias`` and ``A_log``, the norms'
+  weights and the taps.
 - Two execution paths over one layer (``_layer``):
   1. ``forward`` / ``loss_fn``: GSPMD path — logical sharding constraints
      (ShardingRules) and jit; XLA inserts the dp/fsdp/tp collectives.
@@ -187,73 +198,81 @@ def layer_runs(cfg: TransformerConfig) -> Tuple[Tuple[str, int, int], ...]:
 
 
 def _kind_leaves(cfg: TransformerConfig, kind: str) -> Dict[str, tuple]:
-    """name -> (shape, fan_in, PartitionSpec roles) of one layer of
-    ``kind``; fan_in None: ones (a norm), 0: zeros (the bias)."""
+    """name -> (shape, fan_in, PartitionSpec roles, the type the layer
+    reads it in) of one layer of ``kind``; fan_in None: ones (a norm), 0:
+    zeros (the bias). A matmul's operand in the activations' type is read
+    in ``cfg.dtype`` (``mm``) and nowhere else, so a scan may run over
+    that copy of it (``_scan_layers``); every other leaf is read as it is
+    stored (``f32``): what the router's float32 product, a decay or a norm
+    reads is never rounded on the way to the layer."""
     D, Hd = cfg.d_model, cfg.head_dim
     nq, nkv = cfg.n_heads * Hd, cfg.n_kv_heads * Hd
+    mm, f32 = jnp.dtype(cfg.dtype), jnp.dtype(jnp.float32)
     op, ffn = kind.split("_")
     if op == ATTENTION:
-        leaves = {"attn_norm": ((D,), None, (None,)),
-                  "wq": ((D, nq), D, ("fsdp", "tp")),
-                  "wk": ((D, nkv), D, ("fsdp", "tp")),
-                  "wv": ((D, nkv), D, ("fsdp", "tp")),
-                  "wo": ((nq, D), nq, ("tp", "fsdp"))}
+        leaves = {"attn_norm": ((D,), None, (None,), f32),
+                  "wq": ((D, nq), D, ("fsdp", "tp"), mm),
+                  "wk": ((D, nkv), D, ("fsdp", "tp"), mm),
+                  "wv": ((D, nkv), D, ("fsdp", "tp"), mm),
+                  "wo": ((nq, D), nq, ("tp", "fsdp"), mm)}
         if cfg.qk_norm:
-            leaves.update(q_norm=((Hd,), None, (None,)),
-                          k_norm=((Hd,), None, (None,)))
+            leaves.update(q_norm=((Hd,), None, (None,), f32),
+                          k_norm=((Hd,), None, (None,), f32))
     elif op == CONV:
         K = cfg.conv_kernel
-        leaves = {"conv_norm": ((D,), None, (None,)),
-                  "conv_in": ((D, 3 * D), D, ("fsdp", "tp")),
-                  "conv_taps": ((D, K), K, (None, None)),
-                  "conv_out": ((D, D), D, ("tp", "fsdp"))}
+        leaves = {"conv_norm": ((D,), None, (None,), f32),
+                  "conv_in": ((D, 3 * D), D, ("fsdp", "tp"), mm),
+                  "conv_taps": ((D, K), K, (None, None), f32),
+                  "conv_out": ((D, D), D, ("tp", "fsdp"), mm)}
     elif op == KDA:
         H, K = cfg.n_heads, cfg.conv_kernel
-        wide, taps = ((D, nq), D, ("fsdp", "tp")), ((nq, K), K, ("tp", None))
-        leaves = {"kda_norm": ((D,), None, (None,)),
+        wide = ((D, nq), D, ("fsdp", "tp"), mm)
+        taps = ((nq, K), K, ("tp", None), f32)
+        leaves = {"kda_norm": ((D,), None, (None,), f32),
                   "kda_q": wide, "kda_k": wide, "kda_v": wide,
                   "kda_q_taps": taps, "kda_k_taps": taps, "kda_v_taps": taps,
                   # the decay: a = z W_a + dt_bias, one value a channel,
                   # and one rate a head (both drawn as zeros here: a seeded
                   # tree gives them their published distributions itself)
-                  "kda_a": wide, "kda_dt_bias": ((nq,), 0, ("tp",)),
-                  "kda_a_log": ((H,), 0, ("tp",)),
-                  "kda_beta": ((D, H), D, ("fsdp", "tp")),
+                  "kda_a": wide, "kda_dt_bias": ((nq,), 0, ("tp",), f32),
+                  "kda_a_log": ((H,), 0, ("tp",), f32),
+                  "kda_beta": ((D, H), D, ("fsdp", "tp"), mm),
                   "kda_gate": wide,
-                  "kda_o_norm": ((Hd,), None, (None,)),
-                  "kda_out": ((nq, D), nq, ("tp", "fsdp"))}
+                  "kda_o_norm": ((Hd,), None, (None,), f32),
+                  "kda_out": ((nq, D), nq, ("tp", "fsdp"), mm)}
     else:
         H, R = cfg.n_heads, cfg.kv_lora_rank
         qk, rot, dv = cfg.qk_nope_dim + cfg.qk_rope_dim, cfg.qk_rope_dim, \
             cfg.v_head_dim
-        leaves = {"mla_norm": ((D,), None, (None,)),
-                  "mla_q": ((D, H * qk), D, ("fsdp", "tp")),
+        leaves = {"mla_norm": ((D,), None, (None,), f32),
+                  "mla_q": ((D, H * qk), D, ("fsdp", "tp"), mm),
                   # the latent and the keys' rotary part, one for all heads
-                  "mla_kv_a": ((D, R + rot), D, ("fsdp", None)),
-                  "mla_kv_norm": ((R,), None, (None,)),
+                  "mla_kv_a": ((D, R + rot), D, ("fsdp", None), mm),
+                  "mla_kv_norm": ((R,), None, (None,), f32),
                   "mla_kv_b": ((R, H * (cfg.qk_nope_dim + dv)), R,
-                               (None, "tp")),
-                  "mla_gate": ((D, H), D, ("fsdp", "tp")),
-                  "mla_out": ((H * dv, D), H * dv, ("tp", "fsdp"))}
-    leaves["mlp_norm"] = ((D,), None, (None,))
+                               (None, "tp"), mm),
+                  "mla_gate": ((D, H), D, ("fsdp", "tp"), mm),
+                  "mla_out": ((H * dv, D), H * dv, ("tp", "fsdp"), mm)}
+    leaves["mlp_norm"] = ((D,), None, (None,), f32)
     if ffn == DENSE:
         F = cfg.d_ff
-        leaves.update(w_gate=((D, F), D, ("fsdp", "tp")),
-                      w_up=((D, F), D, ("fsdp", "tp")),
-                      w_down=((F, D), F, ("tp", "fsdp")))
+        leaves.update(w_gate=((D, F), D, ("fsdp", "tp"), mm),
+                      w_up=((D, F), D, ("fsdp", "tp"), mm),
+                      w_down=((F, D), F, ("tp", "fsdp"), mm))
     else:
         F, E = cfg.moe_d_ff or cfg.d_ff, len(cfg.experts_held)
-        leaves.update(router=((D, cfg.router_experts), D, (None, None)),
-                      e_gate=((E, D, F), D, ("expert", None, "tp")),
-                      e_up=((E, D, F), D, ("expert", None, "tp")),
-                      e_down=((E, F, D), F, ("expert", "tp", None)))
+        leaves.update(
+            router=((D, cfg.router_experts), D, (None, None), f32),
+            e_gate=((E, D, F), D, ("expert", None, "tp"), mm),
+            e_up=((E, D, F), D, ("expert", None, "tp"), mm),
+            e_down=((E, F, D), F, ("expert", "tp", None), mm))
         if cfg.expert_bias:
-            leaves["expert_bias"] = ((cfg.router_experts,), 0, (None,))
+            leaves["expert_bias"] = ((cfg.router_experts,), 0, (None,), f32)
         if cfg.shared_d_ff:
             Fs = cfg.shared_d_ff
-            leaves.update(s_gate=((D, Fs), D, ("fsdp", "tp")),
-                          s_up=((D, Fs), D, ("fsdp", "tp")),
-                          s_down=((Fs, D), Fs, ("tp", "fsdp")))
+            leaves.update(s_gate=((D, Fs), D, ("fsdp", "tp"), mm),
+                          s_up=((D, Fs), D, ("fsdp", "tp"), mm),
+                          s_down=((Fs, D), Fs, ("tp", "fsdp"), mm))
     return leaves
 
 
@@ -303,7 +322,7 @@ def init_params(cfg: TransformerConfig, key: jax.Array) -> Dict[str, Any]:
         # folds the family's number into the seed's key).
         family = itertools.count(2)
         stack = {}
-        for name, (shape, fan_in, _roles) in leaves.items():
+        for name, (shape, fan_in, _roles, _read) in leaves.items():
             if fan_in:
                 at = next(family)
                 k = ks[at] if at < len(ks) else jax.random.fold_in(key, at)
@@ -334,7 +353,7 @@ def _layer_specs(cfg: TransformerConfig, lead, role) -> Dict[str, Any]:
     axis, on each further axis the mesh axis ``role`` gives its role."""
     return _layers_tree(cfg, lambda _kind, _n, leaves: {
         name: P(lead, *(role[x] for x in roles))
-        for name, (_shape, _fan_in, roles) in leaves.items()})
+        for name, (_shape, _fan_in, roles, _read) in leaves.items()})
 
 
 def param_specs(cfg: TransformerConfig,
@@ -696,9 +715,22 @@ def _layer(cfg: TransformerConfig, kind: str, lp, x, positions,
 
 
 def _scan_layers(cfg: TransformerConfig, kind: str, stack, x, positions,
-                 attention, tp_axis, constrain):
+                 attention, tp_axis, constrain, layers=None):
     """A run of equal layers: one ``lax.scan`` of ``_layer`` over
-    ``stack``, whose leaves lead with the run's layers."""
+    ``stack``, whose leaves lead with the layers (``layers``: the run's
+    slice of them; None: all). The scan runs over each leaf in the type
+    the layer reads it in (``_kind_leaves``): the float32 masters of the
+    matmuls' operands are cast here, a whole stack at once and before it
+    is cut into runs (one cast a stack under its own name in the compiled
+    step, however many runs read it), and the layer's own casts of them do
+    nothing. So the backward pass reads the same stack and keeps no copy
+    of a layer's cast weights, and their gradients leave the loop as
+    stacks of that type, widened by this cast's transpose."""
+    stack = {name: stack[name].astype(read)
+             for name, (_shape, _fan_in, _roles, read)
+             in _kind_leaves(cfg, kind).items()}
+    if layers is not None:
+        stack = jax.tree.map(lambda a: a[layers], stack)
 
     def body(x, lp):
         run = partial(_layer, cfg, kind, lp, positions=positions,
@@ -714,11 +746,9 @@ def _layers(cfg: TransformerConfig, layers, x, positions, constrain):
     run's slice of its kind's stack."""
     counts, stacks = _kind_counts(cfg), _stacks(cfg, layers)
     for kind, start, count in layer_runs(cfg):
-        stack = stacks[kind]
-        if count != counts[kind]:
-            stack = jax.tree.map(lambda a: a[start:start + count], stack)
-        x = _scan_layers(cfg, kind, stack, x, positions, _attention_dense,
-                         None, constrain)
+        run = slice(start, start + count) if count != counts[kind] else None
+        x = _scan_layers(cfg, kind, stacks[kind], x, positions,
+                         _attention_dense, None, constrain, run)
     return x
 
 
