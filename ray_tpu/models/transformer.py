@@ -14,11 +14,11 @@ SURVEY.md §2.6): everything here is built for the MXU and the Mesh:
   leaves' gradients leave the loop in ``cfg.dtype`` and become float32 at
   the cast's transpose, outside it. Which leaves: what ``_kind_leaves``
   says the layer reads in ``cfg.dtype`` (the projections, the SwiGLUs',
-  the conv's two, the KDA and MLA matrices, the experts'). The others
-  stay float32 in the scan because the layer reads them so, or rounds
-  them itself: the router (a float32 product at ``highest``), the
-  selection bias, a KDA layer's ``dt_bias`` and ``A_log``, the norms'
-  weights and the taps.
+  the conv's two, the KDA, MLA and Mamba matrices, the experts' and the
+  latent's). The others stay float32 in the scan because the layer reads
+  them so, or rounds them itself: the router (a float32 product at
+  ``highest``), the selection bias, a KDA or Mamba layer's ``dt_bias``
+  and ``A_log``, the norms' weights and the taps.
 - Two execution paths over one layer (``_layer``):
   1. ``forward`` / ``loss_fn``: GSPMD path — logical sharding constraints
      (ShardingRules) and jit; XLA inserts the dp/fsdp/tp collectives.
@@ -33,20 +33,29 @@ GQA attention with rotary embeddings, RMSNorm, SwiGLU MLP.
 **Layer kinds.** A stack is layers of one or several kinds
 (``layer_kind``): the sequence operator of a layer is causal attention, a
 gated short convolution, Kimi Delta Attention (``kda``: a linear-attention
-layer, its recurrence a chunked scan, ``ops/kda.py``) or latent attention
+layer, its recurrence a chunked scan, ``ops/kda.py``), latent attention
 (``mla``: keys and values expanded from one low-rank latent, a rotary part
-of the keys shared by the heads, values narrower than keys)
-(``layer_types``), its feed-forward the dense SwiGLU (the
-``num_dense_layers`` leading ones) or the routed experts as published
-(``router_experts``; ``parallel/moe.py``: sigmoid or softmax scores, top-k
-over scores plus a bias, among the groups kept where the router limits its
-choice, renormalised gates, no token dropped, and only the experts this
-chip holds computed), with a shared expert beside them where the model has
-one (``shared_d_ff``). ``_kind_leaves``
+of the keys shared by the heads, values narrower than keys) or a Mamba-2
+mixer (``mamba``: a selective state-space layer, its recurrence the chunked
+scan of ``ops/ssd.py``) (``layer_types``), its feed-forward the dense
+SwiGLU (the ``num_dense_layers`` leading ones) or the routed experts as
+published (``router_experts``; ``parallel/moe.py``: sigmoid or softmax
+scores, top-k over scores plus a bias, among the groups kept where the
+router limits its choice, renormalised gates, no token dropped, and only
+the experts this chip holds computed; SwiGLU experts at the model's width,
+or two matrices with a squared ReLU between, ``ffn_act``, at a latent width
+of their own between a down and an up projection every token passes,
+``moe_latent``), with a shared expert beside them where the model has one
+(``shared_d_ff``). A layer may also be its operator or its feed-forward
+alone, one norm and one residual add (``layer_ffns``; ``none`` in either
+list). ``_kind_leaves``
 describes a kind's parameters once; the tree, its specs and the manual
 step's specs are made from that. Each run of equal layers in published
-order is one ``lax.scan`` (``layer_runs``) over its kind's stack. A
-configuration with neither ``layer_types`` nor ``router_experts`` is one
+order (``layer_runs``), or of equal units of several kinds that repeat
+(``layer_units``: five times expert layer then Mamba layer, say), is one
+``lax.scan`` over its kinds' stacks. A
+configuration with none of ``layer_types``, ``layer_ffns`` and
+``router_experts`` is one
 run of ``attention_dense`` and keeps the flat tree ``params["layers"]
 [leaf]``; every other stacks per kind, ``params["layers"][kind][leaf]``
 (``_stacks``). The cached serving bodies run the flat layout and refuse
@@ -92,11 +101,28 @@ class TransformerConfig:
     # The head is the embedding table, transposed; no ``lm_head`` leaf.
     tie_embeddings: bool = False
     # The layer kinds (training body only). ``layer_types``: the
-    # sequence operator of each layer, "attention", "conv", "kda" or "mla"
-    # (None: all attention); the causal depthwise kernels of a conv layer
-    # and of a KDA layer's q, k and v have ``conv_kernel`` taps.
+    # sequence operator of each layer, "attention", "conv", "kda", "mla" or
+    # "mamba", or "none" for a layer that is its feed-forward alone (None:
+    # all attention); the causal depthwise kernels of a conv layer, of a
+    # KDA layer's q, k and v and of a Mamba layer have ``conv_kernel`` taps.
+    # ``layer_ffns``: the feed-forward of each layer, "dense", "moe", or
+    # "none" for a layer that is its operator alone (None: the routed
+    # experts after the ``num_dense_layers`` leading layers, where the
+    # model has them). A layer is never neither.
     layer_types: Optional[Tuple[str, ...]] = None
+    layer_ffns: Optional[Tuple[str, ...]] = None
     conv_kernel: int = 3
+    # Rotary embedding on an attention layer's queries and keys.
+    rope: bool = True
+    # A Mamba-2 layer: ``mamba_heads`` heads of ``mamba_head_dim`` channels
+    # (their product the layer's inner width), a state of ``mamba_state``
+    # a channel, B and C shared by the heads of each of ``mamba_groups``
+    # groups, the scan in chunks of ``mamba_chunk`` positions.
+    mamba_heads: int = 0
+    mamba_head_dim: int = 64
+    mamba_state: int = 128
+    mamba_groups: int = 1
+    mamba_chunk: int = 128
     # A KDA layer: heads of ``head_dim`` for keys and values alike; its
     # log-decay is ``kda_gate_floor * sigmoid(.)``, so it lies in
     # (``kda_gate_floor``, 0) (``ops/kda.py`` is safe down to -5).
@@ -129,6 +155,13 @@ class TransformerConfig:
     # One expert every token goes through, added to the routed result
     # (0: none); replicated over the chips that share the routed ones.
     shared_d_ff: int = 0
+    # An expert, routed or shared: "swiglu" (gate, up, down), or "relu2",
+    # two matrices with ``relu(.) ** 2`` between.
+    ffn_act: str = "swiglu"
+    # The width the routed experts work at where it is not the model's: a
+    # down projection before them and an up projection after their
+    # weighted sum, on every token (0: none, the model's width).
+    moe_latent: int = 0
 
     def __post_init__(self):
         if self.head_dim is None:
@@ -137,13 +170,35 @@ class TransformerConfig:
             object.__setattr__(self, "qk_nope_dim", self.head_dim)
         if self.v_head_dim is None:
             object.__setattr__(self, "v_head_dim", self.head_dim)
-        if self.layer_types is not None:
-            object.__setattr__(self, "layer_types", tuple(self.layer_types))
-            if (len(self.layer_types) != self.n_layers
-                    or set(self.layer_types) - set(OPERATORS)):
+        for name, known in (("layer_types", OPERATORS + (NONE,)),
+                            ("layer_ffns", (DENSE, MOE, NONE))):
+            listed = getattr(self, name)
+            if listed is None:
+                continue
+            object.__setattr__(self, name, tuple(listed))
+            if len(listed) != self.n_layers or set(listed) - set(known):
                 raise ValueError(
-                    f"layer_types {self.layer_types}: one of {OPERATORS} "
+                    f"{name} {tuple(listed)}: one of {known} "
                     f"for each of the {self.n_layers} layers")
+        kinds = {layer_kind(self, i) for i in range(self.n_layers)}
+        ffns = {kind.split("_")[1] for kind in kinds}
+        if f"{NONE}_{NONE}" in kinds or (MOE in ffns
+                                         and not self.router_experts):
+            raise ValueError(
+                f"layer kinds {sorted(kinds)}: a layer is an operator, a "
+                f"feed-forward or both, and \"moe\" needs router_experts")
+        if any(kind.startswith(MAMBA) for kind in kinds) and (
+                self.mamba_heads < 1
+                or self.mamba_heads % self.mamba_groups):
+            raise ValueError(
+                f"a mamba layer needs mamba_heads ({self.mamba_heads}) in "
+                f"whole groups ({self.mamba_groups})")
+        if self.ffn_act not in ("swiglu", "relu2") or (
+                self.ffn_act != "swiglu" and DENSE in ffns):
+            raise ValueError(
+                f"ffn_act {self.ffn_act!r}: \"swiglu\", or \"relu2\" for "
+                f"the routed and the shared experts (a dense feed-forward "
+                f"is SwiGLU)")
         if self.router_experts:
             held = tuple(range(self.router_experts)
                          if self.experts_held is None else self.experts_held)
@@ -169,16 +224,23 @@ class TransformerConfig:
                     f"{self.experts_per_token} a token takes")
 
 
-ATTENTION, CONV, KDA, MLA = OPERATORS = ("attention", "conv", "kda", "mla")
-DENSE, MOE = "dense", "moe"
-# Every kind of layer, ``<operator>_<feed-forward>``; the first is the flat
-# layout's one kind.
-KINDS = tuple(f"{op}_{ffn}" for op in OPERATORS for ffn in (DENSE, MOE))
+ATTENTION, CONV, KDA, MLA, MAMBA = OPERATORS = (
+    "attention", "conv", "kda", "mla", "mamba")
+DENSE, MOE, NONE = "dense", "moe", "none"
+# Every kind of layer, ``<operator>_<feed-forward>``, either of which may
+# be ``none``; the first is the flat layout's one kind. (A kind's place
+# here is folded into ``init_params``' draws: new kinds go to the end.)
+KINDS = tuple(f"{op}_{ffn}" for op in OPERATORS[:4] for ffn in (DENSE, MOE)) \
+    + tuple(f"{MAMBA}_{ffn}" for ffn in (DENSE, MOE)) \
+    + tuple(f"{op}_{NONE}" for op in OPERATORS) \
+    + tuple(f"{NONE}_{ffn}" for ffn in (DENSE, MOE))
 
 
 def layer_kind(cfg: TransformerConfig, i: int) -> str:
     """The kind of layer ``i``, ``<operator>_<feed-forward>``."""
     op = cfg.layer_types[i] if cfg.layer_types is not None else ATTENTION
+    if cfg.layer_ffns is not None:
+        return f"{op}_{cfg.layer_ffns[i]}"
     ffn = MOE if cfg.router_experts and i >= cfg.num_dense_layers else DENSE
     return f"{op}_{ffn}"
 
@@ -195,6 +257,38 @@ def layer_runs(cfg: TransformerConfig) -> Tuple[Tuple[str, int, int], ...]:
             runs.append([kind, seen.get(kind, 0), 1])
         seen[kind] = seen.get(kind, 0) + 1
     return tuple(tuple(r) for r in runs)
+
+
+def layer_units(cfg: TransformerConfig
+                ) -> Tuple[Tuple[Tuple[str, ...], Tuple[int, ...], int], ...]:
+    """The stack as scans: (kinds, starts, count) of each stretch of layers
+    that is ``count`` times the unit ``kinds`` (distinct kinds; ``starts``
+    counts within each kind's own stack), in published order. From each
+    layer on, the unit that repeats over the most layers, at least twice,
+    the shortest such; else the layer alone. A run of equal layers is a
+    unit of one kind, so a pattern without a repeating unit of several
+    kinds gives ``layer_runs``."""
+    kinds = [layer_kind(cfg, i) for i in range(cfg.n_layers)]
+    units, seen, i = [], {}, 0
+    while i < len(kinds):
+        best = (1, 1)                                 # (period, repeats)
+        for period in range(1, (len(kinds) - i) // 2 + 1):
+            unit = kinds[i:i + period]
+            if len(set(unit)) < period:
+                continue
+            repeats = 1
+            while kinds[i + repeats * period:
+                        i + (repeats + 1) * period] == unit:
+                repeats += 1
+            if repeats > 1 and period * repeats > best[0] * best[1]:
+                best = (period, repeats)
+        period, repeats = best
+        unit = tuple(kinds[i:i + period])
+        units.append((unit, tuple(seen.get(k, 0) for k in unit), repeats))
+        for k in unit:
+            seen[k] = seen.get(k, 0) + repeats
+        i += period * repeats
+    return tuple(units)
 
 
 def _kind_leaves(cfg: TransformerConfig, kind: str) -> Dict[str, tuple]:
@@ -240,6 +334,25 @@ def _kind_leaves(cfg: TransformerConfig, kind: str) -> Dict[str, tuple]:
                   "kda_gate": wide,
                   "kda_o_norm": ((Hd,), None, (None,), f32),
                   "kda_out": ((nq, D), nq, ("tp", "fsdp"), mm)}
+    elif op == MAMBA:
+        H, K = cfg.mamba_heads, cfg.conv_kernel
+        inner = H * cfg.mamba_head_dim
+        mixed = inner + 2 * cfg.mamba_groups * cfg.mamba_state
+        leaves = {"mamba_norm": ((D,), None, (None,), f32),
+                  # [z | x B C | dt]: columns of several meanings, so no
+                  # tensor-parallel axis is given them yet
+                  "mamba_in": ((D, inner + mixed + H), D, ("fsdp", None), mm),
+                  "mamba_taps": ((mixed, K), K, (None, None), f32),
+                  "mamba_conv_bias": ((mixed,), 0, (None,), f32),
+                  # one rate, one step bias and one skip a head (zeros and
+                  # ones here: a seeded tree draws the first two itself)
+                  "mamba_a_log": ((H,), 0, (None,), f32),
+                  "mamba_dt_bias": ((H,), 0, (None,), f32),
+                  "mamba_d": ((H,), None, (None,), f32),
+                  "mamba_gate_norm": ((inner,), None, (None,), f32),
+                  "mamba_out": ((inner, D), inner, (None, "fsdp"), mm)}
+    elif op == NONE:
+        leaves = {}
     else:
         H, R = cfg.n_heads, cfg.kv_lora_rank
         qk, rot, dv = cfg.qk_nope_dim + cfg.qk_rope_dim, cfg.qk_rope_dim, \
@@ -253,6 +366,8 @@ def _kind_leaves(cfg: TransformerConfig, kind: str) -> Dict[str, tuple]:
                                (None, "tp"), mm),
                   "mla_gate": ((D, H), D, ("fsdp", "tp"), mm),
                   "mla_out": ((H * dv, D), H * dv, ("tp", "fsdp"), mm)}
+    if ffn == NONE:
+        return leaves
     leaves["mlp_norm"] = ((D,), None, (None,), f32)
     if ffn == DENSE:
         F = cfg.d_ff
@@ -261,17 +376,23 @@ def _kind_leaves(cfg: TransformerConfig, kind: str) -> Dict[str, tuple]:
                       w_down=((F, D), F, ("tp", "fsdp"), mm))
     else:
         F, E = cfg.moe_d_ff or cfg.d_ff, len(cfg.experts_held)
-        leaves.update(
-            router=((D, cfg.router_experts), D, (None, None), f32),
-            e_gate=((E, D, F), D, ("expert", None, "tp"), mm),
-            e_up=((E, D, F), D, ("expert", None, "tp"), mm),
-            e_down=((E, F, D), F, ("expert", "tp", None), mm))
+        W = cfg.moe_latent or D             # the width the experts work at
+        gated = cfg.ffn_act == "swiglu"
+        leaves["router"] = ((D, cfg.router_experts), D, (None, None), f32)
+        if cfg.moe_latent:
+            leaves.update(latent_down=((D, W), D, ("fsdp", None), mm),
+                          latent_up=((W, D), W, (None, "fsdp"), mm))
+        if gated:
+            leaves["e_gate"] = ((E, W, F), W, ("expert", None, "tp"), mm)
+        leaves.update(e_up=((E, W, F), W, ("expert", None, "tp"), mm),
+                      e_down=((E, F, W), F, ("expert", "tp", None), mm))
         if cfg.expert_bias:
             leaves["expert_bias"] = ((cfg.router_experts,), 0, (None,), f32)
         if cfg.shared_d_ff:
             Fs = cfg.shared_d_ff
-            leaves.update(s_gate=((D, Fs), D, ("fsdp", "tp"), mm),
-                          s_up=((D, Fs), D, ("fsdp", "tp"), mm),
+            if gated:
+                leaves["s_gate"] = ((D, Fs), D, ("fsdp", "tp"), mm)
+            leaves.update(s_up=((D, Fs), D, ("fsdp", "tp"), mm),
                           s_down=((Fs, D), Fs, ("tp", "fsdp"), mm))
     return leaves
 
@@ -285,7 +406,8 @@ def _kind_counts(cfg: TransformerConfig) -> Dict[str, int]:
 
 def _flat(cfg: TransformerConfig) -> bool:
     """The layout: one kind, ``layers[leaf]``; else ``layers[kind][leaf]``."""
-    return cfg.layer_types is None and not cfg.router_experts
+    return cfg.layer_types is None and cfg.layer_ffns is None \
+        and not cfg.router_experts
 
 
 def _stacks(cfg: TransformerConfig, layers) -> Dict[str, Any]:
@@ -482,8 +604,9 @@ def _project_qkv(cfg, lp, x, positions):
     if cfg.qk_norm:
         q = rms_norm(q, lp["q_norm"], cfg.norm_eps)
         k = rms_norm(k, lp["k_norm"], cfg.norm_eps)
-    q = rope(q, positions, cfg.rope_theta)
-    k = rope(k, positions, cfg.rope_theta)
+    if cfg.rope:
+        q = rope(q, positions, cfg.rope_theta)
+        k = rope(k, positions, cfg.rope_theta)
     return q, k, v
 
 
@@ -622,6 +745,64 @@ def _kda_out(cfg, lp, x, o, gate):
     return x + (o * jax.nn.sigmoid(gate)) @ lp["kda_out"].astype(cfg.dtype)
 
 
+def _mamba_residual(cfg, lp, x):
+    """A Mamba-2 mixer as a layer's sequence operator (arXiv:2405.21060),
+    with its norm and residual add, x [B, S, D]: ``[z | xBC | dt] = u
+    W_in``; ``xBC`` through causal taps with a bias and a SiLU, then split
+    into a head's inputs and a group's ``B`` and ``C``; ``dt = softplus(dt
+    + dt_bias)`` and ``A = -exp(A_log)``, one a head; the selective scan
+    with its skip ``D`` (``ops/ssd.py``); the result times ``SiLU(z)``
+    RMS-normed over each group's channels (one weight a channel) and
+    projected. No rope: the decay carries position. Two segments: the scan
+    itself is ``seg.mamba_core``. Of what surrounds it the backward pass is
+    left the products' outputs and makes the elementwise chains again."""
+    from ray_tpu.ops.ssd import ssd_chunk
+
+    keep_products = partial(
+        jax.checkpoint, policy=jax.checkpoint_policies.dots_saveable)
+    with jax.named_scope("seg.mamba_proj"):
+        z, xs, b, c, dt = keep_products(partial(_mamba_inputs, cfg))(lp, x)
+    with jax.named_scope("seg.mamba_core"):
+        a = -jnp.exp(lp["mamba_a_log"].astype(jnp.float32))
+        y = ssd_chunk(xs, dt, a, b, c, lp["mamba_d"], cfg.mamba_chunk)
+    with jax.named_scope("seg.mamba_proj"):
+        return keep_products(partial(_mamba_out, cfg))(lp, x, y, z)
+
+
+def _mamba_inputs(cfg, lp, x):
+    """x [B, S, D] -> the gate z [B, S, inner], the heads' inputs
+    [B, S, H, P], B and C [B, S, G, N], and dt [B, S, H] in float32."""
+    dt_ = cfg.dtype
+    B, S, _ = x.shape
+    H, P = cfg.mamba_heads, cfg.mamba_head_dim
+    G, N = cfg.mamba_groups, cfg.mamba_state
+    inner = H * P
+    u = rms_norm(x, lp["mamba_norm"], cfg.norm_eps)
+    w = lp["mamba_in"].astype(dt_)
+    z, mixed = jnp.split(u @ w[:, :-H], [inner], axis=-1)
+    mixed = jax.nn.silu(_causal_taps(mixed, lp["mamba_taps"])
+                        + lp["mamba_conv_bias"].astype(dt_))
+    xs, b, c = jnp.split(mixed, [inner, inner + G * N], axis=-1)
+    # The step's logits leave their product in float32, as a KDA layer's
+    # decay does: a head's rate multiplies them by up to 16.
+    dt = jax.nn.softplus(
+        jnp.matmul(u, w[:, -H:], preferred_element_type=jnp.float32)
+        + lp["mamba_dt_bias"])
+    return (z, xs.reshape(B, S, H, P), b.reshape(B, S, G, N),
+            c.reshape(B, S, G, N), dt)
+
+
+def _mamba_out(cfg, lp, x, y, z):
+    """The scan's result y [B, S, H, P] under ``SiLU(z)``, normed over each
+    group's channels, through ``W_out``, and the residual add."""
+    B, S, _ = x.shape
+    y = y.reshape(B, S, -1) * jax.nn.silu(z)
+    y = rms_norm(y.reshape(B, S, cfg.mamba_groups, -1),
+                 lp["mamba_gate_norm"].reshape(cfg.mamba_groups, -1),
+                 cfg.norm_eps).reshape(B, S, -1)
+    return x + y @ lp["mamba_out"].astype(cfg.dtype)
+
+
 @jax.named_scope("seg.attn_proj")
 def _project_mla(cfg, lp, x, positions):
     """Latent attention's projections in training form (DeepSeek-V2,
@@ -662,32 +843,60 @@ def _mla_out(cfg, lp, x, o, gate):
     return x + o @ lp["mla_out"].astype(cfg.dtype)
 
 
-def _moe_residual(cfg, lp, x):
+def _moe_residual(cfg, lp, x, alone=False):
     """The routed experts as a layer's feed-forward, with its norm and
     residual add, x [B, S, D]: this chip's experts' part of the layer
     (``parallel/moe.py``), and the tokens each held expert got; where the
-    model has a shared expert, every token's pass through it besides.
-    Segments of their own, so not under ``seg.mlp``."""
+    model has a shared expert, every token's pass through it besides, in
+    the experts' own form (``ffn_act``). Where the routed experts work at
+    a latent width (``moe_latent``), every token goes down to it before
+    them and their weighted sum comes up from it after (``seg.moe_latent``:
+    two plain products outside the row passes); the router and the shared
+    expert read the model's width. Segments of their own, so not under
+    ``seg.mlp``. ``alone``: the layer is this feed-forward and nothing else
+    (a pattern of such layers has a norm a mixer, twice as many a block),
+    and the two float32 copies of the residual stream that the norm and the
+    router's product would leave the backward pass, ``[B * S, D]`` each, are
+    made again there from the stream itself."""
     B, S, D = x.shape
     with jax.named_scope("seg.moe_route"):
-        h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps).reshape(B * S, D)
+        norm = jax.checkpoint(rms_norm, static_argnums=2,
+                              prevent_cse=False) if alone else rms_norm
+        h = norm(x, lp["mlp_norm"], cfg.norm_eps).reshape(B * S, D)
         routing = moe.route(
             h, lp["router"], lp.get("expert_bias"),
             experts_held=cfg.experts_held, k=cfg.experts_per_token,
             score=cfg.router_score, norm_topk=cfg.norm_topk,
             scale=cfg.routed_scale, n_group=cfg.router_groups,
-            topk_group=cfg.router_groups_kept)
+            topk_group=cfg.router_groups_kept, keep_input=not alone)
+    dt = cfg.dtype
     if cfg.shared_d_ff:
         with jax.named_scope("seg.moe_shared"):
-            x = x + _swiglu(cfg, {"w_gate": lp["s_gate"], "w_up": lp["s_up"],
-                                  "w_down": lp["s_down"]}, h, None
-                            ).reshape(B, S, D)
+            shared = _swiglu(cfg, {"w_gate": lp["s_gate"], "w_up": lp["s_up"],
+                                   "w_down": lp["s_down"]}, h, None) \
+                if cfg.ffn_act == "swiglu" else _relu2_expert(
+                    h, lp["s_up"].astype(dt), lp["s_down"].astype(dt))
+            x = x + shared.reshape(B, S, D)
+    if cfg.moe_latent:
+        with jax.named_scope("seg.moe_latent"):
+            h = h @ lp["latent_down"].astype(dt)
     with jax.named_scope("seg.moe_experts"):
-        dt = cfg.dtype
         out = moe.held_experts(
-            h, routing, lp["e_gate"].astype(dt), lp["e_up"].astype(dt),
-            lp["e_down"].astype(dt))
+            h, routing,
+            lp["e_gate"].astype(dt) if cfg.ffn_act == "swiglu" else None,
+            lp["e_up"].astype(dt), lp["e_down"].astype(dt))
+        if not cfg.moe_latent:
+            return x + out.reshape(B, S, D), routing.group_sizes
+    with jax.named_scope("seg.moe_latent"):
+        out = out @ lp["latent_up"].astype(dt)
         return x + out.reshape(B, S, D), routing.group_sizes
+
+
+@partial(jax.checkpoint, policy=jax.checkpoint_policies.dots_saveable)
+def _relu2_expert(h, w_up, w_down):
+    """``relu(h W_up) ** 2 W_down``; the backward pass is left the first
+    product's output and squares it again."""
+    return jnp.square(jax.nn.relu(h @ w_up)) @ w_down
 
 
 def _layer(cfg: TransformerConfig, kind: str, lp, x, positions,
@@ -702,23 +911,29 @@ def _layer(cfg: TransformerConfig, kind: str, lp, x, positions,
         x = _conv_residual(cfg, lp, x)
     elif op == KDA:
         x = _kda_residual(cfg, lp, x)
+    elif op == MAMBA:
+        x = _mamba_residual(cfg, lp, x)
     elif op == MLA:
         q, k_nope, k_rope, v, gate = _project_mla(cfg, lp, x, positions)
         x = _mla_out(cfg, lp, x,
                      attention(q, _mla_keys(k_nope, k_rope), v), gate)
-    else:
+    elif op == ATTENTION:
         q, k, v = _project_qkv(cfg, lp, x, positions)
         x = _attn_out(cfg, lp, x, attention(q, k, v), tp_axis)
     if ffn == MOE:
-        return _moe_residual(cfg, lp, x)
+        return _moe_residual(cfg, lp, x, alone=op == NONE)
+    if ffn == NONE:
+        return x, None
     return _mlp_residual(cfg, lp, x, tp_axis), None
 
 
-def _scan_layers(cfg: TransformerConfig, kind: str, stack, x, positions,
-                 attention, tp_axis, constrain, layers=None):
-    """A run of equal layers: one ``lax.scan`` of ``_layer`` over
-    ``stack``, whose leaves lead with the layers (``layers``: the run's
-    slice of them; None: all). The scan runs over each leaf in the type
+def _scan_layers(cfg: TransformerConfig, kinds: Tuple[str, ...], stacks,
+                 x, positions, attention, tp_axis, constrain, layers=None):
+    """A run of equal units of layers, a unit one layer of each of
+    ``kinds`` in turn (a run of equal layers: one kind): one ``lax.scan``
+    of ``_layer`` over ``stacks``, a stack a kind, whose leaves lead with
+    the layers (``layers``: the run's slice of each; None: all). The scan
+    runs over each leaf in the type
     the layer reads it in (``_kind_leaves``): the float32 masters of the
     matmuls' operands are cast here, a whole stack at once and before it
     is cut into runs (one cast a stack under its own name in the compiled
@@ -726,29 +941,34 @@ def _scan_layers(cfg: TransformerConfig, kind: str, stack, x, positions,
     nothing. So the backward pass reads the same stack and keeps no copy
     of a layer's cast weights, and their gradients leave the loop as
     stacks of that type, widened by this cast's transpose."""
-    stack = {name: stack[name].astype(read)
-             for name, (_shape, _fan_in, _roles, read)
-             in _kind_leaves(cfg, kind).items()}
+    stacks = tuple({name: stack[name].astype(read)
+                    for name, (_shape, _fan_in, _roles, read)
+                    in _kind_leaves(cfg, kind).items()}
+                   for kind, stack in zip(kinds, stacks))
     if layers is not None:
-        stack = jax.tree.map(lambda a: a[layers], stack)
+        stacks = tuple(jax.tree.map(lambda a: a[run], stack)
+                       for run, stack in zip(layers, stacks))
 
-    def body(x, lp):
-        run = partial(_layer, cfg, kind, lp, positions=positions,
-                      attention=attention, tp_axis=tp_axis)
-        x, _load = jax.checkpoint(run)(x) if cfg.remat else run(x)
-        return constrain(x, "batch", "sequence", "embed"), None
+    def body(x, lps):
+        for kind, lp in zip(kinds, lps):
+            run = partial(_layer, cfg, kind, lp, positions=positions,
+                          attention=attention, tp_axis=tp_axis)
+            x, _load = jax.checkpoint(run)(x) if cfg.remat else run(x)
+            x = constrain(x, "batch", "sequence", "embed")
+        return x, None
 
-    return lax.scan(body, x, stack)[0]
+    return lax.scan(body, x, stacks)[0]
 
 
 def _layers(cfg: TransformerConfig, layers, x, positions, constrain):
-    """The stack: each run of equal layers in published order over that
-    run's slice of its kind's stack."""
+    """The stack: each run of equal units in published order
+    (``layer_units``) over that run's slice of its kinds' stacks."""
     counts, stacks = _kind_counts(cfg), _stacks(cfg, layers)
-    for kind, start, count in layer_runs(cfg):
-        run = slice(start, start + count) if count != counts[kind] else None
-        x = _scan_layers(cfg, kind, stacks[kind], x, positions,
-                         _attention_dense, None, constrain, run)
+    for kinds, starts, count in layer_units(cfg):
+        runs = None if all(count == counts[k] for k in kinds) else tuple(
+            slice(start, start + count) for start in starts)
+        x = _scan_layers(cfg, kinds, tuple(stacks[k] for k in kinds), x,
+                         positions, _attention_dense, None, constrain, runs)
     return x
 
 
@@ -774,8 +994,8 @@ def moe_load(cfg: TransformerConfig, params: Dict[str, Any],
 
 def _refuse_pattern(cfg: TransformerConfig, body: str) -> None:
     """The cached serving bodies run one kind of layer: the state of a
-    conv or a KDA layer and an MLA layer's latent have no place in the
-    paged cache yet, and an expert layer that holds a share gives a partial
+    conv, a KDA or a Mamba layer and an MLA layer's latent have no place in
+    the paged cache yet, and an expert layer that holds a share gives a partial
     result. A wrong answer is worse than none."""
     if not _flat(cfg):
         raise NotImplementedError(
@@ -881,7 +1101,7 @@ def make_spmd_train_step(cfg: TransformerConfig, mesh: Mesh, params,
         """Run this pp-shard's n_layers // pp layers over activation
         bucket act = (x, positions)."""
         x, positions = act
-        x = _scan_layers(cfg, KINDS[0], stage_layers, x, positions,
+        x = _scan_layers(cfg, KINDS[:1], (stage_layers,), x, positions,
                          attention, tp_axis, lambda x, *_logical: x)
         return x, positions
 

@@ -8,7 +8,8 @@ dropped, under any imbalance, at static shapes: the T x k (token, expert)
 pairs are sorted by expert, the pairs of experts this chip does not hold
 after the held ones,
 and every pass over the sorted rows stops at the last row tile the held
-groups touch: the three products of an expert run grouped over the held
+groups touch: the products of an expert (three of a SwiGLU, two where it
+is two matrices with a squared ReLU between) run grouped over the held
 groups (``ops/grouped_matmul.py``), and the gather before them, the
 row-wise stages between them and the weighted scatter-add after them take
 the same trip count from the same group sizes (``ops/moe_rows.py``,
@@ -36,10 +37,11 @@ from ray_tpu.ops.moe_rows import ROW_TILE
 
 class Routing(NamedTuple):
     """The (token, expert) pairs of one expert layer, sorted by expert with
-    the held experts' pairs first, in the order ``experts_held`` gives."""
-    token: jax.Array        # [T*k] int32: the token of each sorted pair
-    gate: jax.Array         # [T*k] float32: its gate
-    held: jax.Array         # [T*k] bool: the pair's expert lives here
+    the held experts' pairs first, in the order ``experts_held`` gives: the
+    first M = T * min(k, experts held) of them, which hold every held pair."""
+    token: jax.Array        # [M] int32: the token of each sorted pair
+    gate: jax.Array         # [M] float32: its gate
+    held: jax.Array         # [M] bool: the pair's expert lives here
     group_sizes: jax.Array  # [experts held] int32: pairs of each
     experts: jax.Array      # [T, k] int32: the experts each token chose
     gates: jax.Array        # [T, k] float32: and their gates, unsorted
@@ -48,15 +50,22 @@ class Routing(NamedTuple):
 def route(h: jax.Array, router: jax.Array, bias: Optional[jax.Array], *,
           experts_held: Tuple[int, ...], k: int, score: str = "softmax",
           norm_topk: bool = False, scale: float = 1.0, n_group: int = 1,
-          topk_group: int = 1) -> Routing:
+          topk_group: int = 1, keep_input: bool = True) -> Routing:
     """h [T, D], router [D, E] (E the router's published width), bias [E]
     or None -> the layer's routing. The router product is float32 at
     ``highest``: a bf16 product flips near-ties of the top k. The bias
     selects and does not weigh, and takes no gradient. With ``n_group``
-    above 1 the choice is group-limited (``_kept_groups``)."""
+    above 1 the choice is group-limited (``_kept_groups``). The backward
+    pass is left the float32 copy of ``h`` that the product reads, or with
+    ``keep_input`` false makes it again from ``h``."""
     T, E = h.shape[0], router.shape[1]
-    logits = jnp.matmul(h.astype(jnp.float32), router.astype(jnp.float32),
-                        precision=lax.Precision.HIGHEST)
+
+    def product(h, router):
+        return jnp.matmul(h.astype(jnp.float32), router.astype(jnp.float32),
+                          precision=lax.Precision.HIGHEST)
+
+    logits = (product if keep_input else
+              jax.checkpoint(product, prevent_cse=False))(h, router)
     scores = (jax.nn.sigmoid(logits) if score == "sigmoid"
               else jax.nn.softmax(logits, axis=-1))
     select = scores if bias is None else \
@@ -74,6 +83,11 @@ def route(h: jax.Array, router: jax.Array, bias: Optional[jax.Array], *,
     place[list(experts_held)] = np.arange(n_held, dtype=np.int32)
     group = jnp.asarray(place)[experts].reshape(T * k)
     group, pair = lax.sort_key_val(group, jnp.arange(T * k, dtype=jnp.int32))
+    # A token chooses an expert once, so at most ``n_held`` of its k pairs
+    # are held here: behind row T * n_held no pair is, whatever the
+    # routing, and the sorted rows end there (nothing is dropped).
+    if n_held < k:
+        group, pair = group[:T * n_held], pair[:T * n_held]
     group_sizes = jnp.sum(
         group[:, None] == jnp.arange(n_held, dtype=jnp.int32)[None, :],
         axis=0, dtype=jnp.int32)
@@ -105,18 +119,21 @@ def rows_worked(group_sizes: jax.Array, tile: int = ROW_TILE) -> jax.Array:
     return moe_rows.worked_tiles(jnp.sum(group_sizes), tile) * tile
 
 
-def held_experts(h: jax.Array, routing: Routing, e_gate: jax.Array,
-                 e_up: jax.Array, e_down: jax.Array,
-                 tile: int = ROW_TILE) -> jax.Array:
-    """This chip's experts' part of the layer: h [T, D], the held experts'
-    SwiGLU weights [held, D, F], [held, D, F], [held, F, D] in the
-    activations' type -> [T, D], ``sum over a token's held experts of gate
-    * expert(h)``.
+def held_experts(h: jax.Array, routing: Routing,
+                 e_gate: Optional[jax.Array], e_up: jax.Array,
+                 e_down: jax.Array, tile: int = ROW_TILE) -> jax.Array:
+    """This chip's experts' part of the layer: h [T, D] at the width the
+    experts work at (the model's, or a latent's), the held experts' SwiGLU
+    weights [held, D, F], [held, D, F], [held, F, D] in the activations'
+    type, or with ``e_gate`` None the two matrices of an expert that is
+    ``relu(h W1) ** 2 W2`` -> [T, D], ``sum over a token's held experts of
+    gate * expert(h)``.
 
-    Every one of the T x k sorted pairs has a row here, so no pair is
-    dropped whatever the routing, and the rows of pairs whose expert lives
-    elsewhere lie behind the groups, where no pass goes: each one works on
-    ``rows_worked`` rows, in tiles of ``tile`` (a multiple of the grouped
+    Every sorted pair that can be a held expert's has a row here (all T x k,
+    or T x held where fewer experts are held than a token takes), so no
+    pair is dropped whatever the routing, and the rows of pairs whose expert
+    lives elsewhere lie behind the groups, where no pass goes: each one works
+    on ``rows_worked`` rows, in tiles of ``tile`` (a multiple of the grouped
     products' row tile). What a pass leaves behind the routed pairs is
     undefined, so whatever reads a whole array selects by ``held`` first.
     The rows are made again in the backward pass and not kept."""
@@ -128,11 +145,15 @@ def _held_experts(tile, h, routing, e_gate, e_up, e_down):
     n = jnp.sum(routing.group_sizes)
     rows = functools.partial(moe_rows.map_rows, tile=tile)
     xs = moe_rows.gather_rows(h, routing.token, n, tile=tile)
-    xs_gate, xs_up = moe_rows.twice(xs, n, tile=tile)
-    g = grouped_matmul(xs_gate, e_gate, routing.group_sizes)
-    u = grouped_matmul(xs_up, e_up, routing.group_sizes)
-    ys = grouped_matmul(rows(_swiglu_rows, n, g, u), e_down,
-                        routing.group_sizes)
+    if e_gate is None:
+        mid = rows(_relu2_rows, n,
+                   grouped_matmul(xs, e_up, routing.group_sizes))
+    else:
+        xs_gate, xs_up = moe_rows.twice(xs, n, tile=tile)
+        g = grouped_matmul(xs_gate, e_gate, routing.group_sizes)
+        u = grouped_matmul(xs_up, e_up, routing.group_sizes)
+        mid = rows(_swiglu_rows, n, g, u)
+    ys = grouped_matmul(mid, e_down, routing.group_sizes)
     # Masked before the gate meets ys: the gate's gradient reads ys' rows.
     gate = jnp.where(routing.held, routing.gate, 0)[:, None]
     ys = rows(lambda y, w: y.astype(jnp.float32) * w, n, ys, gate)
@@ -144,3 +165,7 @@ def _held_experts(tile, h, routing, e_gate, e_up, e_down):
 def _swiglu_rows(g: jax.Array, u: jax.Array) -> jax.Array:
     return (jax.nn.silu(g.astype(jnp.float32))
             * u.astype(jnp.float32)).astype(g.dtype)
+
+
+def _relu2_rows(u: jax.Array) -> jax.Array:
+    return jnp.square(jax.nn.relu(u.astype(jnp.float32))).astype(u.dtype)

@@ -28,7 +28,8 @@ viewer's operation details (the ``op_name`` of each XLA operation) which
 part of the model the operation is: one of ``SEGMENTS`` (``seg.embed``,
 ``seg.attn_proj``, ``seg.attn_core``, ``seg.mlp``, ``seg.head_loss``, and
 of the further layer kinds ``seg.conv``, ``seg.moe_route``, ``seg.moe_experts``,
-``seg.moe_shared``, ``seg.kda_proj``, ``seg.kda_core``;
+``seg.moe_shared``, ``seg.kda_proj``, ``seg.kda_core``,
+``seg.mamba_proj``, ``seg.mamba_core``, ``seg.moe_latent``;
 the outermost one on the path is the operation's segment, ``norm`` and
 ``rope`` are finer scopes inside), and on the flash kernels one of
 ``KERNELS`` (``flash_fwd``, ``flash_fwd_grouped``, ``flash_bwd_dq``,
@@ -67,15 +68,21 @@ SEGMENTS = ("seg.embed", "seg.attn_proj", "seg.attn_core", "seg.mlp",
             # a KDA layer's norm, projections, taps, gates, output norm,
             # ``W_o`` and residual; its chunked scan (``ops/kda.py``); an
             # expert layer's shared expert
-            "seg.kda_proj", "seg.kda_core", "seg.moe_shared")
+            "seg.kda_proj", "seg.kda_core", "seg.moe_shared",
+            # a Mamba-2 layer's norm, ``W_in``, taps, step, gated norm,
+            # ``W_out`` and residual; its chunked scan (``ops/ssd.py``);
+            # the projections down to and up from the latent width the
+            # routed experts work at
+            "seg.mamba_proj", "seg.mamba_core", "seg.moe_latent")
 # The Pallas kernels of ``ops/flash_attention.py``: each one's ``name=``
 # and the scope around its call; and of ``ops/grouped_matmul.py``, its
 # two kernels named the same way; and of ``ops/moe_rows.py``: the scope
 # around each pass over the sorted rows
 # an expert layer works on (the row-wise one a Pallas call of that name,
 # the gather and the scatter-add each a loop around XLA's own). The
-# chunked scan of ``ops/kda.py`` is plain XLA and has no name here: it is
-# all of ``seg.kda_core``.
+# chunked scans of ``ops/kda.py`` and ``ops/ssd.py`` are plain XLA and have
+# no name here: each is all of its segment (``seg.kda_core``,
+# ``seg.mamba_core``).
 KERNELS = ("flash_fwd", "flash_fwd_grouped", "flash_bwd_dq",
            "flash_bwd_dkv", "moe_gmm", "moe_tgmm",
            "moe_gather_rows", "moe_map_rows", "moe_scatter_rows")

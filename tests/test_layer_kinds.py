@@ -132,7 +132,7 @@ def test_kinds_of_one_seed_do_not_share_draws():
     (dict(moe_every=2), TypeError),
     (dict(capacity_factor=1.25), TypeError),
     (dict(layer_types=("attention", "conv")), ValueError),
-    (dict(layer_types=("attention", "conv", "mamba")), ValueError),
+    (dict(layer_types=("attention", "conv", "retention")), ValueError),
     (dict(router_experts=4, experts_held=(1, 1)), ValueError),
     (dict(router_experts=4, experts_per_token=5), ValueError),
     (dict(router_experts=4, router_score="tanh"), ValueError),

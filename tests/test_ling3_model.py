@@ -254,7 +254,7 @@ def test_the_cached_bodies_refuse_the_new_kinds():
         jax.eval_shape(lambda: decode_step(cfg, params, cache, ints(2),
                                            ints(2), ints(2, 4)))
     with pytest.raises(ValueError, match="layer_types"):
-        TransformerConfig(n_layers=2, layer_types=("kda", "mamba"))
+        TransformerConfig(n_layers=2, layer_types=("kda", "retention"))
     with pytest.raises(ValueError, match="router_groups"):
         dataclasses.replace(cfg, router_groups=5)
     with pytest.raises(ValueError, match="router_groups"):

@@ -52,12 +52,14 @@ CONFIGS = {
 READ_IN_F32 = ("router", "expert_bias", "kda_dt_bias", "kda_a_log")
 
 
-def _parents_scan_layers(cfg, kind, stack, x, positions, attention, tp_axis,
-                         constrain, layers=None):
+def _parents_scan_layers(cfg, kinds, stacks, x, positions, attention,
+                         tp_axis, constrain, layers=None):
     """``_scan_layers`` at the parent of the PR that cast the stacks once
-    (where its caller cut a run out of the stack): the reference."""
+    (where its caller cut a run out of the stack): the reference. It knew
+    runs of one kind, which is all these configurations have."""
+    (kind,), (stack,) = kinds, stacks
     if layers is not None:
-        stack = jax.tree.map(lambda a: a[layers], stack)
+        stack = jax.tree.map(lambda a: a[layers[0]], stack)
 
     def body(x, lp):
         run = partial(transformer._layer, cfg, kind, lp, positions=positions,

@@ -52,16 +52,34 @@ HYBRID = TransformerConfig(
     experts_per_token=4, moe_d_ff=16, router_score="sigmoid", norm_topk=True,
     expert_bias=True, router_groups=4, router_groups_kept=2, shared_d_ff=16,
     dtype=jnp.bfloat16)
+# A third: layers that are a mixer or a feed-forward alone, an expert layer
+# (two-matrix experts at a latent width, a shared expert) and a Mamba-2
+# layer twice, then an attention layer without rope.
+ALONE = TransformerConfig(
+    vocab_size=64, d_model=32, n_layers=5, n_heads=4, n_kv_heads=2,
+    head_dim=8, layer_types=("none", "mamba", "none", "mamba", "attention"),
+    layer_ffns=("moe", "none", "moe", "none", "none"), conv_kernel=4,
+    rope=False, mamba_heads=4, mamba_head_dim=8, mamba_state=16,
+    mamba_groups=2, mamba_chunk=8, router_experts=16, experts_held=(1, 2),
+    experts_per_token=6, moe_d_ff=24, moe_latent=16, ffn_act="relu2",
+    router_score="sigmoid", norm_topk=True, routed_scale=5.0,
+    expert_bias=True, shared_d_ff=40, norm_eps=1e-5, dtype=jnp.bfloat16)
 # The model's train programs, and the segments each one holds: a dense
 # stack has no conv, KDA or expert layer, a pattern holds its own kinds',
 # and the union is the vocabulary.
-TRAIN = {"dense": CFG, "pattern": PATTERN, "hybrid": HYBRID}
+TRAIN = {"dense": CFG, "pattern": PATTERN, "hybrid": HYBRID, "alone": ALONE}
+OF_ALONE = ("seg.mamba_proj", "seg.mamba_core", "seg.moe_latent")
 OF_A_HYBRID = ("seg.kda_proj", "seg.kda_core", "seg.moe_shared")
-OF_A_PATTERN = ("seg.conv", "seg.moe_route", "seg.moe_experts") + OF_A_HYBRID
+OF_A_PATTERN = ("seg.conv", "seg.moe_route", "seg.moe_experts") \
+    + OF_A_HYBRID + OF_ALONE
 SEGMENTS_OF = {
     "dense": tuple(s for s in profiling.SEGMENTS if s not in OF_A_PATTERN),
-    "pattern": tuple(s for s in profiling.SEGMENTS if s not in OF_A_HYBRID),
-    "hybrid": tuple(s for s in profiling.SEGMENTS if s != "seg.conv")}
+    "pattern": tuple(s for s in profiling.SEGMENTS
+                     if s not in OF_A_HYBRID + OF_ALONE),
+    "hybrid": tuple(s for s in profiling.SEGMENTS
+                    if s not in ("seg.conv",) + OF_ALONE),
+    "alone": tuple(s for s in profiling.SEGMENTS if s not in (
+        "seg.mlp", "seg.conv", "seg.kda_proj", "seg.kda_core"))}
 
 
 def _params(cfg=CFG):
@@ -152,6 +170,17 @@ def test_every_matmul_of_the_train_step_lies_under_one_segment(program):
         assert all(by_segment[s] > 0 for s in by_segment if s != "seg.embed")
         assert by_segment["seg.conv"] == 12 and by_segment["seg.mlp"] == 9
         assert by_segment["seg.moe_route"] == 2 * 3    # one a layer, and back
+    elif program == "alone":
+        assert all(by_segment[s] > 0 for s in by_segment if s != "seg.embed")
+        # one body for the two (expert, Mamba) units: a Mamba layer's three
+        # products round its scan (``W_in`` in two parts, ``W_out``), the
+        # latent's two and the shared expert's two, forward and twice
+        # backward (the backward pass makes no product of them again)
+        assert by_segment["seg.mamba_proj"] == 3 * 3
+        assert by_segment["seg.moe_latent"] == 2 * 3
+        assert by_segment["seg.moe_shared"] == 2 * 3
+        assert by_segment["seg.moe_route"] == 3
+        assert by_segment["seg.attn_proj"] == 4 * 3
     else:
         assert all(by_segment[s] > 0 for s in by_segment if s != "seg.embed")
         # a KDA layer's seven products (six in, one out), forward and twice

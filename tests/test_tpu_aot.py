@@ -392,7 +392,8 @@ def test_train_step_names_its_kernels_and_its_fusions(tpu):
         len(named), len(fusions))
     # a uniform stack: every segment but a layer pattern's
     pattern = {"seg.conv", "seg.moe_route", "seg.moe_experts",
-               "seg.kda_proj", "seg.kda_core", "seg.moe_shared"}
+               "seg.kda_proj", "seg.kda_core", "seg.moe_shared",
+               "seg.mamba_proj", "seg.mamba_core", "seg.moe_latent"}
     assert set(profiling.SEGMENTS) - pattern <= {
         r["segment"] for r in table.values()}
 
@@ -469,7 +470,8 @@ def _flash_call_widths(lowered_text):
 
 
 @pytest.mark.parametrize("workload,width", [
-    ("mistral7b-train.seq4k", 128), ("lfm2-24b-a2b-train.seq8k", 64)])
+    ("mistral7b-train.seq4k", 128), ("lfm2-24b-a2b-train.seq8k", 64),
+    ("nemotron3-super-train.seq4k", 128)])
 def test_the_other_cells_flash_kernels_keep_their_one_width(tpu, workload,
                                                             width):
     """A value width of its own is the Ling cell's alone: in the Mistral and
@@ -507,4 +509,50 @@ def test_the_ling_train_step_compiles_and_fits_the_chip(tpu):
     text = compiled.as_text()
     assert "conditional(" not in text
     for name in LING_NAMES:
+        assert re.search(r"(?<![\w.])" + name + r"(?![\w.])", text), name
+
+
+NEMOTRON = "nemotron3-super-train.seq4k"
+NEMOTRON_NAMES = MOE_NAMES + ("seg.mamba_proj", "seg.mamba_core",
+                              "seg.moe_latent", "seg.moe_shared",
+                              "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
+# ``_fits``' bytes of the Nemotron-H cell's step as PR 41 left it (14.32
+# GiB; 17.53 with all T x k sorted rows and the expert layers' float32
+# copies of the stream kept): a step that passes them keeps one again.
+NEMOTRON_STEP_BYTES = 15_377_370_112
+
+
+def test_the_nemotron_train_step_is_two_scans_with_its_names(tpu):
+    """Lowered for the v5e at the cell's size: the pattern EMEMEMEMEM* is
+    one scan of five (expert, Mamba) units and one of the attention layer,
+    not eleven layers written out; the Mamba scan, the latent's projections
+    and the shared expert under their segments, the held experts' passes
+    and the three flash kernels under their names, and no branch. (Lowered
+    only: the compile is the slow test below.)"""
+    text = _lower_cell_step(tpu[0], NEMOTRON).as_text(debug_info=True)
+    assert "stablehlo.case" not in text and "stablehlo.if" not in text
+    for name in NEMOTRON_NAMES:
+        assert re.search(r"(?<![\w.])" + name + r"(?![\w.])", text), name
+    # each grouped kernel is traced once a scan body and pass, not once a
+    # layer: five expert layers share one body
+    calls = [line for line in text.splitlines()
+             if "tpu_custom_call" in line and "moe_gmm" in line]
+    assert 0 < len(calls) <= 8, len(calls)
+    sorted_rows = re.findall(r"tensor<(\d+)x2688xbf16>", text)
+    # a token takes an expert once: 4096 x 8 held, not 4096 x 22, rows
+    assert set(sorted_rows) == {"32768"}
+
+
+@pytest.mark.slow
+def test_the_nemotron_train_step_compiles_and_fits_the_chip(tpu):
+    """The Nemotron-H cell's step through the v5e's compiler: 713.4 M
+    parameters with their AdamW state and a 1 x 4096 step's temporaries fit
+    the chip, in no more than PR 41 left them. Slow-marked as the LFM2
+    step's compile above, for the same reason (half a minute of every
+    core)."""
+    compiled = _lower_cell_step(tpu[0], NEMOTRON).compile()
+    assert _fits(compiled) <= NEMOTRON_STEP_BYTES
+    text = compiled.as_text()
+    assert "conditional(" not in text
+    for name in NEMOTRON_NAMES:
         assert re.search(r"(?<![\w.])" + name + r"(?![\w.])", text), name
