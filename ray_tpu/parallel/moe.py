@@ -5,9 +5,16 @@ plus a selection bias (among the groups of experts kept for the token,
 where the router limits its choice to some), and their gates the scores
 themselves, renormalised over the k where the model says so. No token is
 dropped, under any imbalance, at static shapes: the T x k (token, expert)
-pairs are sorted by expert, the pairs of experts this chip does not hold
-after the held ones,
-and every pass over the sorted rows stops at the last row tile the held
+pairs are sorted by expert, the held experts' in the order the layer is told
+it holds them, an expert's by token, and the pairs of experts this chip does
+not hold after them, by token and then by the place the top k gave each.
+``route`` fetches and puts no value by index, forward or backward: the
+compiler moves one scalar at a time through a gather or a scatter (8-12 ns
+each on a v5e, more than everything else the router does), so a pair's gate
+is selected out of its token's row of scores by a dense comparison, an
+expert's place among the held ones by comparisons with their ids, and the
+gates ride the pairs' sort as an operand, as their cotangents ride a sort
+back. Every pass over the sorted rows stops at the last row tile the held
 groups touch: the products of an expert (three of a SwiGLU, two where it
 is two matrices with a squared ReLU between) run grouped over the held
 groups (``ops/grouped_matmul.py``), and the gather before them, the
@@ -27,7 +34,6 @@ from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax import lax
 
 from ray_tpu.ops import moe_rows
@@ -57,8 +63,16 @@ def route(h: jax.Array, router: jax.Array, bias: Optional[jax.Array], *,
     selects and does not weigh, and takes no gradient. With ``n_group``
     above 1 the choice is group-limited (``_kept_groups``). The backward
     pass is left the float32 copy of ``h`` that the product reads, or with
-    ``keep_input`` false makes it again from ``h``."""
-    T, E = h.shape[0], router.shape[1]
+    ``keep_input`` false makes it again from ``h``.
+
+    The sorted pairs come by group (a held expert's place in
+    ``experts_held``; one group more, behind them, for every other expert)
+    and inside a group in the order token by token, a token's by the place
+    the top k gave them: a held expert's rows are its tokens in rising
+    order. No value is fetched or put by index on the way, in either pass
+    (``_chosen``, ``_sorted_pairs``), and nothing of [T, k, E] waits for the
+    backward pass."""
+    T = h.shape[0]
 
     def product(h, router):
         return jnp.matmul(h.astype(jnp.float32), router.astype(jnp.float32),
@@ -73,27 +87,86 @@ def route(h: jax.Array, router: jax.Array, bias: Optional[jax.Array], *,
     if n_group > 1:
         select = _kept_groups(select, n_group, topk_group)
     _, experts = lax.top_k(select, k)                           # [T, k]
-    gates = jnp.take_along_axis(scores, experts, axis=-1)
+    gates = _chosen(scores, experts)
     if norm_topk:
         gates = gates / (jnp.sum(gates, axis=-1, keepdims=True) + 1e-6)
     gates = gates * scale
     # An expert's place among the held ones; len(held) for one not held.
     n_held = len(experts_held)
-    place = np.full((E,), n_held, np.int32)
-    place[list(experts_held)] = np.arange(n_held, dtype=np.int32)
-    group = jnp.asarray(place)[experts].reshape(T * k)
-    group, pair = lax.sort_key_val(group, jnp.arange(T * k, dtype=jnp.int32))
+    here = experts[:, :, None] == jnp.asarray(experts_held, jnp.int32)
+    group = jnp.min(jnp.where(here, jnp.arange(n_held, dtype=jnp.int32),
+                              n_held), axis=-1).reshape(T * k)
     # A token chooses an expert once, so at most ``n_held`` of its k pairs
     # are held here: behind row T * n_held no pair is, whatever the
     # routing, and the sorted rows end there (nothing is dropped).
-    if n_held < k:
-        group, pair = group[:T * n_held], pair[:T * n_held]
+    rows = T * min(k, n_held)
+    group, pair, gate = (a[:rows] for a in _sorted_pairs(
+        group, gates.reshape(T * k)))
     group_sizes = jnp.sum(
         group[:, None] == jnp.arange(n_held, dtype=jnp.int32)[None, :],
         axis=0, dtype=jnp.int32)
-    return Routing(token=pair // k, gate=gates.reshape(T * k)[pair],
-                   held=group < n_held, group_sizes=group_sizes,
-                   experts=experts, gates=gates)
+    return Routing(token=pair // k, gate=gate, held=group < n_held,
+                   group_sizes=group_sizes, experts=experts, gates=gates)
+
+
+@jax.custom_vjp
+def _chosen(scores: jax.Array, experts: jax.Array) -> jax.Array:
+    """``scores[t, experts[t, j]]`` for scores [T, E] and experts [T, k],
+    with nothing fetched by index: of each row's E scores the one whose
+    place equals the expert's is selected and the others read as zero, so
+    the sum over them is that score to the bit (``where`` and no product:
+    an infinite score of another expert stays where it is). The [T, k, E]
+    mask is the compiler's to fuse into the sum, and the backward pass
+    makes it again from ``experts``. The result stands in memory as a
+    fetched one would: left to merge this sum with a sum over k behind it,
+    the compiler adds a token's gates in another order."""
+    return lax.optimization_barrier(jnp.sum(jnp.where(
+        _chose(experts, scores.shape[1]), scores[:, None, :], 0), axis=-1))
+
+
+def _chose(experts: jax.Array, n_experts: int) -> jax.Array:
+    """[T, k, E] bool: pair (t, j) is token t's choice of expert e."""
+    return experts[:, :, None] == jnp.arange(n_experts, dtype=experts.dtype)
+
+
+def _chosen_fwd(scores, experts):
+    return _chosen(scores, experts), (experts, scores.shape[1])
+
+
+def _chosen_bwd(res, grad):
+    """A token takes an expert once, so at most one of the k terms of an
+    expert's sum is not zero: what a scatter-add would have put there."""
+    experts, n_experts = res
+    return jnp.sum(jnp.where(_chose(experts, n_experts),
+                             grad[:, :, None], 0), axis=1), None
+
+
+_chosen.defvjp(_chosen_fwd, _chosen_bwd)
+
+
+@jax.custom_vjp
+def _sorted_pairs(group: jax.Array, gate: jax.Array):
+    """The N pairs in the order of ``group`` [N], a group's pairs in the
+    order they came in (the sort is stable): their groups, their places
+    before the sort, and ``gate`` [N] in that order. The gates ride the sort
+    as one more operand, and their cotangents ride a sort back whose keys
+    are the places (a permutation's transpose is its inverse): no value is
+    fetched or put by index in either pass."""
+    place = jnp.arange(group.shape[0], dtype=jnp.int32)
+    return lax.sort((group, place, gate), num_keys=1)
+
+
+def _sorted_pairs_fwd(group, gate):
+    out = _sorted_pairs(group, gate)
+    return out, out[1]
+
+
+def _sorted_pairs_bwd(place, grads):
+    # no two places are equal, so stability has nothing to keep apart
+    return None, lax.sort((place, grads[2]), num_keys=1, is_stable=False)[1]
+
+
+_sorted_pairs.defvjp(_sorted_pairs_fwd, _sorted_pairs_bwd)
 
 
 def _kept_groups(select: jax.Array, n_group: int, topk_group: int

@@ -254,6 +254,54 @@ def test_moe_row_passes_compile_under_their_names(tpu, rows):
         assert re.search(r"(?<![\w.])" + name + r"(?![\w.])", text), name
 
 
+# The three expert cells' routers, (T, D, E, what ``route`` is told), each
+# holding experts 0-7, and the sorts the compiled forward and backward of
+# ``route`` held while it fetched its gates by index (``lax.top_k`` is a
+# row sort on the v5e, the groups' two more; then the pairs' one).
+CELLS_ROUTERS = {
+    "lfm2": (8192, 2048, 64, 2, dict(
+        k=4, score="sigmoid", norm_topk=True, scale=1.0)),
+    "ling3": (4096, 2560, 512, 4, dict(
+        k=8, score="sigmoid", norm_topk=True, scale=2.5, n_group=8,
+        topk_group=4)),
+    "nemotron3": (4096, 4096, 512, 2, dict(
+        k=22, score="sigmoid", norm_topk=True, scale=5.0,
+        keep_input=False)),
+}
+
+
+@pytest.mark.parametrize("cell", list(CELLS_ROUTERS))
+def test_route_fetches_and_puts_nothing_by_index(tpu, cell):
+    """``route`` alone through the v5e's compiler at a cell's size, forward
+    and gradient in one program: no ``gather`` or ``scatter`` as large as
+    the T x k pairs is left (the compiler moves one 4-byte scalar every
+    8-12 ns through those: 1 to 3 ms a layer went there until PR 42), and
+    the backward pass costs one sort more, the one that undoes the pairs'."""
+    from ray_tpu.parallel import moe
+
+    T, D, E, sorts_before, kw = CELLS_ROUTERS[cell]
+    one = SingleDeviceSharding(tpu[0])
+    rows = T * min(kw["k"], 8)
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one)
+
+    def weighed(h, router, bias, weights):
+        r = moe.route(h, router, bias, experts_held=tuple(range(8)), **kw)
+        return jnp.sum(jnp.where(r.held, r.gate, 0) * weights), r
+
+    text = jax.jit(jax.value_and_grad(
+        weighed, argnums=(0, 1), has_aux=True)).lower(
+            shape((T, D), jnp.bfloat16), shape((D, E), jnp.float32),
+            shape((E,), jnp.float32), shape((rows,), jnp.float32)
+        ).compile().as_text()
+    by_index = re.findall(r"= \(?\w+\[([\d,]*)\][^=]*? (gather|scatter)\(", text)
+    for dims, op in by_index:
+        size = int(np.prod([int(d) for d in dims.split(",") if d]))
+        assert size < T * kw["k"], (op, dims)
+    assert len(re.findall(r" sort\(", text)) == sorts_before + 1
+
+
 def test_rms_norm_fused_compiles(tpu):
     one = SingleDeviceSharding(tpu[0])
     x = jax.ShapeDtypeStruct((8, 1024, 2048), jnp.bfloat16, sharding=one)
@@ -302,17 +350,20 @@ def _fits(compiled) -> float:
     return used
 
 
-# ``_fits``' bytes of each train step at the parent of PR 40, whose layer
-# scan ran over the float32 masters, kept a second bfloat16 copy of every
-# layer's weights for the backward pass and handed back float32 gradient
-# stacks (compiles in this sandbox at db2cca6). Since then the scan runs
-# over one bfloat16 stack (11.26, 14.42, 12.68 and 12.76 GiB): a step that
-# passes its parent's bytes holds such a copy again.
-PARENTS_STEP_BYTES = {
+# ``_fits``' bytes a train step may take. The smoke's and Mistral's: their
+# steps' at the parent of PR 40, whose layer scan ran over the float32
+# masters, kept a second bfloat16 copy of every layer's weights for the
+# backward pass and handed back float32 gradient stacks (compiles in this
+# sandbox at db2cca6; since then the scan runs over one bfloat16 stack,
+# 11.26 and 14.42 GiB): a step that passes them holds such a copy again.
+# The expert cells': what their steps compile to since PR 42, whose ``route``
+# keeps the [T, k] experts and the pairs' places for the backward pass and
+# nothing of [T, k, E] (12.68 and 12.76 GiB; 2.3 and 8.4 MB under PR 40's).
+STEP_BYTES = {
     "smoke": 12_106_853_888,                        # 11.28 GiB
     "mistral7b-train.seq4k": 16_358_345_216,        # 15.23 GiB
-    "lfm2-24b-a2b-train.seq8k": 14_169_691_648,     # 13.20 GiB
-    "ling3-flash-train.seq4k": 14_750_168_064,      # 13.74 GiB
+    "lfm2-24b-a2b-train.seq8k": 13_613_803_008,     # 12.68 GiB
+    "ling3-flash-train.seq4k": 13_695_501_312,      # 12.76 GiB
 }
 
 
@@ -364,7 +415,7 @@ def test_train_step_compiles_with_the_flash_kernels(tpu):
     assert lowered.as_text().count("tpu_custom_call") == 3
     compiled = lowered.compile()
     assert _kernel_calls(compiled) == 3
-    assert _fits(compiled) <= PARENTS_STEP_BYTES["smoke"]
+    assert _fits(compiled) <= STEP_BYTES["smoke"]
 
 
 def test_train_step_names_its_kernels_and_its_fusions(tpu):
@@ -423,7 +474,7 @@ def test_the_mistral_train_step_compiles_with_room(tpu):
     more than at the parent."""
     workload = "mistral7b-train.seq4k"
     compiled = _lower_cell_step(tpu[0], workload).compile()
-    assert _fits(compiled) <= PARENTS_STEP_BYTES[workload]
+    assert _fits(compiled) <= STEP_BYTES[workload]
 
 
 MOE_NAMES = ("moe_gmm", "moe_tgmm", "moe_gather_rows", "moe_map_rows",
@@ -452,7 +503,7 @@ def test_the_lfm2_train_step_compiles_and_fits_the_chip(tpu):
     spending chip time on the cell
     (``pytest tests/test_tpu_aot.py -m slow``)."""
     compiled = _lower_lfm2_step(tpu[0]).compile()
-    assert _fits(compiled) <= PARENTS_STEP_BYTES["lfm2-24b-a2b-train.seq8k"]
+    assert _fits(compiled) <= STEP_BYTES["lfm2-24b-a2b-train.seq8k"]
     text = compiled.as_text()
     assert "conditional(" not in text
     for name in MOE_NAMES:
@@ -505,7 +556,7 @@ def test_the_ling_train_step_compiles_and_fits_the_chip(tpu):
     with their AdamW state and a 1 x 4096 step's temporaries fit the chip.
     Slow-marked as the LFM2 step's compile above, for the same reason."""
     compiled = _lower_cell_step(tpu[0], "ling3-flash-train.seq4k").compile()
-    assert _fits(compiled) <= PARENTS_STEP_BYTES["ling3-flash-train.seq4k"]
+    assert _fits(compiled) <= STEP_BYTES["ling3-flash-train.seq4k"]
     text = compiled.as_text()
     assert "conditional(" not in text
     for name in LING_NAMES:
@@ -516,10 +567,12 @@ NEMOTRON = "nemotron3-super-train.seq4k"
 NEMOTRON_NAMES = MOE_NAMES + ("seg.mamba_proj", "seg.mamba_core",
                               "seg.moe_latent", "seg.moe_shared",
                               "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
-# ``_fits``' bytes of the Nemotron-H cell's step as PR 41 left it (14.32
-# GiB; 17.53 with all T x k sorted rows and the expert layers' float32
-# copies of the stream kept): a step that passes them keeps one again.
-NEMOTRON_STEP_BYTES = 15_377_370_112
+# ``_fits``' bytes of the Nemotron-H cell's step since PR 42 (14.31 GiB, 12
+# MB under PR 41's; 17.53 GiB with all T x k sorted rows and the expert
+# layers' float32 copies of the stream kept; a [4096, 22, 512] float32 kept
+# in each of five layers would be 0.9 GB): a step that passes them keeps
+# one again.
+NEMOTRON_STEP_BYTES = 15_365_417_472
 
 
 def test_the_nemotron_train_step_is_two_scans_with_its_names(tpu):
