@@ -188,3 +188,48 @@ def test_device_profile_trace(tmp_path):
             (x * 2 + 1).sum().block_until_ready()
     files = trace_files(logdir)
     assert files, "no .xplane.pb produced"
+
+
+def test_a_capture_has_a_clock_and_the_programs_spans_on_it(tmp_path):
+    """``profile_trace`` leaves the epoch of the capture's start beside the
+    capture, and a program built inside the block comes back from
+    ``host_spans`` on the capture's own axis, inside its bounds; one built
+    before the block does not come back."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.util.profiling import (CLOCK_FILE, build_log, host_spans,
+                                        profile_trace)
+
+    x = jnp.arange(96.0)
+
+    def built_before_the_capture(v):
+        return v * 5 - 2
+
+    def built_in_the_capture(v):
+        return (v * 3 + 1).sum()
+
+    build_log()                         # the log listens from here on
+    jax.jit(built_before_the_capture)(x).block_until_ready()
+    logdir = str(tmp_path / "trace")
+    before = time.time_ns()
+    with profile_trace(logdir):
+        jax.jit(built_in_the_capture)(x).block_until_ready()
+    after = time.time_ns()
+    with open(os.path.join(logdir, CLOCK_FILE)) as f:
+        clock = json.load(f)
+    assert before <= clock["epoch_ns_at_start"] \
+        <= clock["epoch_ns_at_stop"] <= after
+    length = clock["epoch_ns_at_stop"] - clock["epoch_ns_at_start"]
+    spans = host_spans(logdir)
+    mine = [s for s in spans if s[0] == "build:jit(built_in_the_capture)"]
+    assert len(mine) == 1
+    _, start_ns, duration_ns = mine[0]
+    assert 0 <= start_ns and duration_ns > 0
+    assert start_ns + duration_ns <= length
+    assert all(s[1] < length and s[1] + s[2] > 0 for s in spans)
+    assert "jit(built_before_the_capture)" in {
+        r["name"] for r in build_log()}
+    assert "build:jit(built_before_the_capture)" not in {
+        s[0] for s in spans}
+    assert [s[1] for s in spans] == sorted(s[1] for s in spans)
