@@ -693,10 +693,12 @@ def _kda_residual(cfg, lp, x):
     + dt_bias))``; beta a sigmoid a head; the heads' outputs are RMS-normed
     (one weight ``[head_dim]``), gated by ``sigmoid(z W_g)`` and projected.
     No rope: the decay carries position. Two segments: the recurrence
-    itself is ``seg.kda_core``. Of what surrounds it the backward pass is
-    left the six projections' outputs and makes the elementwise chains
-    behind them again (some twenty ``[S, H * head_dim]`` arrays a layer
-    otherwise)."""
+    itself is ``seg.kda_core``, and keeps for its backward pass its five
+    inputs, its chunks' inverses and the state each chunk starts from (42
+    MB a layer at 4096 tokens of 8 heads of 128, ``ops/kda.py``); it is not
+    made a second time. Of what surrounds it the backward pass is left the
+    six projections' outputs and makes the elementwise chains behind them
+    again (some twenty ``[S, H * head_dim]`` arrays a layer otherwise)."""
     from ray_tpu.ops.kda import kda_chunk
 
     keep_products = partial(

@@ -41,9 +41,25 @@ is ``g >= -5``, the lower bound the model's gate has
 
 Products take their operands in the type of ``q`` (bfloat16 in the model,
 float32 in the tests) and accumulate in float32; ``g``, ``b``, the running
-sums, the solve and the state are float32. The backward pass is the
-transpose of this chunked form, made by JAX from the forward under
-``jax.checkpoint``: a layer keeps its five inputs and nothing else.
+sums, the solve and the state are float32.
+
+**The backward pass makes no forward of its own.** The operator is one
+``jax.custom_vjp``. Beside its five inputs a layer keeps two things the
+forward made, each in the forward's own type (float32): the chunks'
+inverses ``(I + A)^-1`` and the state each chunk starts from. At 4096
+positions of 8 heads of 128 that is 8.4 + 33.6 MB a layer. The backward
+pass inverts no triangle and carries no state forward again. What is
+cheap it makes again from the inputs, exactly as the forward made it: the
+running sums, the scalings, both score products (0.5 GFLOP of bfloat16
+beside the passes that make their operands, which the transposes read
+anyway), the right-hand sides, their product with the kept inverse, and
+inside the loop a chunk's ``U`` from the kept state. JAX transposes those
+pieces (``jax.vjp`` of ``_operands`` and of ``_chunk``); the solve's own
+transpose is two products with the inverse (``_solve_transposed``). The
+choice of what to keep is by measurement on the chip (``PERF.md``, PR 44):
+keeping the scan's operands, the scores and the right-hand sides as well
+(97 MB a layer more) read the same rate to 0.07 % and compiled to 0.73 GiB
+more; keeping the running sums as well read 2.7 % slower end to end.
 """
 
 from __future__ import annotations
@@ -59,6 +75,14 @@ _EXP_CAP = 80.0     # exp(80) < float32's largest; only masked columns meet it
 
 def _dot(a, b, dims):
     return lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
+
+
+def _dot_highest(a, b, contract):
+    """A float32 product over the leading axes both share, at the precision
+    the triangular solve's own product has."""
+    batch = tuple(range(a.ndim - 2))
+    return lax.dot_general(a, b, (contract, (batch, batch)),
+                           precision=lax.Precision.HIGHEST)
 
 
 def _scores(rows, cols):
@@ -80,13 +104,104 @@ def kda_chunk(q, k, v, g, beta):
     if pad:
         q, k, v, g, beta = (jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) *
                                     (a.ndim - 2)) for a in (q, k, v, g, beta))
-    o = jax.checkpoint(_kda_chunk)(q, k, v, g, beta)
+    o = _whole_chunks(q, k, v, g, beta)
     return o[:, :T] if pad else o
 
 
-def _kda_chunk(q, k, v, g, beta):
+@jax.custom_vjp
+def _whole_chunks(q, k, v, g, beta):
+    """``kda_chunk`` on whole chunks; its backward pass is ``_backward``."""
+    return _forward(q, k, v, g, beta)[0]
+
+
+def _forward(q, k, v, g, beta):
+    """-> o, and what the backward pass is left."""
+    A, *operands = _operands(q, k, v, g, beta)
+    eye = jnp.broadcast_to(jnp.eye(CHUNK, dtype=A.dtype), A.shape)
+    # one diagonal block a triangle: the solve of the identity is the inverse
+    inverse = lax.linalg.triangular_solve(
+        A, eye, left_side=True, lower=True, unit_diagonal=True)
+    xs = _scanned(inverse, *operands)[1]
+
+    def chunk(S, xs):
+        after, o = _chunk(S, xs)
+        return after, (o, S)
+
+    B, _, H, V = v.shape
+    start = jnp.zeros((B, H, q.shape[-1], V), jnp.float32)
+    _, (o, states) = lax.scan(chunk, start, xs)
+    # [N, B, H, C, V] -> [B, T, H, V]
+    o = jnp.transpose(o, (1, 0, 3, 2, 4)).reshape(v.shape).astype(v.dtype)
+    return o, (q, k, v, g, beta, inverse, states)
+
+
+def _backward(kept, do):
+    """The transpose of ``_forward`` about what it kept."""
+    *inputs, inverse, states = kept
+    (_, *operands), operands_vjp = jax.vjp(_operands, *inputs)
+    solved, xs = _scanned(inverse, *operands)
+    B, _, H, V = do.shape
+    do = jnp.moveaxis(do.astype(jnp.float32).reshape(B, -1, CHUNK, H, V),
+                      (1, 3), (0, 2))
+
+    def chunk(dS, at):
+        S, xs, do = at
+        _, chunk_vjp = jax.vjp(_chunk, S, xs)
+        return chunk_vjp((dS, do))
+
+    _, dxs = lax.scan(chunk, jnp.zeros_like(states[0]), (states, xs, do),
+                      reverse=True)
+    dW, dU0, *dxs = (jnp.moveaxis(a, 0, 2) for a in dxs)
+    dsolved = jnp.concatenate([dW.astype(jnp.float32), dU0], axis=-1)
+    dA, drhs = _solve_transposed(inverse, solved, dsolved)
+    return operands_vjp((dA, drhs, *dxs))
+
+
+_whole_chunks.defvjp(_forward, _backward)
+
+
+def _scanned(inverse, rhs, *others):
+    """``[W | U0] = (I + A)^-1 rhs`` in float32, and the scan's operands
+    chunk by chunk ``[N, B, H, ...]``: ``W`` in the type of ``q``, ``U0``,
+    and the others."""
+    solved = _dot_highest(inverse, rhs, ((4,), (3,)))
+    K = others[0].shape[-1]
+    xs = (solved[..., :K].astype(others[0].dtype), solved[..., K:], *others)
+    return solved, tuple(jnp.moveaxis(a, 2, 0) for a in xs)
+
+
+def _solve_transposed(inverse, solved, dsolved):
+    """The backward pass of ``solved = (I + A)^-1 rhs`` about the inverse
+    and the solution: ``drhs = (I + A)^-T dsolved`` and ``dA = -drhs
+    solved^T`` below the diagonal. Two products; nothing is solved."""
+    nb = inverse.ndim - 2
+    drhs = _dot_highest(inverse, dsolved, ((nb,), (nb,)))
+    dA = _dot_highest(drhs, solved, ((nb + 1,), (nb + 1,)))
+    at = jnp.arange(inverse.shape[-1])
+    return jnp.where(at[:, None] > at[None, :], -dA, 0.0), drhs
+
+
+def _chunk(S, xs):
+    """One chunk of every head from the state it starts at."""
+    W, U0, q_in, Aqk, k_out, keep = xs                     # [B,H,C,...]
+    dt = W.dtype
+    Sd = S.astype(dt)
+    bh = ((0, 1), (0, 1))
+    U = U0 - _dot(W, Sd, (((3,), (2,)), bh))               # [B,H,C,V]
+    Ud = U.astype(dt)
+    o = _dot(q_in, Sd, (((3,), (2,)), bh)) \
+        + _dot(Aqk, Ud, (((3,), (2,)), bh))
+    return keep[..., None] * S + _dot(k_out, Ud, (((2,), (2,)), bh)), o
+
+
+def _operands(q, k, v, g, beta):
+    """Everything of every chunk that does not read the state, ``[B, H, N,
+    CHUNK, ...]``: ``A``, the right-hand sides ``b * [K exp(G) | V]``
+    (float32), and what the scan reads beside the solution: ``Q exp(G)``,
+    ``tril(Aqk)``, ``K exp(G_last - G)`` (in the type of ``q``) and
+    ``exp(G_last)``."""
     B, T, H, K = q.shape
-    V, dt, f32 = v.shape[-1], q.dtype, jnp.float32
+    dt, f32 = q.dtype, jnp.float32
     N, n = T // CHUNK, CHUNK // SUB
 
     def chunks(a):          # [B, T, H, ...] -> [B, H, N, CHUNK, ...]
@@ -113,31 +228,9 @@ def _kda_chunk(q, k, v, g, beta):
     A = jnp.where(below, b * _scores(k_rows, cols), 0.0)
     Aqk = jnp.where(below | (at[:, None] == at[None, :]),
                     _scores(q_rows, cols), 0.0)
-    # (I + A) [W | U0] = b * [K exp(G) | V]
     decay = jnp.exp(G)
+    # (I + A) [W | U0] = b * [K exp(G) | V]
     rhs = jnp.concatenate([b * k32 * decay, b * v.astype(f32)], axis=-1)
-    solved = lax.linalg.triangular_solve(
-        A + jnp.eye(CHUNK, dtype=f32), rhs, left_side=True, lower=True,
-        unit_diagonal=True)
-    W, U0 = solved[..., :K], solved[..., K:]
     last = G[:, :, :, -1:, :]                              # [B,H,N,1,K]
-    per_chunk = (W.astype(dt), U0, (q32 * decay).astype(dt), Aqk.astype(dt),
-                 (k32 * jnp.exp(last - G)).astype(dt), jnp.exp(last[..., 0, :]))
-
-    def chunk(S, xs):
-        """One chunk of every head from the state it starts at."""
-        W, U0, q_in, Aqk, k_out, keep = xs                 # [B,H,C,...]
-        Sd = S.astype(dt)
-        bh = ((0, 1), (0, 1))
-        U = U0 - _dot(W, Sd, (((3,), (2,)), bh))           # [B,H,C,V]
-        Ud = U.astype(dt)
-        o = _dot(q_in, Sd, (((3,), (2,)), bh)) \
-            + _dot(Aqk, Ud, (((3,), (2,)), bh))
-        S = keep[..., None] * S + _dot(k_out, Ud, (((2,), (2,)), bh))
-        return S, o
-
-    xs = jax.tree.map(lambda a: jnp.moveaxis(a, 2, 0), per_chunk)
-    _, o = lax.scan(chunk, jnp.zeros((B, H, K, V), f32), xs)
-    # [N, B, H, C, V] -> [B, T, H, V]
-    return jnp.transpose(o, (1, 0, 3, 2, 4)).reshape(B, T, H, V) \
-        .astype(v.dtype)
+    return (A, rhs, (q32 * decay).astype(dt), Aqk.astype(dt),
+            (k32 * jnp.exp(last - G)).astype(dt), jnp.exp(last[..., 0, :]))

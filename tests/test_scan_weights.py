@@ -206,9 +206,12 @@ def _check_the_scans(cfg, jaxpr):
         assert (sorted(t for t in _types(ys(bwd)) if t[0] in matrices)
                 == sorted(t for t in stacked.values() if t[0] in matrices))
         # No layer, forward or backward, casts a matrix from float32: of
-        # what reaches it so, it rounds the norms' weights and the taps.
+        # the leaves that reach it so, it rounds the norms' weights and the
+        # taps. (What a layer kept of its own forward is no leaf: a KDA
+        # layer's float32 states are rounded for its products again.)
         small = {leaf[0] for name, leaf in leaves.items()
                  if name.endswith(("norm", "taps"))}
+        shapes = {leaf[0] for leaf in leaves.values()}
         for scan in (fwd, bwd):
             body = scan.params["jaxpr"].jaxpr
             slices = {v for v in body.invars[scan.params["num_consts"]
@@ -217,9 +220,9 @@ def _check_the_scans(cfg, jaxpr):
             casts = list(_casts_of(body, slices))
             assert casts or scan is bwd     # the reading finds the norms'
             for eqn in casts:
-                assert (eqn.params["new_dtype"] != BF16
-                        or tuple(eqn.invars[0].aval.shape) in small), (
-                    kind, eqn)
+                shape = tuple(eqn.invars[0].aval.shape)
+                assert (eqn.params["new_dtype"] != BF16 or shape in small
+                        or shape not in shapes), (kind, eqn)
 
 
 LAYOUTS = [name for name in CONFIGS if name != "flat-remat"]

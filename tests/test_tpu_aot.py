@@ -359,11 +359,14 @@ def _fits(compiled) -> float:
 # The expert cells': what their steps compile to since PR 42, whose ``route``
 # keeps the [T, k] experts and the pairs' places for the backward pass and
 # nothing of [T, k, E] (12.68 and 12.76 GiB; 2.3 and 8.4 MB under PR 40's).
+# Ling's since PR 44, whose six KDA layers keep their chunks' inverses and
+# states in place of a second forward (12.76 -> 13.46 GiB; 14.19 with the
+# scan's operands, the scores and the right-hand sides kept as well).
 STEP_BYTES = {
     "smoke": 12_106_853_888,                        # 11.28 GiB
     "mistral7b-train.seq4k": 16_358_345_216,        # 15.23 GiB
     "lfm2-24b-a2b-train.seq8k": 13_613_803_008,     # 12.68 GiB
-    "ling3-flash-train.seq4k": 13_695_501_312,      # 12.76 GiB
+    "ling3-flash-train.seq4k": 14_457_002_496,      # 13.46 GiB
 }
 
 
@@ -540,11 +543,15 @@ LING_NAMES = MOE_NAMES + ("seg.kda_core", "flash_fwd", "flash_bwd_dq",
 def test_the_ling_train_step_holds_its_kernels_at_two_widths(tpu):
     """Lowered for the v5e at the cell's size: the KDA scan under its segment,
     the held experts' passes, and the three flash kernels on operands 192
-    wide for queries and keys and 128 for values, none padded. (Lowered
-    only: the compile is the slow test below.)"""
+    wide for queries and keys and 128 for values, none padded; one
+    inversion of the chunks' triangles for each run of KDA layers (three,
+    two and one), so none made again or solved on a backward path (with
+    the forward made twice: twelve). (Lowered only: the compile is the slow
+    test below.)"""
     text = _lower_cell_step(tpu[0], "ling3-flash-train.seq4k").as_text(
         debug_info=True)
     assert "stablehlo.case" not in text and "stablehlo.if" not in text
+    assert text.count("stablehlo.triangular_solve") == 3
     for name in LING_NAMES:
         assert re.search(r"(?<![\w.])" + name + r"(?![\w.])", text), name
     assert _flash_call_widths(text) == {192, 128}
@@ -559,6 +566,14 @@ def test_the_ling_train_step_compiles_and_fits_the_chip(tpu):
     assert _fits(compiled) <= STEP_BYTES["ling3-flash-train.seq4k"]
     text = compiled.as_text()
     assert "conditional(" not in text
+    # a run of KDA layers: one inversion, on the forward path, and the
+    # chunks' loop once forward and once backward
+    core = [line for line in text.splitlines() if "seg.kda_core" in line]
+    inversions = [line for line in core
+                  if "InvertDiagBlocksLowerTriangular" in line]
+    assert len(inversions) == 3
+    assert not any("transpose(jvp" in line for line in inversions)
+    assert sum(" while(" in line for line in core) == 6
     for name in LING_NAMES:
         assert re.search(r"(?<![\w.])" + name + r"(?![\w.])", text), name
 
