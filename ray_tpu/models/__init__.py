@@ -13,6 +13,7 @@ from ray_tpu.models.transformer import (
     init_kv_cache,
     init_params,
     loss_fn,
+    loss_parts,
     make_spmd_train_step,
     param_specs,
     prefill_chunk,
@@ -27,6 +28,7 @@ __all__ = [
     "init_kv_cache",
     "init_params",
     "loss_fn",
+    "loss_parts",
     "make_spmd_train_step",
     "param_specs",
     "prefill_chunk",

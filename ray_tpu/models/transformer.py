@@ -35,7 +35,9 @@ GQA attention with rotary embeddings, RMSNorm, SwiGLU MLP.
 gated short convolution, Kimi Delta Attention (``kda``: a linear-attention
 layer, its recurrence a chunked scan, ``ops/kda.py``), latent attention
 (``mla``: keys and values expanded from one low-rank latent, a rotary part
-of the keys shared by the heads, values narrower than keys) or a Mamba-2
+of the keys shared by the heads, values narrower than keys; the queries
+one matrix or, ``q_lora_rank``, a latent with a norm of their own; a gate
+a head on the outputs or, ``mla_gate``, none) or a Mamba-2
 mixer (``mamba``: a selective state-space layer, its recurrence the chunked
 scan of ``ops/ssd.py``) (``layer_types``), its feed-forward the dense
 SwiGLU (the ``num_dense_layers`` leading ones) or the routed experts as
@@ -60,6 +62,16 @@ run of ``attention_dense`` and keeps the flat tree ``params["layers"]
 [leaf]``; every other stacks per kind, ``params["layers"][kind][leaf]``
 (``_stacks``). The cached serving bodies run the flat layout and refuse
 the other.
+
+**Multi-token prediction** (``mtp_depth`` 1; DeepSeek-V3, arXiv:2412.19437,
+section 2.2). ``loss_fn`` adds a second loss: behind the stack one module
+(``params["mtp"]``) joins the last hidden state, before the final norm,
+with the embedding of each position's next token (a norm each, one
+projection of both), runs one more causal layer of the last layer's kind
+with weights, router and shared expert of its own, and predicts the token
+after next through the model's own table and head (``_mtp_loss``, one
+segment, ``seg.mtp``). ``loss_parts`` gives the two losses apart. Training
+only: the cached bodies and the manual step refuse it.
 """
 
 from __future__ import annotations
@@ -134,6 +146,11 @@ class TransformerConfig:
     qk_nope_dim: Optional[int] = None
     qk_rope_dim: int = 0
     v_head_dim: Optional[int] = None
+    # Its queries too from a latent of this rank with a norm of its own
+    # (None: one matrix), and whether a sigmoid gate a head weighs the
+    # heads' outputs before ``W_o``.
+    q_lora_rank: Optional[int] = None
+    mla_gate: bool = True
     # Routed experts as published, in every layer after the
     # ``num_dense_layers`` leading ones (0 experts: every layer dense).
     # ``router_experts`` is the router's width, ``experts_held`` which of
@@ -162,6 +179,14 @@ class TransformerConfig:
     # down projection before them and an up projection after their
     # weighted sum, on every token (0: none, the model's width).
     moe_latent: int = 0
+    # Multi-token prediction (training body only; DeepSeek-V3,
+    # arXiv:2412.19437, section 2.2): ``mtp_depth`` modules behind the
+    # stack (0: none; 1), each one more layer of the last layer's kind
+    # behind a joint projection of the stack's last hidden state and the
+    # next token's embedding, sharing the table and the head; its loss,
+    # of the token after next, counts ``mtp_weight`` times.
+    mtp_depth: int = 0
+    mtp_weight: float = 0.3
 
     def __post_init__(self):
         if self.head_dim is None:
@@ -180,6 +205,8 @@ class TransformerConfig:
                 raise ValueError(
                     f"{name} {tuple(listed)}: one of {known} "
                     f"for each of the {self.n_layers} layers")
+        if self.mtp_depth not in (0, 1):
+            raise ValueError(f"mtp_depth {self.mtp_depth}: no module or one")
         kinds = {layer_kind(self, i) for i in range(self.n_layers)}
         ffns = {kind.split("_")[1] for kind in kinds}
         if f"{NONE}_{NONE}" in kinds or (MOE in ffns
@@ -357,15 +384,22 @@ def _kind_leaves(cfg: TransformerConfig, kind: str) -> Dict[str, tuple]:
         H, R = cfg.n_heads, cfg.kv_lora_rank
         qk, rot, dv = cfg.qk_nope_dim + cfg.qk_rope_dim, cfg.qk_rope_dim, \
             cfg.v_head_dim
-        leaves = {"mla_norm": ((D,), None, (None,), f32),
-                  "mla_q": ((D, H * qk), D, ("fsdp", "tp"), mm),
-                  # the latent and the keys' rotary part, one for all heads
-                  "mla_kv_a": ((D, R + rot), D, ("fsdp", None), mm),
-                  "mla_kv_norm": ((R,), None, (None,), f32),
-                  "mla_kv_b": ((R, H * (cfg.qk_nope_dim + dv)), R,
-                               (None, "tp"), mm),
-                  "mla_gate": ((D, H), D, ("fsdp", "tp"), mm),
-                  "mla_out": ((H * dv, D), H * dv, ("tp", "fsdp"), mm)}
+        leaves = {"mla_norm": ((D,), None, (None,), f32)}
+        if cfg.q_lora_rank is None:
+            leaves["mla_q"] = ((D, H * qk), D, ("fsdp", "tp"), mm)
+        else:
+            Rq = cfg.q_lora_rank
+            leaves.update(mla_q_a=((D, Rq), D, ("fsdp", None), mm),
+                          mla_q_norm=((Rq,), None, (None,), f32),
+                          mla_q_b=((Rq, H * qk), Rq, (None, "tp"), mm))
+        leaves.update(
+            # the latent and the keys' rotary part, one for all heads
+            mla_kv_a=((D, R + rot), D, ("fsdp", None), mm),
+            mla_kv_norm=((R,), None, (None,), f32),
+            mla_kv_b=((R, H * (cfg.qk_nope_dim + dv)), R, (None, "tp"), mm))
+        if cfg.mla_gate:
+            leaves["mla_gate"] = ((D, H), D, ("fsdp", "tp"), mm)
+        leaves["mla_out"] = ((H * dv, D), H * dv, ("tp", "fsdp"), mm)
     if ffn == NONE:
         return leaves
     leaves["mlp_norm"] = ((D,), None, (None,), f32)
@@ -428,9 +462,15 @@ def _dense_init(key, shape, fan_in):
             * (1.0 / math.sqrt(fan_in)))
 
 
-def init_params(cfg: TransformerConfig, key: jax.Array) -> Dict[str, Any]:
-    """Stacked-layer param pytree, ``_stacks``' layout. Weights f32
-    (master copy)."""
+def mtp_kind(cfg: TransformerConfig) -> str:
+    """The kind of a multi-token-prediction module's layer: the stack's
+    last layer's."""
+    return layer_kind(cfg, cfg.n_layers - 1)
+
+
+def _draws(key: jax.Array):
+    """(16 keys of ``key``, the function that draws a kind's stack from
+    them): what a seed means for ``init_params``."""
     # One distinct key per weight family: same-shaped families (wq/wk/wv,
     # w_gate/w_up, e_gate/e_up) must not share init, or attention/MLP
     # branches start out identical and training silently degrades.
@@ -458,6 +498,16 @@ def init_params(cfg: TransformerConfig, key: jax.Array) -> Dict[str, Any]:
                 stack[name] = fill((n,) + shape, jnp.float32)
         return stack
 
+    return ks, stack_of
+
+
+def init_params(cfg: TransformerConfig, key: jax.Array) -> Dict[str, Any]:
+    """Stacked-layer param pytree, ``_stacks``' layout. Weights f32
+    (master copy). With multi-token prediction, ``params["mtp"]``: the
+    modules stacked, each its two input norms, its joint projection
+    ``[2 * d_model, d_model]`` (rows: the hidden state's half, then the
+    embedding's), its layer (``mtp_kind``) and its output norm."""
+    ks, stack_of = _draws(key)
     params = {
         "embed": jax.random.normal(ks[0], (cfg.vocab_size, cfg.d_model),
                                    jnp.float32) * 0.02,
@@ -467,15 +517,28 @@ def init_params(cfg: TransformerConfig, key: jax.Array) -> Dict[str, Any]:
     if not cfg.tie_embeddings:
         params["lm_head"] = _dense_init(
             ks[1], (cfg.d_model, cfg.vocab_size), cfg.d_model)
+    if cfg.mtp_depth:
+        n, D, kind = cfg.mtp_depth, cfg.d_model, mtp_kind(cfg)
+        mks, module_stack = _draws(jax.random.fold_in(key, len(KINDS)))
+        ones = jnp.ones((n, D), jnp.float32)
+        params["mtp"] = {
+            "h_norm": ones, "e_norm": ones, "out_norm": ones,
+            "proj": jax.vmap(lambda k: _dense_init(k, (2 * D, D), 2 * D))(
+                jax.random.split(mks[0], n)),
+            "block": module_stack(kind, n, _kind_leaves(cfg, kind))}
     return params
+
+
+def _stack_specs(leaves, lead, role) -> Dict[str, P]:
+    return {name: P(lead, *(role[x] for x in roles))
+            for name, (_shape, _fan_in, roles, _read) in leaves.items()}
 
 
 def _layer_specs(cfg: TransformerConfig, lead, role) -> Dict[str, Any]:
     """PartitionSpecs of the ``layers`` tree: ``lead`` on the stacked
     axis, on each further axis the mesh axis ``role`` gives its role."""
-    return _layers_tree(cfg, lambda _kind, _n, leaves: {
-        name: P(lead, *(role[x] for x in roles))
-        for name, (_shape, _fan_in, roles, _read) in leaves.items()})
+    return _layers_tree(cfg, lambda _kind, _n, leaves: _stack_specs(
+        leaves, lead, role))
 
 
 def param_specs(cfg: TransformerConfig,
@@ -490,15 +553,22 @@ def param_specs(cfg: TransformerConfig,
     # Of several runs, a kind's stack is no contiguous block of layers: no
     # stage axis.
     stage = r.stage if len(layer_runs(cfg)) == 1 else None
+    role = {"tp": r.mlp, "fsdp": r.fsdp_shard, "expert": r.expert,
+            None: None}
     specs = {
         "embed": P(r.vocab, None),
-        "layers": _layer_specs(cfg, stage, {
-            "tp": r.mlp, "fsdp": r.fsdp_shard, "expert": r.expert,
-            None: None}),
+        "layers": _layer_specs(cfg, stage, role),
         "final_norm": P(None),
     }
     if not cfg.tie_embeddings:
         specs["lm_head"] = P(r.fsdp_shard, r.vocab)
+    if cfg.mtp_depth:
+        norm = P(None, None)
+        specs["mtp"] = {
+            "h_norm": norm, "e_norm": norm, "out_norm": norm,
+            "proj": P(None, None, r.fsdp_shard),
+            "block": _stack_specs(_kind_leaves(cfg, mtp_kind(cfg)), None,
+                                  role)}
     return specs
 
 
@@ -808,22 +878,30 @@ def _mamba_out(cfg, lp, x, y, z):
 @jax.named_scope("seg.attn_proj")
 def _project_mla(cfg, lp, x, positions):
     """Latent attention's projections in training form (DeepSeek-V2,
-    arXiv:2405.04434, section 2.1, no query latent; no absorbed weights,
-    no cache), x [B, S, D] -> q [B,S,H,nope+rope], the keys' position-free
-    part [B,S,H,nope], their rotary part [B,S,1,rope] that every head
-    shares, v [B,S,H,Dv], and the heads' output gate [B,S,H]."""
+    arXiv:2405.04434, section 2.1; no absorbed weights, no cache),
+    x [B, S, D] -> q [B,S,H,nope+rope], the keys' position-free part
+    [B,S,H,nope], their rotary part [B,S,1,rope] that every head shares,
+    v [B,S,H,Dv], and the heads' output gate [B,S,H] (None where the
+    configuration has none). The queries are one product, or with a query
+    latent (``q_lora_rank``) ``RMSNorm(z W_qa) W_qb``."""
     dt = cfg.dtype
     B, S, _ = x.shape
     H, nope, R = cfg.n_heads, cfg.qk_nope_dim, cfg.kv_lora_rank
     h = rms_norm(x, lp["mla_norm"], cfg.norm_eps)
-    q = (h @ lp["mla_q"].astype(dt)).reshape(B, S, H, -1)
+    if cfg.q_lora_rank is None:
+        q = h @ lp["mla_q"].astype(dt)
+    else:
+        q = rms_norm(h @ lp["mla_q_a"].astype(dt), lp["mla_q_norm"],
+                     cfg.norm_eps) @ lp["mla_q_b"].astype(dt)
+    q = q.reshape(B, S, H, -1)
     kv_a = h @ lp["mla_kv_a"].astype(dt)
     latent = rms_norm(kv_a[..., :R], lp["mla_kv_norm"], cfg.norm_eps)
     kv = (latent @ lp["mla_kv_b"].astype(dt)).reshape(B, S, H, -1)
     q = jnp.concatenate(
         [q[..., :nope], rope(q[..., nope:], positions, cfg.rope_theta)], -1)
     k_rope = rope(kv_a[..., None, R:], positions, cfg.rope_theta)
-    gate = jax.nn.sigmoid(h @ lp["mla_gate"].astype(dt))
+    gate = jax.nn.sigmoid(h @ lp["mla_gate"].astype(dt)) \
+        if cfg.mla_gate else None
     return q, kv[..., :nope], k_rope, kv[..., nope:], gate
 
 
@@ -838,11 +916,12 @@ def _mla_keys(k_nope, k_rope):
 
 @jax.named_scope("seg.attn_proj")
 def _mla_out(cfg, lp, x, o, gate):
-    """The heads' outputs o [B, S, H, Dv], each under its gate, through
-    ``W_o``, and the residual add."""
+    """The heads' outputs o [B, S, H, Dv], each under its gate where it
+    has one, through ``W_o``, and the residual add."""
     B, S, _ = x.shape
-    o = (o * gate[..., None]).reshape(B, S, -1)
-    return x + o @ lp["mla_out"].astype(cfg.dtype)
+    if gate is not None:
+        o = o * gate[..., None]
+    return x + o.reshape(B, S, -1) @ lp["mla_out"].astype(cfg.dtype)
 
 
 def _moe_residual(cfg, lp, x, alone=False):
@@ -978,7 +1057,9 @@ def moe_load(cfg: TransformerConfig, params: Dict[str, Any],
              tokens: jax.Array) -> Dict[str, jax.Array]:
     """Tokens routed to each held expert in each expert layer of a forward
     pass over ``tokens`` [B, S], from the layers' own routing:
-    ``{kind: int32 [layers of that kind, experts held]}``."""
+    ``{kind: int32 [layers of that kind, experts held]}``, and under
+    ``"mtp"`` a multi-token-prediction module's router (each position's
+    next token is the row's own; the last position takes the first's)."""
     B, S = tokens.shape
     load: Dict[str, list] = {}
     x = _embed(cfg, params, tokens)
@@ -991,6 +1072,14 @@ def moe_load(cfg: TransformerConfig, params: Dict[str, Any],
                               _attention_dense, None)
             if sizes is not None:
                 load.setdefault(kind, []).append(sizes)
+    if cfg.mtp_depth:
+        x = _mtp_input(cfg, params, x, jnp.roll(tokens, -1, axis=1),
+                       lambda x, *_logical: x)
+        lp = jax.tree.map(lambda a: a[0], params["mtp"]["block"])
+        _x, sizes = _layer(cfg, mtp_kind(cfg), lp, x, positions,
+                           _attention_dense, None)
+        if sizes is not None:
+            load["mtp"] = [sizes]
     return {kind: jnp.stack(sizes) for kind, sizes in load.items()}
 
 
@@ -1005,13 +1094,16 @@ def _refuse_pattern(cfg: TransformerConfig, body: str) -> None:
             f"this configuration has a layer pattern "
             f"({[k for k, _s, _n in layer_runs(cfg)]}), which only "
             f"forward/loss_fn run")
+    if cfg.mtp_depth:
+        raise NotImplementedError(
+            f"{body} has no place for a multi-token-prediction module, "
+            f"which only loss_fn runs")
 
 
-def forward(cfg: TransformerConfig, params: Dict[str, Any],
-            tokens: jax.Array,
-            mesh: Optional[Mesh] = None,
-            rules: Optional[ShardingRules] = None) -> jax.Array:
-    """GSPMD path: tokens [B, S] -> logits [B, S, V]. Layers via lax.scan."""
+def _hidden(cfg: TransformerConfig, params, tokens, mesh, rules):
+    """GSPMD path: tokens [B, S] -> (the stack's last hidden state
+    [B, S, D] before the final norm, its positions, the sharding
+    constraint of this mesh). Layers via lax.scan."""
     r = rules or ShardingRules()
 
     def constrain(x, *logical):
@@ -1025,6 +1117,15 @@ def forward(cfg: TransformerConfig, params: Dict[str, Any],
     x = constrain(x, "batch", "sequence", "embed")
     positions = jnp.broadcast_to(jnp.arange(S), (B, S))
     x = _layers(cfg, params["layers"], x, positions, constrain)
+    return x, positions, constrain
+
+
+def forward(cfg: TransformerConfig, params: Dict[str, Any],
+            tokens: jax.Array,
+            mesh: Optional[Mesh] = None,
+            rules: Optional[ShardingRules] = None) -> jax.Array:
+    """GSPMD path: tokens [B, S] -> logits [B, S, V]."""
+    x, _positions, constrain = _hidden(cfg, params, tokens, mesh, rules)
     return constrain(_final_logits(cfg, params, x),
                      "batch", "sequence", "vocab")
 
@@ -1044,17 +1145,71 @@ def _final_logits(cfg, params, x):
                     rms_norm(x, params["final_norm"], cfg.norm_eps))
 
 
+def _token_nll(logits, targets):
+    logp = jax.nn.log_softmax(logits, axis=-1)
+    return -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
+
+
 @jax.named_scope("seg.head_loss")
 def _next_token_nll(logits, targets):
-    logp = jax.nn.log_softmax(logits, axis=-1)
-    nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
-    return jnp.mean(nll)
+    return jnp.mean(_token_nll(logits, targets))
+
+
+def _mtp_input(cfg: TransformerConfig, params, h, next_tokens, constrain):
+    """What a multi-token-prediction module's layer reads, [B, S, D]: the
+    stack's last hidden state ``h`` (before the final norm) and the
+    embedding of each position's next token, each under a norm of its own,
+    joined by one projection."""
+    m = params["mtp"]
+    e = _embed(cfg, params, next_tokens)
+    joined = jnp.concatenate([rms_norm(h, m["h_norm"][0], cfg.norm_eps),
+                              rms_norm(e, m["e_norm"][0], cfg.norm_eps)], -1)
+    return constrain(joined @ m["proj"][0].astype(cfg.dtype),
+                     "batch", "sequence", "embed")
+
+
+@jax.named_scope("seg.mtp")
+def _mtp_loss(cfg: TransformerConfig, params, h, targets, positions,
+              constrain):
+    """The module's loss (DeepSeek-V3, arXiv:2412.19437, section 2.2, depth
+    1): position i joins ``h_i`` with the embedding of ``targets_i`` and
+    predicts ``targets_{i+1}`` through the model's own table and head, so
+    their gradients hold both uses. Between them one causal layer of
+    ``mtp_kind`` with weights of its own, a stack of one through
+    ``_scan_layers``; it runs over all S positions (the flash tiles stay
+    whole), and the last, which has no token after next, is left out of
+    the mean: being causal it moves no other. One segment for all of it:
+    its norms, the join, its layer, its head pass and its loss."""
+    x = _mtp_input(cfg, params, h, targets, constrain)
+    g = _scan_layers(cfg, (mtp_kind(cfg),), (params["mtp"]["block"],), x,
+                     positions, _attention_dense, None, constrain)
+    out_norm = params["mtp"]["out_norm"][0]
+    logits = constrain(
+        _lm_head(cfg, params, rms_norm(g, out_norm, cfg.norm_eps)),
+        "batch", "sequence", "vocab")
+    nll = _token_nll(logits, jnp.roll(targets, -1, axis=1))
+    return jnp.mean(nll[:, :-1])
+
+
+def loss_parts(cfg: TransformerConfig, params, tokens, targets,
+               mesh=None, rules=None) -> Tuple[jax.Array, Any]:
+    """(the next-token loss, the multi-token-prediction module's loss of
+    the token after next; None without a module), apart."""
+    x, positions, constrain = _hidden(cfg, params, tokens, mesh, rules)
+    main = _next_token_nll(
+        constrain(_final_logits(cfg, params, x),
+                  "batch", "sequence", "vocab"), targets)
+    if not cfg.mtp_depth:
+        return main, None
+    return main, _mtp_loss(cfg, params, x, targets, positions, constrain)
 
 
 def loss_fn(cfg: TransformerConfig, params, tokens, targets,
             mesh=None, rules=None) -> jax.Array:
-    return _next_token_nll(forward(cfg, params, tokens, mesh, rules),
-                           targets)
+    """The training loss: the next-token loss, and ``mtp_weight`` times
+    the module's where the configuration has one."""
+    main, extra = loss_parts(cfg, params, tokens, targets, mesh, rules)
+    return main if extra is None else main + cfg.mtp_weight * extra
 
 
 # ---------------------------------------------------------------------------

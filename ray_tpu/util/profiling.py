@@ -29,7 +29,8 @@ part of the model the operation is: one of ``SEGMENTS`` (``seg.embed``,
 ``seg.attn_proj``, ``seg.attn_core``, ``seg.mlp``, ``seg.head_loss``, and
 of the further layer kinds ``seg.conv``, ``seg.moe_route``, ``seg.moe_experts``,
 ``seg.moe_shared``, ``seg.kda_proj``, ``seg.kda_core``,
-``seg.mamba_proj``, ``seg.mamba_core``, ``seg.moe_latent``;
+``seg.mamba_proj``, ``seg.mamba_core``, ``seg.moe_latent``, and
+``seg.mtp`` round a multi-token-prediction module whole;
 the outermost one on the path is the operation's segment, ``norm`` and
 ``rope`` are finer scopes inside), and on the flash kernels one of
 ``KERNELS`` (``flash_fwd``, ``flash_fwd_grouped``, ``flash_bwd_dq``,
@@ -130,7 +131,10 @@ SEGMENTS = ("seg.embed", "seg.attn_proj", "seg.attn_core", "seg.mlp",
             # ``W_out`` and residual; its chunked scan (``ops/ssd.py``);
             # the projections down to and up from the latent width the
             # routed experts work at
-            "seg.mamba_proj", "seg.mamba_core", "seg.moe_latent")
+            "seg.mamba_proj", "seg.mamba_core", "seg.moe_latent",
+            # a multi-token-prediction module whole, outermost on its path:
+            # its two norms, the join, its layer, its head pass and loss
+            "seg.mtp")
 # The Pallas kernels of ``ops/flash_attention.py``: each one's ``name=``
 # and the scope around its call; and of ``ops/grouped_matmul.py``, its
 # two kernels named the same way; and of ``ops/moe_rows.py``: the scope

@@ -88,23 +88,38 @@ def test_the_model_path_hands_the_kernels_both_widths(monkeypatch):
     assert _rel(got, want) < 2e-6
 
 
-def test_the_layer_is_the_references():
-    """One MLA layer of the program against the family's reference: the
-    latent's norm, the shared rotary key, the two widths, the heads' gate."""
-    cfg = _cfg()
-    params = FAMILY.make_params(MODEL, SEED)
-    lp = jax.tree.map(lambda a: a[0], params["layers"]["mla_moe"])
+@pytest.mark.parametrize("family", ["ling3", "joyai"])
+def test_the_layer_is_the_references(family):
+    """One MLA layer of the program against its family's reference: the
+    latent's norm, the shared rotary key, the two widths; the Ling family's
+    one query matrix and gate a head, the JoyAI family's query latent with
+    a norm of its own and no gate (its reference turns the rotary pairs
+    interleaved and puts the tree's columns back first)."""
+    if family == "ling3":
+        cfg, model, ref = _cfg(), MODEL, REF
+        params = FAMILY.make_params(MODEL, SEED)
+        lp = jax.tree.map(lambda a: a[0], params["layers"]["mla_moe"])
+        published = lp
+    else:
+        import test_joyai_model as joyai
+
+        cfg, model, ref = joyai._cfg(), joyai.MODEL, joyai.REF
+        params = joyai.FAMILY.make_params(model, SEED)
+        lp = jax.tree.map(lambda a: a[0], params["layers"]["mla_moe"])
+        published = ref.published_columns(model, lp)
+        assert set(lp) >= {"mla_q_a", "mla_q_norm", "mla_q_b"}
+        assert "mla_q" not in lp and "mla_gate" not in lp
     x = jax.random.normal(jax.random.PRNGKey(4), (1, 48, 32), jnp.float32)
     with jax.default_matmul_precision("highest"):
         q, k_nope, k_rope, v, gate = transformer._project_mla(
             cfg, lp, x, jnp.arange(48)[None])
     assert q.shape == (1, 48, 2, 24) and k_nope.shape == (1, 48, 2, 16)
     assert k_rope.shape == (1, 48, 1, 8) and v.shape == (1, 48, 2, 16)
-    assert gate.shape == (1, 48, 2)
+    assert gate is None if family == "joyai" else gate.shape == (1, 48, 2)
     keys = transformer._mla_keys(k_nope, k_rope)
     np.testing.assert_array_equal(keys[:, :, 0, 16:], keys[:, :, 1, 16:])
-    z = REF.rms_norm(x[0], lp["mla_norm"], MODEL["rms_norm_eps"])
-    want = REF.mla(MODEL, lp, z, mm_highest)
+    z = ref.rms_norm(x[0], lp["mla_norm"], model["rms_norm_eps"])
+    want = ref.mla(model, published, z, mm_highest)
     with jax.default_matmul_precision("highest"):
         o = transformer._attention_dense(q, keys, v)
         got = transformer._mla_out(cfg, lp, x, o, gate)[0] - x[0]
@@ -113,5 +128,6 @@ def test_the_layer_is_the_references():
     # are the configuration's, not the operator's)
     bare = dataclasses.replace(cfg, qk_rope_dim=0)
     leaves = transformer._kind_leaves(bare, "mla_dense")
-    assert leaves["mla_q"][0] == (32, 2 * 16)
+    query = "mla_q" if family == "ling3" else "mla_q_b"
+    assert leaves[query][0] == (cfg.q_lora_rank or 32, 2 * 16)
     assert leaves["mla_kv_a"][0] == (32, 16)

@@ -64,22 +64,37 @@ ALONE = TransformerConfig(
     experts_per_token=6, moe_d_ff=24, moe_latent=16, ffn_act="relu2",
     router_score="sigmoid", norm_topk=True, routed_scale=5.0,
     expert_bias=True, shared_d_ff=40, norm_eps=1e-5, dtype=jnp.bfloat16)
+# A fourth: latent attention with a query latent and no gate in both
+# layers, a dense MLP and then routed experts with a shared one, and a
+# multi-token-prediction module behind the stack.
+MODULE = TransformerConfig(
+    vocab_size=64, d_model=32, n_layers=2, n_heads=2, n_kv_heads=2, d_ff=64,
+    head_dim=16, layer_types=("mla", "mla"), kv_lora_rank=16, q_lora_rank=24,
+    mla_gate=False, qk_nope_dim=16, qk_rope_dim=8, v_head_dim=16,
+    num_dense_layers=1, router_experts=16, experts_held=(1, 2),
+    experts_per_token=4, moe_d_ff=16, router_score="sigmoid", norm_topk=True,
+    expert_bias=True, shared_d_ff=16, mtp_depth=1, dtype=jnp.bfloat16)
 # The model's train programs, and the segments each one holds: a dense
 # stack has no conv, KDA or expert layer, a pattern holds its own kinds',
 # and the union is the vocabulary.
-TRAIN = {"dense": CFG, "pattern": PATTERN, "hybrid": HYBRID, "alone": ALONE}
+TRAIN = {"dense": CFG, "pattern": PATTERN, "hybrid": HYBRID, "alone": ALONE,
+         "module": MODULE}
+OF_A_MODULE = ("seg.mtp",)
 OF_ALONE = ("seg.mamba_proj", "seg.mamba_core", "seg.moe_latent")
 OF_A_HYBRID = ("seg.kda_proj", "seg.kda_core", "seg.moe_shared")
 OF_A_PATTERN = ("seg.conv", "seg.moe_route", "seg.moe_experts") \
-    + OF_A_HYBRID + OF_ALONE
+    + OF_A_HYBRID + OF_ALONE + OF_A_MODULE
 SEGMENTS_OF = {
     "dense": tuple(s for s in profiling.SEGMENTS if s not in OF_A_PATTERN),
     "pattern": tuple(s for s in profiling.SEGMENTS
-                     if s not in OF_A_HYBRID + OF_ALONE),
+                     if s not in OF_A_HYBRID + OF_ALONE + OF_A_MODULE),
     "hybrid": tuple(s for s in profiling.SEGMENTS
-                    if s not in ("seg.conv",) + OF_ALONE),
+                    if s not in ("seg.conv",) + OF_ALONE + OF_A_MODULE),
     "alone": tuple(s for s in profiling.SEGMENTS if s not in (
-        "seg.mlp", "seg.conv", "seg.kda_proj", "seg.kda_core"))}
+        "seg.mlp", "seg.conv", "seg.kda_proj", "seg.kda_core")
+        + OF_A_MODULE),
+    "module": tuple(s for s in profiling.SEGMENTS if s not in (
+        "seg.conv", "seg.kda_proj", "seg.kda_core") + OF_ALONE)}
 
 
 def _params(cfg=CFG):
@@ -101,6 +116,11 @@ def _paths(compiled_text):
 
 def _segments_on(path):
     return set(re.findall(r"(?<![\w.])seg\.\w+", path))
+
+
+def _outermost(path):
+    """The segment a reader gives ``path`` to: the first on it."""
+    return re.findall(r"(?<![\w.])seg\.\w+", path)[:1]
 
 
 @functools.lru_cache(maxsize=None)
@@ -158,8 +178,12 @@ def test_every_matmul_of_the_train_step_lies_under_one_segment(program):
     lowered, text = _train_step(program)
     matmuls = [p for p, is_matmul in _paths(text) if is_matmul]
     for path in matmuls:
-        assert len(_segments_on(path)) == 1, path
-    by_segment = {s: sum(s in _segments_on(p) for p in matmuls)
+        # one segment; under a multi-token-prediction module, which is
+        # outermost on its path, the module's and its layer's own
+        assert len(_segments_on(path)) == 1 or (
+            _outermost(path) == ["seg.mtp"]
+            and len(_segments_on(path)) == 2), path
+    by_segment = {s: sum([s] == _outermost(p) for p in matmuls)
                   for s in SEGMENTS_OF[program]}
     if program == "dense":
         assert len(matmuls) == lowered.as_text().count(
@@ -181,6 +205,18 @@ def test_every_matmul_of_the_train_step_lies_under_one_segment(program):
         assert by_segment["seg.moe_shared"] == 2 * 3
         assert by_segment["seg.moe_route"] == 3
         assert by_segment["seg.attn_proj"] == 4 * 3
+    elif program == "module":
+        assert all(by_segment[s] > 0 for s in by_segment if s != "seg.embed")
+        # latent attention with a query latent: five products a layer
+        # (``W_qa``, ``W_qb``, ``W_kva``, ``W_kvb``, ``W_o``), forward and
+        # twice backward, in each of the stack's two runs; the module's own
+        # five, its join, its head pass, its router, its shared expert's
+        # three and its experts' lie under ``seg.mtp`` and nowhere else
+        assert by_segment["seg.attn_proj"] == 2 * 5 * 3
+        assert by_segment["seg.head_loss"] == 3
+        assert by_segment["seg.moe_route"] == 3
+        assert by_segment["seg.moe_shared"] == 9
+        assert by_segment["seg.mtp"] >= (5 + 1 + 1 + 1 + 3) * 3
     else:
         assert all(by_segment[s] > 0 for s in by_segment if s != "seg.embed")
         # a KDA layer's seven products (six in, one out), forward and twice

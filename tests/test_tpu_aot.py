@@ -447,7 +447,8 @@ def test_train_step_names_its_kernels_and_its_fusions(tpu):
     # a uniform stack: every segment but a layer pattern's
     pattern = {"seg.conv", "seg.moe_route", "seg.moe_experts",
                "seg.kda_proj", "seg.kda_core", "seg.moe_shared",
-               "seg.mamba_proj", "seg.mamba_core", "seg.moe_latent"}
+               "seg.mamba_proj", "seg.mamba_core", "seg.moe_latent",
+               "seg.mtp"}
     assert set(profiling.SEGMENTS) - pattern <= {
         r["segment"] for r in table.values()}
 
@@ -624,3 +625,51 @@ def test_the_nemotron_train_step_compiles_and_fits_the_chip(tpu):
     assert "conditional(" not in text
     for name in NEMOTRON_NAMES:
         assert re.search(r"(?<![\w.])" + name + r"(?![\w.])", text), name
+
+
+JOYAI = "joyai-flash-train.seq4k"
+JOYAI_NAMES = MOE_NAMES + ("seg.mtp", "seg.moe_shared", "seg.mlp",
+                           "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
+# ``_fits``' bytes of the JoyAI cell's step as PR 45 left it (15.12 GiB:
+# arguments 7.60, temporaries 7.51): a step that passes them keeps more
+# for the backward pass of its six latent-attention bodies than it did.
+JOYAI_STEP_BYTES = 16_233_662_976
+
+
+def test_the_joyai_train_step_holds_the_module_and_three_bodies(tpu):
+    """Lowered for the v5e at the cell's size: the dense layer, one scan of
+    the four expert layers and the multi-token-prediction module's layer,
+    three bodies, each with the three flash kernels on operands 192 wide for
+    queries and keys and 128 for values at all 32 heads; the module's under
+    ``seg.mtp``; the held experts' passes under their names; no branch.
+    (Lowered only: the compile is the slow test below.)"""
+    text = _lower_cell_step(tpu[0], JOYAI).as_text(debug_info=True)
+    assert "stablehlo.case" not in text and "stablehlo.if" not in text
+    for name in JOYAI_NAMES:
+        assert re.search(r"(?<![\w.])" + name + r"(?![\w.])", text), name
+    assert _flash_call_widths(text) == {192, 128}
+    calls = [line for line in text.splitlines()
+             if "tpu_custom_call" in line and "flash_" in line]
+    assert len(calls) == 9
+    assert all("tensor<32x4096x192xbf16>" in line for line in calls)
+    # no sorted row beyond the T x 8 pairs a token's 8 experts make
+    assert set(re.findall(r"tensor<(\d+)x768xbf16>", text)) <= {
+        "32768", "2048", "4096"}
+
+
+@pytest.mark.slow
+def test_the_joyai_train_step_compiles_and_fits_the_chip(tpu):
+    """The JoyAI cell's step through the v5e's compiler: 680.4 M parameters
+    with their AdamW state and a 1 x 4096 step's temporaries (six bodies of
+    latent attention at 32 heads, two head passes) fit the chip, in no more
+    than PR 45 left them. Slow-marked as the LFM2 step's compile above, for
+    the same reason (a minute of every core)."""
+    compiled = _lower_cell_step(tpu[0], JOYAI).compile()
+    assert _fits(compiled) <= JOYAI_STEP_BYTES
+    text = compiled.as_text()
+    assert "conditional(" not in text
+    for name in JOYAI_NAMES:
+        assert re.search(r"(?<![\w.])" + name + r"(?![\w.])", text), name
+    # the module's kernels carry its segment and their own names
+    assert any("seg.mtp" in line and "flash_fwd" in line
+               for line in text.splitlines())
