@@ -656,7 +656,62 @@ def _attention_dense(q, k, v, causal=True, grad=True):
 
 @jax.named_scope("seg.embed")
 def _embed(cfg, params, tokens):
-    return params["embed"].astype(cfg.dtype)[tokens]
+    """The table's rows at ``tokens`` in ``cfg.dtype``, bit for bit
+    ``params["embed"].astype(cfg.dtype)[tokens]``; the table's gradient is
+    ``_table_rows``'s own."""
+    return _table_rows(params["embed"], tokens, cfg.dtype,
+                       cfg.tie_embeddings)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def _table_rows(table, tokens, dtype, copied):
+    """``table``'s rows at ``tokens`` in ``dtype``. Only the rows read are
+    cast, and nothing else of the table is touched; but where a copy of the
+    whole table in ``dtype`` is made anyway (``copied``: a tied head reads
+    one, ``_lm_head``), the rows are that copy's, half as wide to fetch
+    (read from float32 there, LFM2's step lost 1.6 % on the v5e: PERF.md
+    section 6, PR 46). Its backward pass is ``_table_rows_grad``."""
+    if copied:
+        return table.astype(dtype)[tokens]
+    return table[tokens].astype(dtype)
+
+
+def _table_rows_kept(table, tokens, dtype, copied):
+    # the table for its shape and type alone: nothing of it is read again
+    return _table_rows(table, tokens, dtype, copied), (table, tokens)
+
+
+@jax.named_scope("seg.embed")
+def _table_rows_grad(_dtype, _copied, kept, g):
+    """The table's cotangent: the rows' cotangents summed at their ids in
+    float32, whatever type the rows were read in (JAX's own transpose of
+    the look-up sums duplicates in that type and widens the whole table
+    afterwards). A ``custom_vjp``'s backward pass inherits no scope from
+    its forward, so it carries its segment itself."""
+    table, tokens = kept
+    V, D = table.shape
+    g = g.astype(jnp.float32)
+    # A power-of-two block of columns at a time, each block a table of its
+    # own (2560 = 2048 + 512): on the v5e XLA's scatter-add of 4096 rows
+    # costs 270 to 350 ns a row at 2048 and 4096 columns and 1600 to 7100
+    # at 2560, 3584, 5120 and 7168, in bf16 as in f32 (PERF.md section 5,
+    # PR 46); no power-of-two width read slow, and the blocks joined cost
+    # less than the rows summed at a padded width.
+    parts, at = [], 0
+    while at < D:
+        wide = 1 << ((D - at).bit_length() - 1)
+        parts.append(jnp.zeros((V, wide), jnp.float32).at[tokens].add(
+            g[..., at:at + wide]))
+        at += wide
+    total = jnp.concatenate(parts, axis=1)
+    # One sum an instruction: XLA folds the add of two look-ups' sums (a
+    # multi-token-prediction module's second) into one scatter-add of both
+    # ids' rows, which two segments would share.
+    total = lax.optimization_barrier(total)
+    return total.astype(table.dtype), None
+
+
+_table_rows.defvjp(_table_rows_kept, _table_rows_grad)
 
 
 @jax.named_scope("seg.attn_proj")

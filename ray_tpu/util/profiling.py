@@ -147,7 +147,10 @@ SEGMENTS = ("seg.embed", "seg.attn_proj", "seg.attn_core", "seg.mlp",
 # and carries the segment as the flash kernels' does; it reads the chunks'
 # inverses and states the forward kept (42 MB a layer at 4096 tokens of 8
 # heads of 128) and makes no forward of its own. ``ops/ssd.py``'s is still
-# the forward made again under ``jax.checkpoint`` and transposed.
+# the forward made again under ``jax.checkpoint`` and transposed. The
+# table look-up's (``models/transformer.py:_embed``) is a ``custom_vjp``'s
+# too and writes ``seg.embed`` itself: the rows' cotangents summed at
+# their ids into the table's float32 gradient.
 KERNELS = ("flash_fwd", "flash_fwd_grouped", "flash_bwd_dq",
            "flash_bwd_dkv", "moe_gmm", "moe_tgmm",
            "moe_gather_rows", "moe_map_rows", "moe_scatter_rows")

@@ -147,11 +147,15 @@ def train_step():
 
 
 @functools.lru_cache(maxsize=None)
-def _gradient_paths(program):
+def _gradient_text(program):
     cfg = TRAIN[program]
-    text = jax.jit(jax.grad(lambda p, t, y: loss_fn(cfg, p, t, y))).lower(
+    return jax.jit(jax.grad(lambda p, t, y: loss_fn(cfg, p, t, y))).lower(
         _params(cfg), _tokens(2, 16), _tokens(2, 16)).compile().as_text()
-    return _paths(text)
+
+
+@functools.lru_cache(maxsize=None)
+def _gradient_paths(program):
+    return _paths(_gradient_text(program))
 
 
 @pytest.mark.parametrize("program,segment", [
@@ -162,6 +166,24 @@ def test_gradient_holds_each_segment_forward_and_backward(program, segment):
             if segment in _segments_on(p)]
     assert any("jvp(" in p and "transpose(" not in p for p in mine), segment
     assert any("transpose(jvp(" in p for p in mine), segment
+
+
+@pytest.mark.parametrize("program", sorted(TRAIN))
+def test_the_tables_gradient_is_summed_under_its_segment(program):
+    """``_embed``'s backward pass is a ``custom_vjp``'s and writes its scope
+    itself: every sum of row cotangents into a ``[V, D]`` table lies in the
+    backward pass under ``seg.embed`` (a module's second look-up under the
+    module's, which is outermost), is made in float32, and none is made in
+    the rows' type any more."""
+    cfg = TRAIN[program]
+    text = _gradient_text(program)
+    table = rf"\[{cfg.vocab_size},{cfg.d_model}\]\S* scatter\("
+    sums = re.findall(rf"= f32{table}.*?op_name=\"([^\"]*)\"", text)
+    assert all(p.startswith("jit(<lambda>)/transpose(jvp(")
+               and p.endswith("/seg.embed/scatter-add") for p in sums), sums
+    assert sorted(_outermost(p)[0] for p in sums) == (
+        ["seg.embed", "seg.mtp"] if cfg.mtp_depth else ["seg.embed"])
+    assert not re.search(rf"= bf16{table}", text)
 
 
 # Matmuls of the dense train step by segment: 4 + 2 + 3 a layer forward,

@@ -362,11 +362,16 @@ def _fits(compiled) -> float:
 # Ling's since PR 44, whose six KDA layers keep their chunks' inverses and
 # states in place of a second forward (12.76 -> 13.46 GiB; 14.19 with the
 # scan's operands, the scores and the right-hand sides kept as well).
+# Since PR 46 the table's gradient is summed in float32 zeros where bf16 ones
+# were: LFM2's [8192, 2048] 0.1 MB more (its tied table's rows are still
+# read off the head's bf16 copy), Ling's 0.6 MB
+# more (its 2560 columns summed as 2048 + 512 and joined, where the backward
+# pass has spent its residuals), Mistral's the same to the byte.
 STEP_BYTES = {
     "smoke": 12_106_853_888,                        # 11.28 GiB
     "mistral7b-train.seq4k": 16_358_345_216,        # 15.23 GiB
-    "lfm2-24b-a2b-train.seq8k": 13_613_803_008,     # 12.68 GiB
-    "ling3-flash-train.seq4k": 14_457_002_496,      # 13.46 GiB
+    "lfm2-24b-a2b-train.seq8k": 13_613_932_032,     # 12.68 GiB
+    "ling3-flash-train.seq4k": 14_457_615_360,      # 13.46 GiB
 }
 
 
@@ -587,8 +592,8 @@ NEMOTRON_NAMES = MOE_NAMES + ("seg.mamba_proj", "seg.mamba_core",
 # MB under PR 41's; 17.53 GiB with all T x k sorted rows and the expert
 # layers' float32 copies of the stream kept; a [4096, 22, 512] float32 kept
 # in each of five layers would be 0.9 GB): a step that passes them keeps
-# one again.
-NEMOTRON_STEP_BYTES = 15_365_417_472
+# one again. 4.7 MB more since PR 46 (the table's float32 gradient).
+NEMOTRON_STEP_BYTES = 15_370_102_784
 
 
 def test_the_nemotron_train_step_is_two_scans_with_its_names(tpu):
@@ -633,7 +638,10 @@ JOYAI_NAMES = MOE_NAMES + ("seg.mtp", "seg.moe_shared", "seg.mlp",
 # ``_fits``' bytes of the JoyAI cell's step as PR 45 left it (15.12 GiB:
 # arguments 7.60, temporaries 7.51): a step that passes them keeps more
 # for the backward pass of its six latent-attention bodies than it did.
-JOYAI_STEP_BYTES = 16_233_662_976
+# 66 MB more since PR 46 (15.18 GiB): the module's look-up sums its rows
+# into float32 [16160, 2048] where bf16 was, and that gradient waits out
+# the stack's whole backward pass.
+JOYAI_STEP_BYTES = 16_299_951_104
 
 
 def test_the_joyai_train_step_holds_the_module_and_three_bodies(tpu):
