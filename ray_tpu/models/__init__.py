@@ -9,6 +9,7 @@ from ray_tpu.models.draft import draft_config, shift_params
 from ray_tpu.models.transformer import (
     TransformerConfig,
     decode_step,
+    exit_weights,
     forward,
     init_kv_cache,
     init_params,
@@ -24,6 +25,7 @@ __all__ = [
     "TransformerConfig",
     "decode_step",
     "draft_config",
+    "exit_weights",
     "forward",
     "init_kv_cache",
     "init_params",

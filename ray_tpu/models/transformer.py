@@ -72,6 +72,17 @@ with weights, router and shared expert of its own, and predicts the token
 after next through the model's own table and head (``_mtp_loss``, one
 segment, ``seg.mtp``). ``loss_parts`` gives the two losses apart. Training
 only: the cached bodies and the manual step refuse it.
+
+**A looped stack** (``total_ut_steps`` T > 1; Ouro, arXiv:2510.25741).
+The layers run T times a step over their own output with the same weights
+(``_passes``), each pass ending in the final norm, whose output is the next
+pass's input and what an exit gate (``params["exit_gate"]``, one float32
+logit a position) and the head read; the loss weighs the passes'
+next-token losses by the exit distribution the gates give, with an entropy
+term (``_exit_loss``; the gate, the distribution and the weighing under
+``seg.exit``). ``exit_weights`` gives that distribution. With sandwich
+norms (``sandwich_norm``) a layer norms its attention's and its MLP's
+outputs before their residual adds. Training only, as the module.
 """
 
 from __future__ import annotations
@@ -187,6 +198,17 @@ class TransformerConfig:
     # of the token after next, counts ``mtp_weight`` times.
     mtp_depth: int = 0
     mtp_weight: float = 0.3
+    # A looped stack (training body only; Ouro, arXiv:2510.25741): the
+    # layers run ``total_ut_steps`` times over their own output with the
+    # same weights, each pass ending in the final norm; an exit gate after
+    # every pass, and a loss over the passes weighed by the exit
+    # distribution the gates give, plus ``exit_entropy_beta`` times its
+    # negative entropy (1: one pass, no gate, the next-token loss).
+    total_ut_steps: int = 1
+    exit_entropy_beta: float = 0.1
+    # An RMSNorm (weights of its own) on the attention's and the MLP's
+    # outputs before their residual adds: sandwich norms.
+    sandwich_norm: bool = False
 
     def __post_init__(self):
         if self.head_dim is None:
@@ -207,6 +229,14 @@ class TransformerConfig:
                     f"for each of the {self.n_layers} layers")
         if self.mtp_depth not in (0, 1):
             raise ValueError(f"mtp_depth {self.mtp_depth}: no module or one")
+        if self.total_ut_steps < 1 or (
+                (self.total_ut_steps > 1 or self.sandwich_norm)
+                and not (_flat(self) and not self.mtp_depth)):
+            raise ValueError(
+                f"total_ut_steps {self.total_ut_steps}, sandwich_norm "
+                f"{self.sandwich_norm}: a looped stack and sandwich norms "
+                f"are of a stack of attention layers with one dense "
+                f"feed-forward and no multi-token-prediction module")
         kinds = {layer_kind(self, i) for i in range(self.n_layers)}
         ffns = {kind.split("_")[1] for kind in kinds}
         if f"{NONE}_{NONE}" in kinds or (MOE in ffns
@@ -339,6 +369,8 @@ def _kind_leaves(cfg: TransformerConfig, kind: str) -> Dict[str, tuple]:
         if cfg.qk_norm:
             leaves.update(q_norm=((Hd,), None, (None,), f32),
                           k_norm=((Hd,), None, (None,), f32))
+        if cfg.sandwich_norm:
+            leaves["attn_post_norm"] = ((D,), None, (None,), f32)
     elif op == CONV:
         K = cfg.conv_kernel
         leaves = {"conv_norm": ((D,), None, (None,), f32),
@@ -408,6 +440,8 @@ def _kind_leaves(cfg: TransformerConfig, kind: str) -> Dict[str, tuple]:
         leaves.update(w_gate=((D, F), D, ("fsdp", "tp"), mm),
                       w_up=((D, F), D, ("fsdp", "tp"), mm),
                       w_down=((F, D), F, ("tp", "fsdp"), mm))
+        if cfg.sandwich_norm:
+            leaves["mlp_post_norm"] = ((D,), None, (None,), f32)
     else:
         F, E = cfg.moe_d_ff or cfg.d_ff, len(cfg.experts_held)
         W = cfg.moe_latent or D             # the width the experts work at
@@ -506,7 +540,9 @@ def init_params(cfg: TransformerConfig, key: jax.Array) -> Dict[str, Any]:
     (master copy). With multi-token prediction, ``params["mtp"]``: the
     modules stacked, each its two input norms, its joint projection
     ``[2 * d_model, d_model]`` (rows: the hidden state's half, then the
-    embedding's), its layer (``mtp_kind``) and its output norm."""
+    embedding's), its layer (``mtp_kind``) and its output norm. A looped
+    stack's exit gate, ``params["exit_gate"]``: ``w`` [d_model, 1] and
+    ``b`` [1] (zeros)."""
     ks, stack_of = _draws(key)
     params = {
         "embed": jax.random.normal(ks[0], (cfg.vocab_size, cfg.d_model),
@@ -526,6 +562,11 @@ def init_params(cfg: TransformerConfig, key: jax.Array) -> Dict[str, Any]:
             "proj": jax.vmap(lambda k: _dense_init(k, (2 * D, D), 2 * D))(
                 jax.random.split(mks[0], n)),
             "block": module_stack(kind, n, _kind_leaves(cfg, kind))}
+    if cfg.total_ut_steps > 1:
+        params["exit_gate"] = {
+            "w": _dense_init(jax.random.fold_in(key, len(KINDS) + 1),
+                             (cfg.d_model, 1), cfg.d_model),
+            "b": jnp.zeros((1,), jnp.float32)}
     return params
 
 
@@ -569,6 +610,8 @@ def param_specs(cfg: TransformerConfig,
             "proj": P(None, None, r.fsdp_shard),
             "block": _stack_specs(_kind_leaves(cfg, mtp_kind(cfg)), None,
                                   role)}
+    if cfg.total_ut_steps > 1:
+        specs["exit_gate"] = {"w": P(None, None), "b": P(None)}
     return specs
 
 
@@ -737,12 +780,15 @@ def _project_qkv(cfg, lp, x, positions):
 
 @jax.named_scope("seg.attn_proj")
 def _attn_out(cfg, lp, x, o, tp_axis=None):
-    """Output projection of the attention heads o [B, S, Hq, Dh] and the
-    residual add onto x [B, S, D]."""
+    """Output projection of the attention heads o [B, S, Hq, Dh], its
+    norm where the layer has sandwich norms, and the residual add onto
+    x [B, S, D]."""
     B, S, _ = x.shape
     o = o.reshape(B, S, -1) @ lp["wo"].astype(cfg.dtype)
     if tp_axis is not None:
         o = lax.psum(o, tp_axis)  # row-parallel output proj
+    if cfg.sandwich_norm:
+        o = rms_norm(o, lp["attn_post_norm"], cfg.norm_eps)
     return x + o
 
 
@@ -763,9 +809,13 @@ def _attention_ring(sp_axis, q, k, v):
 
 @jax.named_scope("seg.mlp")
 def _mlp_residual(cfg, lp, x, tp_axis=None):
-    """MLP norm, SwiGLU and the residual add, x [B, S, D]."""
+    """MLP norm, SwiGLU, its output's norm where the layer has sandwich
+    norms, and the residual add, x [B, S, D]."""
     h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
-    return x + _swiglu(cfg, lp, h, tp_axis)
+    out = _swiglu(cfg, lp, h, tp_axis)
+    if cfg.sandwich_norm:
+        out = rms_norm(out, lp["mlp_post_norm"], cfg.norm_eps)
+    return x + out
 
 
 def _swiglu(cfg, lp, h, tp_axis):
@@ -1076,7 +1126,11 @@ def _scan_layers(cfg: TransformerConfig, kinds: Tuple[str, ...], stacks,
     step, however many runs read it), and the layer's own casts of them do
     nothing. So the backward pass reads the same stack and keeps no copy
     of a layer's cast weights, and their gradients leave the loop as
-    stacks of that type, widened by this cast's transpose."""
+    stacks of that type, widened by this cast's transpose. (Stacks handed
+    in cast already, as a looped stack's are, pass the cast unchanged.)
+    Each layer is checkpointed where the configuration asks (``remat``)
+    and in a looped stack, whose passes would otherwise keep every layer
+    application's activations for the backward pass."""
     stacks = tuple({name: stack[name].astype(read)
                     for name, (_shape, _fan_in, _roles, read)
                     in _kind_leaves(cfg, kind).items()}
@@ -1089,7 +1143,8 @@ def _scan_layers(cfg: TransformerConfig, kinds: Tuple[str, ...], stacks,
         for kind, lp in zip(kinds, lps):
             run = partial(_layer, cfg, kind, lp, positions=positions,
                           attention=attention, tp_axis=tp_axis)
-            x, _load = jax.checkpoint(run)(x) if cfg.remat else run(x)
+            x, _load = jax.checkpoint(run)(x) \
+                if cfg.remat or cfg.total_ut_steps > 1 else run(x)
             x = constrain(x, "batch", "sequence", "embed")
         return x, None
 
@@ -1142,7 +1197,10 @@ def _refuse_pattern(cfg: TransformerConfig, body: str) -> None:
     """The cached serving bodies run one kind of layer: the state of a
     conv, a KDA or a Mamba layer and an MLA layer's latent have no place in
     the paged cache yet, and an expert layer that holds a share gives a partial
-    result. A wrong answer is worse than none."""
+    result. A looped stack needs a cache a layer a pass, which
+    ``llm/kv_cache.py`` lacks; these bodies and the manual step run each
+    layer once, and have met sandwich norms in no test. A wrong answer is
+    worse than none."""
     if not _flat(cfg):
         raise NotImplementedError(
             f"{body} runs attention layers with one feed-forward kind; "
@@ -1153,12 +1211,26 @@ def _refuse_pattern(cfg: TransformerConfig, body: str) -> None:
         raise NotImplementedError(
             f"{body} has no place for a multi-token-prediction module, "
             f"which only loss_fn runs")
+    if cfg.total_ut_steps > 1 or cfg.sandwich_norm:
+        raise NotImplementedError(
+            f"{body} runs each layer once and without sandwich norms; this "
+            f"configuration has {cfg.total_ut_steps} passes of its stack "
+            f"and sandwich_norm {cfg.sandwich_norm}, which only "
+            f"forward/loss_fn run")
 
 
 def _hidden(cfg: TransformerConfig, params, tokens, mesh, rules):
     """GSPMD path: tokens [B, S] -> (the stack's last hidden state
     [B, S, D] before the final norm, its positions, the sharding
     constraint of this mesh). Layers via lax.scan."""
+    x, positions, constrain = _stream(cfg, params, tokens, mesh, rules)
+    x = _layers(cfg, params["layers"], x, positions, constrain)
+    return x, positions, constrain
+
+
+def _stream(cfg: TransformerConfig, params, tokens, mesh, rules):
+    """GSPMD path: tokens [B, S] -> (their embeddings [B, S, D], their
+    positions, the sharding constraint of this mesh)."""
     r = rules or ShardingRules()
 
     def constrain(x, *logical):
@@ -1171,18 +1243,79 @@ def _hidden(cfg: TransformerConfig, params, tokens, mesh, rules):
     x = _embed(cfg, params, tokens)
     x = constrain(x, "batch", "sequence", "embed")
     positions = jnp.broadcast_to(jnp.arange(S), (B, S))
-    x = _layers(cfg, params["layers"], x, positions, constrain)
     return x, positions, constrain
+
+
+def _passes(cfg: TransformerConfig, params, x, positions, constrain, read):
+    """A looped stack (``total_ut_steps`` T > 1) over the stream ``x``
+    [B, S, D] (``_stream``): the layers T times over their own output,
+    each pass ending in the final norm, whose output ``x_t`` is the next
+    pass's input and what ``read(x_t)`` reads -> (``x_T``, ``read``'s
+    results stacked over the passes). One ``lax.scan`` over the passes
+    around the layers' own: the stacks are cast to the type the layers
+    read them in once, before the first pass, and each leaf's gradient
+    sums its T uses in that type; each layer application is checkpointed
+    (``_scan_layers``), so the backward pass keeps a layer's input a pass
+    and makes the layer again."""
+    layers = {name: params["layers"][name].astype(read_as)   # flat layout
+              for name, (_shape, _fan_in, _roles, read_as)
+              in _kind_leaves(cfg, KINDS[0]).items()}
+
+    def one_pass(x, _):
+        x = _layers(cfg, layers, x, positions, constrain)
+        with jax.named_scope("seg.head_loss"):
+            x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+        return x, read(x)
+
+    return lax.scan(one_pass, x, None, length=cfg.total_ut_steps)
+
+
+@jax.named_scope("seg.exit")
+def _exit_logits(params, x):
+    """The exit gate's logit of each position, float32 [B, S]:
+    ``x w + b``, ``x`` read in float32."""
+    gate = params["exit_gate"]
+    return jnp.matmul(x.astype(jnp.float32), gate["w"],
+                      precision=lax.Precision.HIGHEST)[..., 0] + gate["b"]
+
+
+def _exit_log_probs(logits):
+    """The exit distribution's logarithm over T passes from the gates'
+    logits [T, ...]: with ``lambda_t = sigmoid(logit_t)``, ``p_t =
+    lambda_t prod_{j<t} (1 - lambda_j)`` for t < T and ``p_T = prod_{j<T}
+    (1 - lambda_j)`` (the last pass's gate weighs nothing); in log-sigmoids,
+    so no factor underflows."""
+    stay = jnp.cumsum(jax.nn.log_sigmoid(-logits[:-1]), axis=0)
+    before = jnp.concatenate([jnp.zeros_like(stay[:1]), stay])
+    leave = jnp.concatenate([jax.nn.log_sigmoid(logits[:-1]),
+                             jnp.zeros_like(stay[:1])])
+    return leave + before
+
+
+def exit_weights(cfg: TransformerConfig, params: Dict[str, Any],
+                 tokens: jax.Array) -> jax.Array:
+    """The exit distribution of a looped stack over ``tokens`` [B, S],
+    averaged over the positions: float32 [total_ut_steps], summing to 1."""
+    _x, logits = _passes(cfg, params, *_stream(cfg, params, tokens, None,
+                                               None),
+                         partial(_exit_logits, params))
+    return jnp.mean(jnp.exp(_exit_log_probs(logits)), axis=(1, 2))
 
 
 def forward(cfg: TransformerConfig, params: Dict[str, Any],
             tokens: jax.Array,
             mesh: Optional[Mesh] = None,
             rules: Optional[ShardingRules] = None) -> jax.Array:
-    """GSPMD path: tokens [B, S] -> logits [B, S, V]."""
-    x, _positions, constrain = _hidden(cfg, params, tokens, mesh, rules)
-    return constrain(_final_logits(cfg, params, x),
-                     "batch", "sequence", "vocab")
+    """GSPMD path: tokens [B, S] -> logits [B, S, V]; of a looped stack,
+    its last pass's."""
+    if cfg.total_ut_steps > 1:
+        x, positions, constrain = _stream(cfg, params, tokens, mesh, rules)
+        x, _ = _passes(cfg, params, x, positions, constrain, lambda x: None)
+        logits = _lm_head(cfg, params, x)
+    else:
+        x, _positions, constrain = _hidden(cfg, params, tokens, mesh, rules)
+        logits = _final_logits(cfg, params, x)
+    return constrain(logits, "batch", "sequence", "vocab")
 
 
 @jax.named_scope("seg.head_loss")
@@ -1249,7 +1382,10 @@ def _mtp_loss(cfg: TransformerConfig, params, h, targets, positions,
 def loss_parts(cfg: TransformerConfig, params, tokens, targets,
                mesh=None, rules=None) -> Tuple[jax.Array, Any]:
     """(the next-token loss, the multi-token-prediction module's loss of
-    the token after next; None without a module), apart."""
+    the token after next; None without a module), apart. Of a looped
+    stack: (its exit objective, ``_exit_loss``; None)."""
+    if cfg.total_ut_steps > 1:
+        return _exit_loss(cfg, params, tokens, targets, mesh, rules), None
     x, positions, constrain = _hidden(cfg, params, tokens, mesh, rules)
     main = _next_token_nll(
         constrain(_final_logits(cfg, params, x),
@@ -1259,10 +1395,43 @@ def loss_parts(cfg: TransformerConfig, params, tokens, targets,
     return main, _mtp_loss(cfg, params, x, targets, positions, constrain)
 
 
+def _exit_loss(cfg: TransformerConfig, params, tokens, targets, mesh,
+               rules):
+    """A looped stack's objective (Ouro's Stage I, arXiv:2510.25741): the
+    mean over positions of ``sum_t p_t l_t + exit_entropy_beta * sum_t p_t
+    log p_t``, ``l_t`` the next-token loss of pass t's head and ``p`` the
+    exit distribution of the gates (``_exit_log_probs``); no gradient is
+    stopped. Each pass's head and loss is checkpointed, so of the passes'
+    ``[B, S, V]`` float32 logits one is alive at a time."""
+    x, positions, constrain = _stream(cfg, params, tokens, mesh, rules)
+
+    @jax.named_scope("seg.head_loss")
+    def head_loss(params, x):
+        logits = constrain(_lm_head(cfg, params, x),
+                           "batch", "sequence", "vocab")
+        return _token_nll(logits, targets)
+
+    _x, (logits, nll) = _passes(
+        cfg, params, x, positions, constrain,
+        lambda x: (_exit_logits(params, x),
+                   jax.checkpoint(head_loss)(params, x)))
+    return _exit_objective(cfg, logits, nll)
+
+
+@jax.named_scope("seg.exit")
+def _exit_objective(cfg: TransformerConfig, logits, nll):
+    """The gates' logits and the passes' next-token losses, float32
+    [T, B, S] each -> the exit objective of ``_exit_loss``."""
+    log_p = _exit_log_probs(logits)
+    return jnp.mean(jnp.sum(
+        jnp.exp(log_p) * (nll + cfg.exit_entropy_beta * log_p), axis=0))
+
+
 def loss_fn(cfg: TransformerConfig, params, tokens, targets,
             mesh=None, rules=None) -> jax.Array:
     """The training loss: the next-token loss, and ``mtp_weight`` times
-    the module's where the configuration has one."""
+    the module's where the configuration has one; a looped stack's exit
+    objective."""
     main, extra = loss_parts(cfg, params, tokens, targets, mesh, rules)
     return main if extra is None else main + cfg.mtp_weight * extra
 

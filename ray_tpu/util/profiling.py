@@ -29,8 +29,9 @@ part of the model the operation is: one of ``SEGMENTS`` (``seg.embed``,
 ``seg.attn_proj``, ``seg.attn_core``, ``seg.mlp``, ``seg.head_loss``, and
 of the further layer kinds ``seg.conv``, ``seg.moe_route``, ``seg.moe_experts``,
 ``seg.moe_shared``, ``seg.kda_proj``, ``seg.kda_core``,
-``seg.mamba_proj``, ``seg.mamba_core``, ``seg.moe_latent``, and
-``seg.mtp`` round a multi-token-prediction module whole;
+``seg.mamba_proj``, ``seg.mamba_core``, ``seg.moe_latent``, ``seg.exit``
+round a looped stack's exit gates and the weighing of its passes' losses,
+and ``seg.mtp`` round a multi-token-prediction module whole;
 the outermost one on the path is the operation's segment, ``norm`` and
 ``rope`` are finer scopes inside), and on the flash kernels one of
 ``KERNELS`` (``flash_fwd``, ``flash_fwd_grouped``, ``flash_bwd_dq``,
@@ -132,6 +133,9 @@ SEGMENTS = ("seg.embed", "seg.attn_proj", "seg.attn_core", "seg.mlp",
             # the projections down to and up from the latent width the
             # routed experts work at
             "seg.mamba_proj", "seg.mamba_core", "seg.moe_latent",
+            # a looped stack's exit gates, their distribution over the
+            # passes, its entropy and the passes' losses weighed by it
+            "seg.exit",
             # a multi-token-prediction module whole, outermost on its path:
             # its two norms, the join, its layer, its head pass and loss
             "seg.mtp")

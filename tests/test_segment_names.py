@@ -74,27 +74,35 @@ MODULE = TransformerConfig(
     num_dense_layers=1, router_experts=16, experts_held=(1, 2),
     experts_per_token=4, moe_d_ff=16, router_score="sigmoid", norm_topk=True,
     expert_bias=True, shared_d_ff=16, mtp_depth=1, dtype=jnp.bfloat16)
+# A fifth: the dense stack looped, its two layers run three times with
+# sandwich norms, an exit gate after every pass and the exit objective.
+LOOPED = TransformerConfig(
+    vocab_size=64, d_model=32, n_layers=2, n_heads=4, n_kv_heads=4, d_ff=64,
+    total_ut_steps=3, sandwich_norm=True, dtype=jnp.bfloat16)
 # The model's train programs, and the segments each one holds: a dense
 # stack has no conv, KDA or expert layer, a pattern holds its own kinds',
 # and the union is the vocabulary.
 TRAIN = {"dense": CFG, "pattern": PATTERN, "hybrid": HYBRID, "alone": ALONE,
-         "module": MODULE}
+         "module": MODULE, "looped": LOOPED}
+OF_A_LOOP = ("seg.exit",)
 OF_A_MODULE = ("seg.mtp",)
 OF_ALONE = ("seg.mamba_proj", "seg.mamba_core", "seg.moe_latent")
 OF_A_HYBRID = ("seg.kda_proj", "seg.kda_core", "seg.moe_shared")
 OF_A_PATTERN = ("seg.conv", "seg.moe_route", "seg.moe_experts") \
     + OF_A_HYBRID + OF_ALONE + OF_A_MODULE
 SEGMENTS_OF = {
-    "dense": tuple(s for s in profiling.SEGMENTS if s not in OF_A_PATTERN),
-    "pattern": tuple(s for s in profiling.SEGMENTS
-                     if s not in OF_A_HYBRID + OF_ALONE + OF_A_MODULE),
-    "hybrid": tuple(s for s in profiling.SEGMENTS
-                    if s not in ("seg.conv",) + OF_ALONE + OF_A_MODULE),
+    "dense": tuple(s for s in profiling.SEGMENTS
+                   if s not in OF_A_PATTERN + OF_A_LOOP),
+    "pattern": tuple(s for s in profiling.SEGMENTS if s not in
+                     OF_A_HYBRID + OF_ALONE + OF_A_MODULE + OF_A_LOOP),
+    "hybrid": tuple(s for s in profiling.SEGMENTS if s not in
+                    ("seg.conv",) + OF_ALONE + OF_A_MODULE + OF_A_LOOP),
     "alone": tuple(s for s in profiling.SEGMENTS if s not in (
         "seg.mlp", "seg.conv", "seg.kda_proj", "seg.kda_core")
-        + OF_A_MODULE),
+        + OF_A_MODULE + OF_A_LOOP),
     "module": tuple(s for s in profiling.SEGMENTS if s not in (
-        "seg.conv", "seg.kda_proj", "seg.kda_core") + OF_ALONE)}
+        "seg.conv", "seg.kda_proj", "seg.kda_core") + OF_ALONE + OF_A_LOOP),
+    "looped": tuple(s for s in profiling.SEGMENTS if s not in OF_A_PATTERN)}
 
 
 def _params(cfg=CFG):
@@ -239,6 +247,14 @@ def test_every_matmul_of_the_train_step_lies_under_one_segment(program):
         assert by_segment["seg.moe_route"] == 3
         assert by_segment["seg.moe_shared"] == 9
         assert by_segment["seg.mtp"] >= (5 + 1 + 1 + 1 + 3) * 3
+    elif program == "looped":
+        # one body for all three passes: a layer's products forward, once
+        # more where the backward pass makes the checkpointed layer again,
+        # and twice backward; the head's likewise, once a pass; the exit
+        # gate's forward and its weight's gradient
+        assert by_segment == {"seg.embed": 0, "seg.attn_proj": 4 * 4,
+                              "seg.attn_core": 2 * 4, "seg.mlp": 3 * 4,
+                              "seg.head_loss": 4, "seg.exit": 2}
     else:
         assert all(by_segment[s] > 0 for s in by_segment if s != "seg.embed")
         # a KDA layer's seven products (six in, one out), forward and twice

@@ -449,11 +449,12 @@ def test_train_step_names_its_kernels_and_its_fusions(tpu):
     named = [s for s in fusions if s != segments.UNATTRIBUTED]
     assert len(fusions) > 50 and len(named) >= 0.9 * len(fusions), (
         len(named), len(fusions))
-    # a uniform stack: every segment but a layer pattern's
+    # a uniform stack: every segment but a layer pattern's and a looped
+    # stack's
     pattern = {"seg.conv", "seg.moe_route", "seg.moe_experts",
                "seg.kda_proj", "seg.kda_core", "seg.moe_shared",
                "seg.mamba_proj", "seg.mamba_core", "seg.moe_latent",
-               "seg.mtp"}
+               "seg.mtp", "seg.exit"}
     assert set(profiling.SEGMENTS) - pattern <= {
         r["segment"] for r in table.values()}
 
@@ -663,6 +664,36 @@ def test_the_joyai_train_step_holds_the_module_and_three_bodies(tpu):
     # no sorted row beyond the T x 8 pairs a token's 8 experts make
     assert set(re.findall(r"tensor<(\d+)x768xbf16>", text)) <= {
         "32768", "2048", "4096"}
+
+
+OURO = "ouro-2.6b-train.seq4k"
+# ``_fits``' bytes of the Ouro cell's step as the PR that brought it left
+# them (14.14 GiB: arguments 6.84, the parameters and Adam's two moments;
+# temporaries 7.29): a step that passes them keeps more of its 32 layer
+# applications or its four head passes for the backward pass than a layer's
+# input a pass and one pass's logits.
+OURO_STEP_BYTES = 15_179_355_136
+
+
+def test_the_ouro_train_step_compiles_one_body_for_32_applications(tpu):
+    """The looped stack's step through the v5e's compiler at the cell's
+    size (612.4 M parameters, 8 layers run 4 times, ten seconds): one layer
+    body in a scan of the layers inside a scan of the passes, so the flash
+    forward is lowered twice (the forward and the checkpoint's making it
+    again) and each backward kernel once, at 16 heads of 128; no branch; it
+    fits the chip in no more than it took when it came."""
+    lowered = _lower_cell_step(tpu[0], OURO)
+    text = lowered.as_text(debug_info=True)
+    assert "stablehlo.case" not in text and "stablehlo.if" not in text
+    calls = [line for line in text.splitlines()
+             if "tpu_custom_call" in line and "flash_" in line]
+    assert len(calls) == 4
+    assert all("tensor<16x4096x128xbf16>" in line for line in calls)
+    for name in ("seg.exit", "seg.head_loss", "rematted_computation"):
+        assert name in text, name
+    compiled = lowered.compile()
+    assert _fits(compiled) <= OURO_STEP_BYTES
+    assert _kernel_calls(compiled) == 4
 
 
 @pytest.mark.slow
