@@ -98,6 +98,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ray_tpu.ops.flash_attention import FLASH_LSE, FLASH_OUT
 from ray_tpu.parallel.mesh import mesh_shape
 from ray_tpu.parallel import moe
 from ray_tpu.parallel.ring_attention import ring_attention
@@ -1113,6 +1114,12 @@ def _layer(cfg: TransformerConfig, kind: str, lp, x, positions,
     return _mlp_residual(cfg, lp, x, tp_axis), None
 
 
+# What a layer's checkpoint keeps beyond its input: the flash forward's two
+# residuals, about a layer input's bytes, for a kernel not run again.
+_KEEP_FLASH = jax.checkpoint_policies.save_only_these_names(FLASH_OUT,
+                                                            FLASH_LSE)
+
+
 def _scan_layers(cfg: TransformerConfig, kinds: Tuple[str, ...], stacks,
                  x, positions, attention, tp_axis, constrain, layers=None):
     """A run of equal units of layers, a unit one layer of each of
@@ -1130,7 +1137,12 @@ def _scan_layers(cfg: TransformerConfig, kinds: Tuple[str, ...], stacks,
     in cast already, as a looped stack's are, pass the cast unchanged.)
     Each layer is checkpointed where the configuration asks (``remat``)
     and in a looped stack, whose passes would otherwise keep every layer
-    application's activations for the backward pass."""
+    application's activations for the backward pass. The checkpoint keeps
+    the layer's input and, where attention ran the flash kernel, the
+    kernel's output and log-sum-exp (``_KEEP_FLASH``): the backward pass
+    makes the rest of the layer again (norms, projections, rope, the
+    feed-forward) and hands the kept two to the backward kernels, and runs
+    no flash forward a second time."""
     stacks = tuple({name: stack[name].astype(read)
                     for name, (_shape, _fan_in, _roles, read)
                     in _kind_leaves(cfg, kind).items()}
@@ -1143,7 +1155,7 @@ def _scan_layers(cfg: TransformerConfig, kinds: Tuple[str, ...], stacks,
         for kind, lp in zip(kinds, lps):
             run = partial(_layer, cfg, kind, lp, positions=positions,
                           attention=attention, tp_axis=tp_axis)
-            x, _load = jax.checkpoint(run)(x) \
+            x, _load = jax.checkpoint(run, policy=_KEEP_FLASH)(x) \
                 if cfg.remat or cfg.total_ut_steps > 1 else run(x)
             x = constrain(x, "batch", "sequence", "embed")
         return x, None
@@ -1256,7 +1268,8 @@ def _passes(cfg: TransformerConfig, params, x, positions, constrain, read):
     read them in once, before the first pass, and each leaf's gradient
     sums its T uses in that type; each layer application is checkpointed
     (``_scan_layers``), so the backward pass keeps a layer's input a pass
-    and makes the layer again."""
+    and its flash kernel's output and log-sum-exp, and makes the rest of
+    the layer again."""
     layers = {name: params["layers"][name].astype(read_as)   # flat layout
               for name, (_shape, _fan_in, _roles, read_as)
               in _kind_leaves(cfg, KINDS[0]).items()}

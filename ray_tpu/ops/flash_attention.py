@@ -45,10 +45,19 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.ops import backend
 
 NEG_INF = -1e30
+
+# The names of the forward's output and log-sum-exp, the residuals the
+# backward kernels read: a checkpoint whose policy saves them
+# (``jax.checkpoint_policies.save_only_these_names``) hands them to the
+# backward pass in place of running the forward kernel again. Without such
+# a policy a name lowers to nothing.
+FLASH_OUT = "flash_out"
+FLASH_LSE = "flash_lse"
 
 # What the TPU compiler accepts, found by compiling ahead of time for a
 # v5e topology (tests/test_tpu_aot.py holds both sides of each limit):
@@ -472,7 +481,11 @@ def _flash_forward_grouped(q, k, v, causal, scale, block_q, block_k,
 
 
 def _flash_forward(q, k, v, causal, scale, block_q, block_k, interpret):
-    """Returns (out [B,H,Sq,Dv], lse [B,H,8,Sq])."""
+    """Returns (out [B,H,Sq,Dv], lse [B,H,8,Sq]), named ``FLASH_OUT`` and
+    ``FLASH_LSE``: the output the caller reads is the residual the
+    backward pass reads, one named value (a name on the residual alone
+    leaves the primal output unnamed, and a checkpoint then runs the
+    kernel again for it)."""
     from jax.experimental import pallas as pl
 
     B, H, Sq, D = q.shape
@@ -507,7 +520,8 @@ def _flash_forward(q, k, v, causal, scale, block_q, block_k, interpret):
     )
     with jax.named_scope("flash_fwd"):
         out, lse = call(qr, kr, vr)
-    return out.reshape(B, H, Sq, Dv), lse.reshape(B, H, 8, Sq)
+    return (checkpoint_name(out.reshape(B, H, Sq, Dv), FLASH_OUT),
+            checkpoint_name(lse.reshape(B, H, 8, Sq), FLASH_LSE))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
