@@ -1331,13 +1331,19 @@ def forward(cfg: TransformerConfig, params: Dict[str, Any],
     return constrain(logits, "batch", "sequence", "vocab")
 
 
+def _head_weight(cfg, params):
+    """The head's weight [D, V] in ``cfg.dtype``: ``lm_head``, or the
+    embedding table's copy transposed."""
+    if cfg.tie_embeddings:
+        # One table, two uses: the gradient reaches it from both.
+        return params["embed"].astype(cfg.dtype).T
+    return params["lm_head"].astype(cfg.dtype)
+
+
 @jax.named_scope("seg.head_loss")
 def _lm_head(cfg, params, x):
     """f32 logits of final-normed hidden states."""
-    if cfg.tie_embeddings:
-        # One table, two uses: the gradient reaches it from both.
-        return (x @ params["embed"].astype(cfg.dtype).T).astype(jnp.float32)
-    return (x @ params["lm_head"].astype(cfg.dtype)).astype(jnp.float32)
+    return (x @ _head_weight(cfg, params)).astype(jnp.float32)
 
 
 @jax.named_scope("seg.head_loss")
@@ -1347,13 +1353,73 @@ def _final_logits(cfg, params, x):
 
 
 def _token_nll(logits, targets):
+    """The next-token loss a position of given logits, by autodiff's own
+    rules: the plain form the tests hold ``_head_nll`` to."""
     logp = jax.nn.log_softmax(logits, axis=-1)
     return -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
 
 
 @jax.named_scope("seg.head_loss")
-def _next_token_nll(logits, targets):
-    return jnp.mean(_token_nll(logits, targets))
+def _head_nll(cfg, params, x, targets, constrain):
+    """The next-token loss of each position, float32 [B, S], of final-normed
+    hidden states ``x`` and their ``targets``: ``_token_nll(_lm_head(...))``
+    in one ``jax.custom_vjp`` (``_logits_nll``) that keeps the logits in
+    ``cfg.dtype`` and writes nothing of float32 over the vocabulary."""
+    return _logits_nll(x, _head_weight(cfg, params), targets, constrain)
+
+
+@jax.named_scope("seg.head_loss")
+def _final_nll(cfg, params, x, targets, constrain):
+    """The training loss's next-token part: the mean over positions of the
+    loss of the stack's output ``x`` under the final norm."""
+    return jnp.mean(_head_nll(
+        cfg, params, rms_norm(x, params["final_norm"], cfg.norm_eps),
+        targets, constrain))
+
+
+def _is_target(logits, targets):
+    """[..., V] booleans, true at each row's target: a comparison with the
+    columns' index, so no value is fetched or put by index."""
+    return lax.broadcasted_iota(jnp.int32, logits.shape,
+                                logits.ndim - 1) == targets[..., None]
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _logits_nll(x, w, targets, constrain):
+    """``lse(x w) − (x w)[target]`` a position, float32, from the logits
+    ``x w`` in their own type; its backward pass is ``_logits_nll_grad``."""
+    return _logits_nll_kept(x, w, targets, constrain)[0]
+
+
+def _logits_nll_kept(x, w, targets, constrain):
+    logits = constrain(x @ w, "batch", "sequence", "vocab")
+    wide = logits.astype(jnp.float32)
+    top = jnp.max(wide, axis=-1, keepdims=True)
+    lse = top[..., 0] + jnp.log(jnp.sum(jnp.exp(wide - top), axis=-1))
+    picked = jnp.sum(jnp.where(_is_target(logits, targets), wide, 0.0),
+                     axis=-1)
+    return lse - picked, (x, w, targets, logits, lse)
+
+
+@jax.named_scope("seg.head_loss")
+def _logits_nll_grad(constrain, kept, dnll):
+    """The cotangents of ``x`` and ``w``: the logits' cotangent ``(softmax −
+    onehot) dnll`` made in one elementwise pass from the logits and the
+    log-sum-exp and cast to the logits' type, as JAX's transpose of the cast
+    casts it, then the two products (the transpose of a log-softmax and a
+    pick would scatter into float32 zeros over the vocabulary and read them
+    twice). It writes its segment itself, as ``_table_rows_grad`` does, so
+    the join's segment does not rest on the names JAX gives a transpose."""
+    x, w, targets, logits, lse = kept
+    p = jnp.exp(logits.astype(jnp.float32) - lse[..., None])
+    g = jnp.where(_is_target(logits, targets), p - 1.0, p) * dnll[..., None]
+    g = constrain(g.astype(logits.dtype), "batch", "sequence", "vocab")
+    dx = g @ w.T
+    dw = jnp.einsum("...d,...v->dv", x, g)
+    return dx, dw, None
+
+
+_logits_nll.defvjp(_logits_nll_kept, _logits_nll_grad)
 
 
 def _mtp_input(cfg: TransformerConfig, params, h, next_tokens, constrain):
@@ -1385,10 +1451,8 @@ def _mtp_loss(cfg: TransformerConfig, params, h, targets, positions,
     g = _scan_layers(cfg, (mtp_kind(cfg),), (params["mtp"]["block"],), x,
                      positions, _attention_dense, None, constrain)
     out_norm = params["mtp"]["out_norm"][0]
-    logits = constrain(
-        _lm_head(cfg, params, rms_norm(g, out_norm, cfg.norm_eps)),
-        "batch", "sequence", "vocab")
-    nll = _token_nll(logits, jnp.roll(targets, -1, axis=1))
+    nll = _head_nll(cfg, params, rms_norm(g, out_norm, cfg.norm_eps),
+                    jnp.roll(targets, -1, axis=1), constrain)
     return jnp.mean(nll[:, :-1])
 
 
@@ -1400,9 +1464,7 @@ def loss_parts(cfg: TransformerConfig, params, tokens, targets,
     if cfg.total_ut_steps > 1:
         return _exit_loss(cfg, params, tokens, targets, mesh, rules), None
     x, positions, constrain = _hidden(cfg, params, tokens, mesh, rules)
-    main = _next_token_nll(
-        constrain(_final_logits(cfg, params, x),
-                  "batch", "sequence", "vocab"), targets)
+    main = _final_nll(cfg, params, x, targets, constrain)
     if not cfg.mtp_depth:
         return main, None
     return main, _mtp_loss(cfg, params, x, targets, positions, constrain)
@@ -1414,15 +1476,12 @@ def _exit_loss(cfg: TransformerConfig, params, tokens, targets, mesh,
     mean over positions of ``sum_t p_t l_t + exit_entropy_beta * sum_t p_t
     log p_t``, ``l_t`` the next-token loss of pass t's head and ``p`` the
     exit distribution of the gates (``_exit_log_probs``); no gradient is
-    stopped. Each pass's head and loss is checkpointed, so of the passes'
-    ``[B, S, V]`` float32 logits one is alive at a time."""
+    stopped. Each pass's head and loss (``_head_nll``) is checkpointed, so
+    of the passes' ``[B, S, V]`` logits one is alive at a time."""
     x, positions, constrain = _stream(cfg, params, tokens, mesh, rules)
 
-    @jax.named_scope("seg.head_loss")
     def head_loss(params, x):
-        logits = constrain(_lm_head(cfg, params, x),
-                           "batch", "sequence", "vocab")
-        return _token_nll(logits, targets)
+        return _head_nll(cfg, params, x, targets, constrain)
 
     _x, (logits, nll) = _passes(
         cfg, params, x, positions, constrain,
@@ -1520,7 +1579,7 @@ def make_spmd_train_step(cfg: TransformerConfig, mesh: Mesh, params,
         else:
             x, _ = stage_fn(params["layers"], (x, positions))
 
-        return _next_token_nll(_final_logits(cfg, params, x), targets)
+        return _final_nll(cfg, params, x, targets, lambda y, *_logical: y)
 
     from ray_tpu.parallel.mesh import AXES
 

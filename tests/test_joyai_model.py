@@ -176,7 +176,10 @@ def test_bfloat16_loss_and_gradients_stay_near_the_reference(
 def test_depth_zero_is_the_program_without_the_module():
     """Bit for bit: the tree's other leaves, the loss (the next-token part
     of the two) and its gradients, and the very operations of the loss as
-    it was written before the module (``_next_token_nll(forward(...))``)."""
+    it is written without the module (the next-token loss of the stack's
+    final-normed output, ``_final_nll``); and to rounding the loss and its
+    gradients as autodiff of the logits ``forward`` gives
+    (``_token_nll(forward(...))``)."""
     cfg = _cfg()
     bare = dataclasses.replace(cfg, mtp_depth=0)
     key = jax.random.PRNGKey(3)
@@ -194,11 +197,21 @@ def test_depth_zero_is_the_program_without_the_module():
         np.array_equal, grads,
         {k: v for k, v in main_grads.items() if k != "mtp"})))
     assert loss_parts(bare, without, tokens, targets)[1] is None
-    before = lambda p, t, y: transformer._next_token_nll(
-        transformer.forward(bare, p, t), y)
+
+    def before(p, t, y):
+        x, _positions, constrain = transformer._hidden(bare, p, t, None, None)
+        return transformer._final_nll(bare, p, x, y, constrain)
+
     now = lambda p, t, y: loss_fn(bare, p, t, y)
     assert str(jax.make_jaxpr(now)(without, tokens, targets)) \
         == str(jax.make_jaxpr(before)(without, tokens, targets))
+    of_logits, of_logits_grads = jax.jit(jax.value_and_grad(
+        lambda p: jnp.mean(transformer._token_nll(
+            transformer.forward(bare, p, tokens), targets))))(without)
+    np.testing.assert_allclose(float(loss), float(of_logits), rtol=1e-6)
+    for a, b in zip(jax.tree.leaves(grads), jax.tree.leaves(of_logits_grads)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-5, atol=1e-6)
 
 
 def test_the_interleaved_reference_is_the_half_rotation_program():

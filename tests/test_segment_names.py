@@ -215,6 +215,14 @@ def test_every_matmul_of_the_train_step_lies_under_one_segment(program):
             and len(_segments_on(path)) == 2), path
     by_segment = {s: sum([s] == _outermost(p) for p in matmuls)
                   for s in SEGMENTS_OF[program]}
+    # The next-token loss's backward pass is a ``custom_vjp``'s rule
+    # (``_logits_nll_grad``), which writes its segment itself, inside the
+    # call's: its two products a head pass, x's cotangent and the weight's,
+    # and a module's pass through the head under the module's, outermost.
+    rule = [_outermost(p)[0] for p in matmuls
+            if "transpose(" in p and "/seg.head_loss/seg.head_loss/" in p]
+    assert sorted(rule) == ["seg.head_loss"] * 2 + (
+        ["seg.mtp"] * 2 if program == "module" else []), rule
     if program == "dense":
         assert len(matmuls) == lowered.as_text().count(
             "stablehlo.dot_general") == 30
