@@ -1,9 +1,6 @@
 PYTHON ?= python
 
-.PHONY: test analyze bench bench-control-plane bench-llm \
-	bench-llm-prefix bench-disagg bench-gate bench-chaos \
-	bench-ownership bench-elastic bench-failover bench-trace \
-	bench-flight chaos-gate debug-dump
+.PHONY: test analyze chaos-gate debug-dump
 
 test: analyze
 	JAX_PLATFORMS=cpu $(PYTHON) -m pytest tests/ -q -m 'not slow'
@@ -16,98 +13,6 @@ test: analyze
 # tests/test_raylint.py.
 analyze:
 	$(PYTHON) scripts/raylint.py ray_tpu/
-
-bench:
-	$(PYTHON) bench.py --all
-
-# Host control-plane microbenchmark (non-compiled @remote path through
-# the real scheduler + head/transport): chain 1k, fan-out 10k, cluster
-# fan-out. Prints one JSON line.
-bench-control-plane:
-	JAX_PLATFORMS=cpu $(PYTHON) bench.py --suite control_plane
-
-# Continuous-batching LLM serving: tokens/s vs naive per-request decode
-# plus time-to-first-token on the streamed path. Prints one JSON line.
-bench-llm:
-	JAX_PLATFORMS=cpu $(PYTHON) bench.py --suite llm_serving
-
-# Prefix-cache-aware serving: tokens/s + TTFT on a prefix-heavy
-# workload (shared system prompt, unique tails) with copy-on-write
-# shared prefix blocks vs the caching-disabled engine. One JSON line.
-bench-llm-prefix:
-	JAX_PLATFORMS=cpu $(PYTHON) bench.py --suite llm_prefix
-
-# Disaggregated prefill/decode serving + speculative decoding: p99 TTFT
-# under decode saturation, disagg (1 prefill + 1 decode replica, p2p KV
-# shipping) vs colocated (2 replicas) — the <= 0.7x ratio is asserted
-# in-suite (flight-recorder capture on miss) — plus spec-vs-vanilla
-# decode tokens/s (>= 1.3x, greedy parity asserted). One JSON line;
-# llm_disagg.p99_ttft_ratio is REQUIRED by check_bench.
-bench-disagg:
-	JAX_PLATFORMS=cpu $(PYTHON) bench.py --suite llm_disagg
-	$(PYTHON) scripts/check_bench.py \
-		--require llm_disagg.p99_ttft_ratio
-
-# Chaos x load SLO probe: hundreds of concurrent token streams through
-# a 2-replica LLM deployment with a replica SIGKILLed mid-load and
-# low-priority traffic shed by policy; records p99 TTFT and the
-# effective success rate (shed-by-policy counted separately from
-# failures). One JSON line.
-bench-chaos:
-	JAX_PLATFORMS=cpu $(PYTHON) bench.py --suite chaos_slo
-
-# Ownership-directory flatness probe: a real head + 2 node daemons run
-# a steady-state fan-out, 32 simulated members join, and the driver's
-# owner directory ingests synthetic direct completion reports for 10k
-# then 100k objects — head object-plane RPCs and FT-log appends must
-# stay flat in object count (O(membership)); owner_locate answers are
-# served over the real p2p plane. One JSON line; the flatness headline
-# (ownership.head_rpcs_per_1k_objects) is REQUIRED by check_bench with
-# an ABSOLUTE <= 1.0 gate.
-bench-ownership:
-	JAX_PLATFORMS=cpu $(PYTHON) bench.py --suite ownership
-
-# Elastic production episode: a ramp->spike->fall traffic shape (the
-# seeded loadgen DSL) against an autoscaled LLM serving deployment on
-# REAL autoscaler-launched nodes, with the seeded NodeKiller killing a
-# node mid-ramp and wire faults armed; records p99 TTFT under scale,
-# p99 cold start (node launch -> first token), drain-before-reap
-# counters, and the scale-to-zero wake latency. One JSON line;
-# elastic_slo.p99_ttft_under_scale is REQUIRED by check_bench.
-bench-elastic:
-	JAX_PLATFORMS=cpu $(PYTHON) bench.py --suite elastic_slo
-
-# Head-failover episode: the elastic shape with the PRIMARY HEAD
-# SIGKILLed mid-ramp and a warm standby promoting over the shared
-# state log (epoch fence on the wire); records the measured blackout
-# (first refused head RPC -> first promoted reply), effective success
-# (>= 0.99 asserted, zero ref loss), and post-promotion epoch. One
-# JSON line; head_failover.blackout_s is REQUIRED by check_bench.
-bench-failover:
-	JAX_PLATFORMS=cpu $(PYTHON) bench.py --suite head_failover
-	$(PYTHON) scripts/check_bench.py \
-		--require head_failover.blackout_s
-
-# Tracing inertness probe: the real-cluster fan-out with tracing OFF
-# vs ARMED (spans recorded on every hop, context on every wire frame)
-# — the armed rate must stay >= 0.95x, then the gate requires the
-# committed record to carry the ratio and hold the floor.
-bench-trace:
-	JAX_PLATFORMS=cpu $(PYTHON) bench.py --suite trace_overhead
-	$(PYTHON) scripts/check_bench.py \
-		--require trace_overhead.fanout_ratio \
-		--min trace_overhead.fanout_ratio=0.95
-
-# Flight-recorder inertness probe: the real-cluster fan-out with the
-# recorder + stack sampler armed in EVERY process, A/B'd in-session by
-# toggling sampling cluster-wide (flight_ctl) — the armed rate must
-# stay >= 0.95x, then the gate requires the committed record to carry
-# the ratio and hold the floor.
-bench-flight:
-	JAX_PLATFORMS=cpu $(PYTHON) bench.py --suite flight_overhead
-	$(PYTHON) scripts/check_bench.py \
-		--require flight_overhead.fanout_ratio \
-		--min flight_overhead.fanout_ratio=0.95
 
 # One-command postmortem collection from a live cluster: pulls every
 # process's flight bundle (all-thread stacks, event rings, profile
@@ -122,16 +27,7 @@ debug-dump:
 
 # Deterministic chaos slice inside tier-1 time: the seeded fault-
 # injection / NodeKiller / shedding matrix cells (pytest -m chaos,
-# excluding the slow full-sweep cells), then the bench gate requiring
-# the chaos_slo SLO metric to be present and holding.
+# excluding the slow full-sweep cells).
 chaos-gate:
 	JAX_PLATFORMS=cpu $(PYTHON) -m pytest tests/test_chaos_matrix.py \
 		-q -m 'chaos and not slow'
-	$(PYTHON) scripts/check_bench.py \
-		--require chaos_slo.p99_ttft_under_kill
-
-# Regression gate over committed BENCH_pr*.json records: fails when the
-# newest record regresses >20% vs the previous one; required headline
-# metrics (cluster fan-out, streaming, llm_serving) must be present.
-bench-gate:
-	$(PYTHON) scripts/check_bench.py

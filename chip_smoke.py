@@ -62,7 +62,7 @@ SERVE_ENGINE = dict(num_blocks=4096, block_size=16, max_num_seqs=32,
 SERVE_NEW_TOKENS = 32
 # bf16 logits of two routes through the same weights (numerics phase).
 LOGIT_MAX_TOL, LOGIT_MEAN_TOL = 0.15, 0.02
-# Training: the dense 201M model of bench.py:_model_setup, at 8 x 1024.
+# Training: a dense 201M model, at 8 x 1024.
 TRAIN_MODEL = dict(vocab_size=32768, d_model=1024, n_layers=8, n_heads=16,
                    n_kv_heads=16, d_ff=4096, max_seq_len=1024)
 TRAIN_BATCH, TRAIN_SEQ, TRAIN_STEPS = 8, 1024, 6

@@ -61,6 +61,7 @@ def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
     lib.rtn_store_open.restype = p
     lib.rtn_store_open.argtypes = [ctypes.c_char_p]
     lib.rtn_store_close.argtypes = [p]
+    lib.rtn_store_unlink.argtypes = [p]
     lib.rtn_store_capacity.restype = u64
     lib.rtn_store_capacity.argtypes = [p]
     lib.rtn_store_used.restype = u64

@@ -302,6 +302,15 @@ void rtn_store_close(void* handle) {
   delete s;
 }
 
+// Drop the segment's name now and leave the mapping to rtn_store_close:
+// a store created under the same name before that close stays linked.
+void rtn_store_unlink(void* handle) {
+  Store* s = (Store*)handle;
+  if (!s || !s->owner) return;
+  shm_unlink(s->name);
+  s->owner = 0;
+}
+
 uint64_t rtn_store_capacity(void* handle) {
   return ((Store*)handle)->hdr->arena_size;
 }

@@ -5,6 +5,7 @@ from __future__ import annotations
 
 import ctypes
 import os
+import threading
 from typing import List, Optional
 
 import numpy as np
@@ -16,6 +17,7 @@ _ERRS = {
     -1: "exists", -2: "not found", -3: "full", -4: "timeout",
     -5: "closed", -6: "bad state", -7: "system error",
 }
+_CLOSED = -5
 
 
 class NativeError(RuntimeError):
@@ -42,12 +44,21 @@ def _check(code: int, op: str):
 class NativeObjectStore:
     """Shared-memory object store (plasma-parity surface: put/get/contains/
     delete + mutable objects). ``create`` owns the segment; ``open``
-    attaches from another process."""
+    attaches from another process.
+
+    ``close`` may race calls on other threads (a worker retired while
+    the runtime shuts down, a reader blocked in ``mo_read``): calls in
+    flight keep the segment mapped, the last of them unmaps it, and a
+    call after ``close`` raises ``NativeError`` (closed) instead of
+    handing the library a freed handle."""
 
     def __init__(self, handle, lib, owner: bool):
         self._h = handle
         self._lib = lib
         self._owner = owner
+        self._calls = 0
+        self._closing = False
+        self._calls_lock = threading.Lock()
 
     @staticmethod
     def create(name: Optional[str] = None, capacity: int = 64 << 20,
@@ -75,10 +86,40 @@ class NativeObjectStore:
         store.name = name
         return store
 
+    def _enter(self, op: str):
+        """The handle, held until the matching ``_exit``."""
+        with self._calls_lock:
+            if self._closing:
+                raise NativeError(_CLOSED, op)
+            self._calls += 1
+            return self._h
+
+    def _exit(self):
+        with self._calls_lock:
+            self._calls -= 1
+            h = self._h if self._closing and not self._calls else None
+            if h:
+                self._h = None
+        if h:
+            self._lib.rtn_store_close(h)
+
+    def _call(self, op: str, fn, *args):
+        h = self._enter(op)
+        try:
+            return fn(h, *args)
+        finally:
+            self._exit()
+
     def close(self):
-        if self._h:
-            self._lib.rtn_store_close(self._h)
-            self._h = None
+        with self._calls_lock:
+            if self._closing:
+                return
+            self._closing = True
+            self._calls += 1  # held like a call: the last one out unmaps
+        # The name goes now: the next runtime of this process creates its
+        # store under the same name, which a late unmap must not unlink.
+        self._lib.rtn_store_unlink(self._h)
+        self._exit()
 
     def __del__(self):
         try:
@@ -88,66 +129,76 @@ class NativeObjectStore:
 
     # ------------------------------------------------------------- objects
     def put(self, object_id: int, data: bytes):
-        _check(self._lib.rtn_put(self._h, object_id, data, len(data)),
-               "put")
+        _check(self._call("put", self._lib.rtn_put, object_id, data,
+                          len(data)), "put")
 
     def get(self, object_id: int) -> bytes:
         ptr = ctypes.POINTER(ctypes.c_uint8)()
         ln = ctypes.c_uint64()
-        _check(self._lib.rtn_get(self._h, object_id, ctypes.byref(ptr),
-                                 ctypes.byref(ln)), "get")
-        return ctypes.string_at(ptr, ln.value)
+        h = self._enter("get")
+        try:
+            _check(self._lib.rtn_get(h, object_id, ctypes.byref(ptr),
+                                     ctypes.byref(ln)), "get")
+            return ctypes.string_at(ptr, ln.value)
+        finally:
+            self._exit()
 
     def get_view(self, object_id: int) -> memoryview:
         """Zero-copy view into the shm segment (valid until delete)."""
         ptr = ctypes.POINTER(ctypes.c_uint8)()
         ln = ctypes.c_uint64()
-        _check(self._lib.rtn_get(self._h, object_id, ctypes.byref(ptr),
-                                 ctypes.byref(ln)), "get")
+        _check(self._call("get", self._lib.rtn_get, object_id,
+                          ctypes.byref(ptr), ctypes.byref(ln)), "get")
         arr = np.ctypeslib.as_array(ptr, shape=(ln.value,))
         return memoryview(arr)
 
     def contains(self, object_id: int) -> bool:
-        return bool(self._lib.rtn_contains(self._h, object_id))
+        return bool(self._call("contains", self._lib.rtn_contains,
+                               object_id))
 
     def delete(self, object_id: int):
-        _check(self._lib.rtn_delete(self._h, object_id), "delete")
+        _check(self._call("delete", self._lib.rtn_delete, object_id),
+               "delete")
 
     def stats(self) -> dict:
-        return {
-            "capacity": self._lib.rtn_store_capacity(self._h),
-            "used": self._lib.rtn_store_used(self._h),
-            "num_objects": self._lib.rtn_store_num_objects(self._h),
-        }
+        lib = self._lib
+        return self._call("stats", lambda h: {
+            "capacity": lib.rtn_store_capacity(h),
+            "used": lib.rtn_store_used(h),
+            "num_objects": lib.rtn_store_num_objects(h),
+        })
 
     # ----------------------------------------------------- mutable objects
     def mo_create(self, object_id: int, max_size: int,
                   num_readers: int = 1):
-        _check(self._lib.rtn_mo_create(self._h, object_id, max_size,
-                                       num_readers), "mo_create")
+        _check(self._call("mo_create", self._lib.rtn_mo_create, object_id,
+                          max_size, num_readers), "mo_create")
 
     def mo_write(self, object_id: int, data: bytes,
                  timeout_s: float = 60.0):
-        _check(self._lib.rtn_mo_write(self._h, object_id, data, len(data),
-                                      int(timeout_s * 1000)), "mo_write")
+        _check(self._call("mo_write", self._lib.rtn_mo_write, object_id,
+                          data, len(data), int(timeout_s * 1000)),
+               "mo_write")
 
     def mo_read(self, object_id: int, last_seen: int, max_size: int,
                 timeout_s: float = 60.0) -> (bytes, int):
         buf = ctypes.create_string_buffer(max_size)
         ln = ctypes.c_uint64()
         ver = ctypes.c_uint64()
-        _check(self._lib.rtn_mo_read(
-            self._h, object_id, last_seen, buf, max_size,
-            ctypes.byref(ln), ctypes.byref(ver),
+        _check(self._call(
+            "mo_read", self._lib.rtn_mo_read, object_id, last_seen, buf,
+            max_size, ctypes.byref(ln), ctypes.byref(ver),
             int(timeout_s * 1000)), "mo_read")
         return buf.raw[:ln.value], ver.value
 
     def mo_close(self, object_id: int):
-        _check(self._lib.rtn_mo_close(self._h, object_id), "mo_close")
+        _check(self._call("mo_close", self._lib.rtn_mo_close, object_id),
+               "mo_close")
 
     def mo_destroy(self, object_id: int):
         """Close + reclaim the payload arena (owner teardown path)."""
-        _check(self._lib.rtn_mo_destroy(self._h, object_id), "mo_destroy")
+        _check(self._call("mo_destroy", self._lib.rtn_mo_destroy,
+                          object_id), "mo_destroy")
 
 
 class NativeMutableChannel:
@@ -177,7 +228,8 @@ class NativeMutableChannel:
             self._store.mo_write(self.object_id, data,
                                  timeout_s=timeout if timeout else 60.0)
         except NativeError as e:
-            if e.code == -2:  # destroyed channel == closed to peers
+            # A destroyed channel or a closed store is closed to peers.
+            if e.code in (-2, _CLOSED):
                 raise ChannelError("channel destroyed") from None
             raise
 
@@ -189,7 +241,8 @@ class NativeMutableChannel:
                 self.object_id, self._last_seen[reader_id], self.max_size,
                 timeout_s=timeout if timeout else 60.0)
         except NativeError as e:
-            if e.code == -2:  # destroyed channel == closed to peers
+            # A destroyed channel or a closed store is closed to peers.
+            if e.code in (-2, _CLOSED):
                 raise ChannelError("channel destroyed") from None
             raise
         self._last_seen[reader_id] = ver

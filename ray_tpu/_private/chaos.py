@@ -317,8 +317,8 @@ def head_kill_target(proc, name: str = "head") -> KillTarget:
     """Target that SIGKILLs the HEAD process (the control plane itself
     — the failover suite's fault). ``once``: a dead primary stays dead;
     the warm standby promotes over the shared state log and clients
-    fail over by epoch, which is exactly what the matrix rows and
-    ``bench.py --suite head_failover`` assert."""
+    fail over by epoch, which is exactly what the matrix rows
+    assert."""
     return popen_kill_target(name, proc, kind="head", once=True)
 
 

@@ -137,9 +137,6 @@ _D("shm_store_bytes", int, 256 * 1024 * 1024,
    "Shared-memory store segment size for the native object store.")
 _D("shm_store_slots", int, 4096,
    "Max concurrent objects in the native shared-memory store.")
-_D("use_native_queue", bool, True,
-   "Route task dependency tracking through the C++ ready-ring when the "
-   "native layer is available.")
 _D("worker_mode", str, "process",
    "Task execution plane: 'process' (spawned worker processes over the shm "
    "store — the default, matching the reference's process-isolated "
@@ -216,13 +213,6 @@ _D("native_cache", str, "",
 _D("coordinator_address", str, "",
    "Multi-process device-plane coordinator address for "
    "parallel.distributed.initialize ('' = single-process mesh).")
-_D("ownership_directory", bool, True,
-   "Ownership-based object directory: node daemons skip the per-object "
-   "steady-state object_announce to the head (locations flow to the "
-   "owning driver in the direct task_done/item_done reports; peers "
-   "resolve owner-direct over the p2p plane), and an exiting driver "
-   "lease-transfers its table to the head. Off = every completion "
-   "announces to the head (the pre-ownership centralized directory).")
 _D("head_log_compact_records", int, 50000,
    "Compact the head's append-only state log once it holds this many "
    "records (snapshot + truncate; 0 disables compaction).")

@@ -12,8 +12,8 @@ just a request) explainable after the fact. Three pieces, one module:
   speedscope paste) or speedscope JSON. Armed by ``RAY_TPU_PROFILE``;
   pure-Python, no py-spy dependency, safe to leave running (the GIL
   serializes the sample against user code — cost is bounded by
-  ``profile_hz`` × stack depth, gated ≥0.95 fan-out ratio by
-  ``bench.py --suite flight_overhead``).
+  ``profile_hz`` × stack depth; disarmed it starts no thread, which
+  ``tests/test_flight.py::test_disarmed_is_inert`` holds).
 - **Structured event ring** — a bounded deque of ``(ts, kind, data)``
   tuples: state transitions, queue depths, lock-hold outliers (fed by
   ``util/sanitizer.py``'s tracked locks), GC pauses (a ``gc.callbacks``

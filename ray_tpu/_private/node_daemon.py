@@ -900,9 +900,7 @@ class NodeDaemon:
         the head first — the relayed consumer resolves through the
         head's fallback directory. Streaming item_done reports ride the
         same batches: many yields that accumulate while one flush is on
-        the wire coalesce into one vectored flight per driver.
-        ``ownership_directory=false`` restores the pre-ownership
-        announce-everything behavior."""
+        the wire coalesce into one vectored flight per driver."""
         from ray_tpu._private.object_server import PeerUnreachableError
 
         while True:
@@ -946,25 +944,11 @@ class NodeDaemon:
                 except Exception as exc:  # keep reporting others
                     log.warning("dropping one malformed completion "
                                 "record; reporting the rest: %r", exc)
-            ownership = GlobalConfig.ownership_directory
-            announced = True
-            if not ownership:
-                # Centralized directory (rollback lever): every result
-                # location coalesces through the head BEFORE completion
-                # reports go out — direct completion is only legal once
-                # the directory can serve later cross-node pulls.
-                announce = [ob for rec in built for ob in rec[4]]
-                try:
-                    self.head.object_announce_many(announce)
-                except Exception as exc:  # head hiccup: take the relay,
-                    announced = False     # which re-records locations
-                    log.debug("announce batch failed; falling back to "
-                              "relayed completions: %r", exc)
             by_driver: Dict[tuple, list] = {}
             for rec in built:
                 by_driver.setdefault((rec[2], rec[3]), []).append(rec)
             for (addr, driver_id), entries in by_driver.items():
-                if addr is not None and announced:
+                if addr is not None:
                     try:
                         replies = self.head._peers.call_many(
                             addr, [(kind, data)
@@ -986,21 +970,19 @@ class NodeDaemon:
                         entries = failed
                     except PeerUnreachableError:
                         pass  # driver not directly dialable: relay below
-                if ownership:
-                    # Relay fallback under ownership: the head becomes
-                    # the directory of record for THIS batch. The
-                    # task_done relay records its oid locations
-                    # server-side; only large streamed items (announce +
-                    # pull) need the explicit announce flight.
-                    fallback = [ob for rec in entries for ob in rec[4]
-                                if rec[0] == "item_done"]
-                    try:
-                        if fallback:
-                            self.head.object_announce_many(fallback)
-                        self.announce_fallback_oids += len(fallback)
-                    except Exception as exc:  # pub/sub item consumers
-                        log.debug("fallback announce failed (item pulls "
-                                  "resolve via owner only): %r", exc)
+                # Relay fallback: the head becomes the directory of
+                # record for THIS batch. The task_done relay records its
+                # oid locations server-side; only large streamed items
+                # (announce + pull) need the explicit announce flight.
+                fallback = [ob for rec in entries for ob in rec[4]
+                            if rec[0] == "item_done"]
+                try:
+                    if fallback:
+                        self.head.object_announce_many(fallback)
+                    self.announce_fallback_oids += len(fallback)
+                except Exception as exc:  # pub/sub item consumers
+                    log.debug("fallback announce failed (item pulls "
+                              "resolve via owner only): %r", exc)
                 dones = [(rec[4], rec[1]) for rec in entries
                          if rec[0] == "task_done"]
                 try:

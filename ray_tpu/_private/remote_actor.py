@@ -657,9 +657,7 @@ class ActorHost:
             "errs": errs,
             "inline": inline,
         }, protocol=5)
-        from ray_tpu._private.config import GlobalConfig
-
-        if GlobalConfig.ownership_directory and driver_addr:
+        if driver_addr:
             try:
                 self.head._peers.call(tuple(driver_addr),
                                       ("task_done", done))

@@ -1692,14 +1692,13 @@ class RemoteRouter:
         # fallback directory in ONE coalesced flight, so borrowers of a
         # gracefully-exited driver keep resolving. A SIGKILLed owner
         # skips this — its consumers fail typed (OwnerDiedError).
-        if GlobalConfig.ownership_directory:
-            try:
-                entries = self.owner_directory.snapshot_locations()
-                if entries:
-                    self.head.object_transfer_many(entries)
-            except Exception as exc:  # noqa: BLE001 — head gone: the
-                log.debug("lease handoff failed (head unreachable); "
-                          "borrowed refs will fail typed: %r", exc)
+        try:
+            entries = self.owner_directory.snapshot_locations()
+            if entries:
+                self.head.object_transfer_many(entries)
+        except Exception as exc:  # noqa: BLE001 — head gone: the
+            log.debug("lease handoff failed (head unreachable); "
+                      "borrowed refs will fail typed: %r", exc)
         with self._dispatch_cv:
             self._dispatch_cv.notify_all()
         self._pool.shutdown(wait=False, cancel_futures=True)

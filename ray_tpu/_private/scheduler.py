@@ -19,7 +19,6 @@ from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
-from ray_tpu._private.config import GlobalConfig
 from ray_tpu._private.ids import ObjectID, TaskID
 from ray_tpu._private.log import get_logger
 from ray_tpu._private.task_events import TaskEventBuffer
@@ -128,8 +127,7 @@ class LocalScheduler:
     def __init__(self, store, resource_pool: ResourcePool, num_workers: int,
                  task_events: Optional[TaskEventBuffer] = None,
                  lineage: Optional[dict] = None,
-                 worker_pool=None, shm_store=None,
-                 use_native_queue: Optional[bool] = None):
+                 worker_pool=None, shm_store=None):
         self._store = store
         self._resources = resource_pool
         self._pool = ThreadPoolExecutor(
@@ -182,15 +180,12 @@ class LocalScheduler:
         self._dq = None
         self._dq_handles: Dict[TaskID, int] = {}   # pending task -> handle
         self._dq_specs: Dict[int, TaskSpec] = {}
-        if use_native_queue is None:
-            use_native_queue = GlobalConfig.use_native_queue
-        if use_native_queue:
-            try:
-                from ray_tpu._native.store import NativeDynQueue
+        try:
+            from ray_tpu._native.store import NativeDynQueue
 
-                self._dq = NativeDynQueue()
-            except Exception:  # noqa: BLE001 — native layer optional
-                self._dq = None
+            self._dq = NativeDynQueue()
+        except Exception:  # noqa: BLE001 — native layer optional
+            self._dq = None
         if self._dq is not None:
             self._dq_pump = threading.Thread(
                 target=self._dq_pump_loop, daemon=True,

@@ -11,8 +11,8 @@ the tree may carry. The gate enforces three things:
 3. **Budget**: ``len(findings) <= budget`` and ``budget ==
    len(baseline)`` — growing the baseline requires raising the budget,
    which check 3 turns into an explicit, reviewable diff on two counts
-   that only ever go down together (the check_bench.py idiom: the
-   committed record is the ratchet).
+   that only ever go down together (the committed record is the
+   ratchet).
 """
 
 from __future__ import annotations

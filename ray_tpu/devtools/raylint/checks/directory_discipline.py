@@ -44,8 +44,7 @@ DIRECTORY_RPCS = frozenset({
 ALLOWED_FALLBACK_SITES: Set[Tuple[str, str, str]] = {
     # Node daemon: per-driver RELAY fallback (NAT'd / undialable driver)
     # announces that batch's streamed-item locations so the relayed
-    # consumer can resolve them via the head; with the flag off, the
-    # pre-ownership announce-everything path.
+    # consumer can resolve them via the head.
     ("ray_tpu/_private/node_daemon.py", "NodeDaemon._report_loop",
      "object_announce_many"),
     # Node daemon drain-before-reap: after offloading node-held result

@@ -1,5 +1,5 @@
 """CLI (reference role: ray/scripts/scripts.py — `ray status/list/
-microbenchmark/timeline/job`). argparse, no click dependency.
+timeline/job`). argparse, no click dependency.
 
 Usage: python -m ray_tpu.scripts.cli <command> [...]
 """
@@ -58,21 +58,6 @@ def cmd_timeline(args):
     with open(args.output, "w") as f:
         json.dump(trace, f)
     print(f"wrote {len(trace)} events to {args.output}")
-
-
-def cmd_microbenchmark(args):
-    """Run the microbenchmark suites (reference: `ray microbenchmark`).
-    ``--suite control_plane`` covers the cross-node rows: cluster
-    fan-out through the real head + node daemon with the direct-
-    dispatch counters (relay eliminated, fn bytes shipped once)."""
-    import subprocess
-
-    cmd = [sys.executable, "bench.py"]
-    if getattr(args, "suite", None):
-        cmd += ["--suite", args.suite]
-    else:
-        cmd += ["--all"]
-    raise SystemExit(subprocess.call(cmd))
 
 
 def cmd_job(args):
@@ -244,11 +229,6 @@ def main(argv=None):
     p = sub.add_parser("timeline")
     p.add_argument("--output", default="timeline.json")
     p.set_defaults(fn=cmd_timeline)
-    p = sub.add_parser("microbenchmark")
-    p.add_argument("--suite", default=None,
-                   help="one suite instead of --all (e.g. control_plane "
-                        "for the cross-node rows)")
-    p.set_defaults(fn=cmd_microbenchmark)
     p = sub.add_parser("job")
     p.add_argument("job_cmd", choices=["submit"])
     p.add_argument("entrypoint", nargs=argparse.REMAINDER)

@@ -328,12 +328,8 @@ def ownership_summary() -> Dict[str, Any]:
     claim — plus this runtime's owner/resolver counters (locations
     tracked, owner-direct locates/pulls served, head-fallback pulls).
     Safe without a head (local-only runtimes report their side only)."""
-    from ray_tpu._private.config import GlobalConfig
-
     w = global_worker()
-    out: Dict[str, Any] = {
-        "ownership_directory": bool(GlobalConfig.ownership_directory),
-    }
+    out: Dict[str, Any] = {}
     router = w.remote_router
     if router is not None:
         directory = router.owner_directory

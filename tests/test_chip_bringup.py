@@ -173,6 +173,28 @@ def test_a_chip_worker_never_falls_back_to_the_cpu(two_chip_runtime):
         ray_tpu.get(Model.remote().platform.remote(), timeout=120)
 
 
+def test_a_chip_worker_retired_after_shutdown_leaves_the_driver_up():
+    """A chip worker is retired by its task's thread; when that thread
+    runs late, after ``shutdown`` has closed the store, the retirement's
+    channel teardown must not take the driver down."""
+    out = _python(
+        "import os, time, ray_tpu\n"
+        "from ray_tpu._private import worker_pool\n"
+        "retire = worker_pool.WorkerProcess.shutdown\n"
+        "def late(self, timeout=2.0):\n"
+        "    time.sleep(1.0)\n"
+        "    return retire(self, timeout)\n"
+        "worker_pool.WorkerProcess.shutdown = late\n"
+        "ray_tpu.init(num_cpus=1, num_tpus=1)\n"
+        "on_chip = ray_tpu.remote(num_tpus=1)(lambda: os.getpid())\n"
+        "assert ray_tpu.get(on_chip.remote()) != os.getpid()\n"
+        "ray_tpu.shutdown()\n"
+        "time.sleep(1.5)\n"
+        "print('up')\n")
+    assert out.returncode == 0, (out.returncode, out.stderr[-2000:])
+    assert out.stdout.split() == ["up"]
+
+
 def test_use_tpu_requests_a_chip_for_each_train_worker():
     from ray_tpu.train import ScalingConfig
 

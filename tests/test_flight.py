@@ -9,8 +9,7 @@ rotate-at-capacity + stale-expiry hardening. The e2e suite spins a
 real head + two node daemons (PROCESS worker mode) fully armed and
 proves ``ray_tpu.debug_dump()`` assembles one incident archive from
 >= 4 distinct processes with ZERO new steady-state head RPCs, that a
-deliberately hung worker auto-dumps without operator action, and that
-a forced bench SLO-gate failure auto-captures an archive.
+deliberately hung worker auto-dumps without operator action.
 """
 
 import json
@@ -379,38 +378,6 @@ def test_spilled_bundle_merge_newest_and_stale_expiry(tmp_path):
     assert {b["pid"] for b in got} == {11}
 
 
-# ------------------------------------------------------------ bench capture
-def test_bench_autocapture_on_forced_gate_failure(tmp_path):
-    """bench.maybe_capture_debug: a failed SLO gate with a live
-    runtime pulls a debug archive; a passing gate captures nothing."""
-    sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
-    try:
-        import bench
-    finally:
-        sys.path.pop(0)
-    ray_tpu.shutdown()
-    ray_tpu.init(num_cpus=2, worker_mode="thread",
-                 ignore_reinit_error=True)
-    try:
-        assert bench.maybe_capture_debug(
-            "forced", ok=True, out_dir=str(tmp_path)) is None
-        assert not list(tmp_path.iterdir())
-        incident = bench.maybe_capture_debug(
-            "forced", ok=False, out_dir=str(tmp_path))
-        assert incident and os.path.isdir(incident)
-        manifest = json.loads(
-            open(os.path.join(incident, "manifest.json")).read())
-        assert "driver" in manifest["sources"]
-        bundle = json.loads(
-            open(os.path.join(incident, "driver.json")).read())
-        assert bundle["stacks"]  # all-thread stacks present
-        # _slo_assert raises with the archive path appended.
-        with pytest.raises(AssertionError, match="debug bundle"):
-            bench._slo_assert("forced", False, "floor missed")
-    finally:
-        ray_tpu.shutdown()
-
-
 # --------------------------------------------------------------------- e2e
 def _spawn_env():
     env = dict(os.environ)
@@ -564,8 +531,7 @@ def test_e2e_debug_dump_two_nodes(tmp_path):
 
 def test_e2e_cluster_profiling_toggle(tmp_path):
     """flight_ctl round trip: set_cluster_profiling pauses/resumes
-    samplers on the driver and every node (the flight_overhead bench's
-    A/B verb)."""
+    samplers on the driver and every node."""
     env = _spawn_env()
     os.environ["RAY_TPU_FLIGHT"] = "1"
     os.environ["RAY_TPU_PROFILE"] = "1"

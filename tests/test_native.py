@@ -2,7 +2,11 @@
 cross-process), and the C++ ready queue (reference test model:
 src/ray/object_manager/plasma tests + cluster_task_manager_test.cc)."""
 
+import functools
 import multiprocessing as mp
+import os
+import subprocess
+import sys
 import threading
 import time
 
@@ -249,40 +253,128 @@ def test_dyn_queue_edge_capacity_overflow():
         dq.add_dep(consumer, producers[2])  # edge table full
 
 
-def test_scheduler_native_queue_full_falls_back(ray_start_regular):
-    """A full native ring degrades to the python dependency path: chains
-    still execute correctly past the ring capacity."""
+_CLOSE_RACE = r"""
+import sys, threading, time
+from ray_tpu._native import NativeMutableChannel, NativeObjectStore
+from ray_tpu.exceptions import ChannelError
+
+store = NativeObjectStore.create(capacity=1 << 20, max_objects=64)
+ch = NativeMutableChannel(store, max_size=1024)
+if sys.argv[1] == "after":
+    store.close()
+    ch.destroy()  # a worker retired after its runtime shut down
+    for call in (lambda: ch.read(timeout=0.1), lambda: store.put(1, b"x")):
+        try:
+            call()
+        except Exception as exc:
+            print(type(exc).__name__, exc)
+else:
+    def read():
+        try:
+            ch.read(timeout=0.5)
+        except ChannelError as exc:
+            print(type(exc).__name__)
+
+    t = threading.Thread(target=read)
+    t.start()
+    while store._calls == 0:
+        time.sleep(0.001)
+    store.close()
+    assert store._h is not None, "unmapped under a blocked reader"
+    # The next runtime's store, under the same name, outlives the unmap.
+    again = NativeObjectStore.create(name=store.name, capacity=1 << 20,
+                                     max_objects=64)
+    t.join()
+    assert store._h is None, "never unmapped"
+    NativeObjectStore.open(store.name).close()
+    again.close()
+"""
+
+
+@pytest.mark.parametrize("when, expect", [
+    ("after", "ChannelError channel destroyed\n"
+              "NativeError native put failed: closed"),
+    ("during", "ChannelTimeoutError"),
+], ids=["after", "during"])
+def test_store_close_races_calls_on_other_threads(when, expect):
+    """A call after ``close`` raises (it used to hand the library a
+    freed handle and crash the process: a chip worker retired by its
+    task's thread after the fixture shut the runtime down), and a
+    reader blocked in ``mo_read`` keeps the segment mapped until it
+    returns, without unlinking a store created since under its name."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    out = subprocess.run([sys.executable, "-c", _CLOSE_RACE, when],
+                         cwd=root, capture_output=True, text=True,
+                         timeout=60)
+    assert out.returncode == 0, (out.returncode, out.stderr[-2000:])
+    assert out.stdout.strip() == expect
+
+
+def _run_chain(sched, n, step):
+    """Submit ``n`` tasks, each taking the one before's result, and
+    return the last one's value."""
+    from ray_tpu._private.ids import ObjectID
+    from ray_tpu._private.scheduler import TaskSpec
+    from ray_tpu._private.worker import ObjectRef, global_worker
+
+    w = global_worker()
+    prev_ref = None
+    for i in range(n):
+        task_id = w.next_task_id()
+        rid = ObjectID.for_task_return(task_id, 0)
+        sched.submit(TaskSpec(
+            task_id=task_id, function=functools.partial(step, i),
+            args=(prev_ref,) if prev_ref is not None else (0,),
+            kwargs={}, num_returns=1, return_ids=[rid],
+            name=f"chain{i}", resources={"CPU": 1.0}))
+        prev_ref = ObjectRef(rid)
+    value = w.store.get(prev_ref.object_id, timeout=30)
+    return w.serialization_context.deserialize(value)
+
+
+def test_scheduler_without_native_queue_runs_python_tracker(
+        ray_start_regular, monkeypatch):
+    """Where the native layer cannot be imported, the scheduler tracks
+    dependencies in Python: a dependent chain still runs in order."""
+    import ray_tpu._native.store as native_store
     from ray_tpu._private.scheduler import LocalScheduler, ResourcePool
     from ray_tpu._private.worker import global_worker
 
-    w = global_worker()
-    sched = LocalScheduler(w.store, ResourcePool({"CPU": 2.0}),
-                           num_workers=2, lineage={})
+    def _no_native(*a, **kw):
+        raise ImportError("native layer unavailable")
+
+    monkeypatch.setattr(native_store, "NativeDynQueue", _no_native)
+    sched = LocalScheduler(global_worker().store,
+                           ResourcePool({"CPU": 2.0}), num_workers=2,
+                           lineage={})
+    order = []
+
+    def step(i, x):
+        order.append(i)
+        return x + 1
+
+    try:
+        assert sched._dq is None
+        assert _run_chain(sched, 40, step) == 40
+        assert order == list(range(40))
+    finally:
+        sched.shutdown()
+
+
+def test_scheduler_native_queue_full_falls_back(ray_start_regular):
+    """A full native ring degrades to the python dependency path: chains
+    still execute correctly past the ring capacity."""
+    from ray_tpu._native.store import NativeDynQueue
+    from ray_tpu._private.scheduler import LocalScheduler, ResourcePool
+    from ray_tpu._private.worker import global_worker
+
+    sched = LocalScheduler(global_worker().store,
+                           ResourcePool({"CPU": 2.0}), num_workers=2,
+                           lineage={})
     try:
         # Tiny ring to force MemoryError fallbacks mid-traffic.
-        from ray_tpu._native.store import NativeDynQueue
-
         sched._dq = NativeDynQueue(max_tasks=8, max_edges=8)
-        import ray_tpu
-        from ray_tpu._private.ids import ObjectID
-        from ray_tpu._private.scheduler import TaskSpec
-        from ray_tpu._private.worker import ObjectRef
-
-        prev_ref = None
-        refs = []
-        for i in range(40):  # 5x the ring capacity
-            task_id = w.next_task_id()
-            rid = ObjectID.for_task_return(task_id, 0)
-            args = (prev_ref,) if prev_ref is not None else (0,)
-            spec = TaskSpec(
-                task_id=task_id,
-                function=lambda x: x + 1,
-                args=args, kwargs={}, num_returns=1, return_ids=[rid],
-                name=f"chain{i}", resources={"CPU": 1.0})
-            prev_ref = ObjectRef(rid)
-            refs.append(prev_ref)
-            sched.submit(spec)
-        w_store_value = w.store.get(refs[-1].object_id, timeout=30)
-        assert w.serialization_context.deserialize(w_store_value) == 40
+        # 5x the ring capacity.
+        assert _run_chain(sched, 40, lambda i, x: x + 1) == 40
     finally:
         sched.shutdown()

@@ -303,6 +303,51 @@ def test_foreign_ref_announced_after_lookup_wakes_event_driven(
     attached.kv_put(b"own/done", b"1")
 
 
+# ------------------------------------------------------------- flatness
+def test_fanout_through_a_node_leaves_the_head_object_plane_flat(head_proc):
+    """A fan-out through a node daemon to a dialable driver: every
+    completion goes direct to the owner, so the head's object-plane
+    RPCs, its relayed completions and its state-log appends do not
+    move with the number of objects (zero per 1k objects)."""
+    node = subprocess.Popen(
+        [sys.executable, "-m", "ray_tpu._private.node_daemon", "--address",
+         head_proc, "--num-cpus", "2", "--worker-mode", "thread"],
+        stdout=subprocess.PIPE, text=True,
+        env=dict(os.environ, JAX_PLATFORMS="cpu"))
+    ray_tpu.shutdown()
+    try:
+        assert "joined" in node.stdout.readline()
+        w = ray_tpu.init(num_cpus=0, num_tpus=0, worker_mode="thread",
+                         address=head_proc)
+        deadline = time.monotonic() + 10
+        while not all(n.get("peer_addr")
+                      for n in w.head_client.node_list() or [{}]):
+            assert time.monotonic() < deadline, "no peer address"
+            time.sleep(0.1)
+        sq = ray_tpu.remote(lambda x: x * x)
+        # Warm (function shipped, direct dial open) before the baseline.
+        assert ray_tpu.get([sq.remote(i) for i in range(4)],
+                           timeout=60) == [0, 1, 4, 9]
+        before = w.head_client.head_stats()
+        direct0 = w.remote_router.direct_done_reports
+        n = 300
+        assert ray_tpu.get([sq.remote(i) for i in range(n)],
+                           timeout=60) == [i * i for i in range(n)]
+        after = w.head_client.head_stats()
+        for kind in ("object_announce", "object_transfer_batch",
+                     "task_done", "task_push"):
+            assert (after["rpc_counts"].get(kind, 0)
+                    == before["rpc_counts"].get(kind, 0)), kind
+        for key in ("object_plane_rpcs", "log_appends"):
+            assert after[key] == before[key], key
+        assert w.remote_router.direct_done_reports - direct0 >= n
+        assert w.remote_router.relayed_done_reports == 0
+    finally:
+        ray_tpu.shutdown()
+        node.kill()
+        node.wait(timeout=5)
+
+
 # ----------------------------------------------------------- observability
 def test_head_stats_and_state_surface(attached):
     stats = attached.head_client.head_stats()
@@ -312,7 +357,6 @@ def test_head_stats_and_state_surface(attached):
     from ray_tpu.util.state import ownership_summary
 
     summary = ownership_summary()
-    assert summary["ownership_directory"] is True
     assert "owner" in summary and "resolver" in summary
     assert summary["head"]["rpc_total"] >= stats["rpc_total"]
 
@@ -328,7 +372,6 @@ def test_dashboard_api_head(attached):
         with urllib.request.urlopen(dash.url + "/api/head",
                                     timeout=10) as r:
             payload = json.loads(r.read())
-        assert payload["ownership_directory"] is True
         assert "rpc_counts" in payload["head"]
     finally:
         stop_dashboard()

@@ -396,29 +396,6 @@ def test_merge_prometheus_valid_exposition():
                if ln.startswith("# HELP ray_tpu_tasks_finished ")) == 1
 
 
-def test_check_bench_min_gate(tmp_path):
-    import json
-
-    sys.path.insert(0, os.path.join(os.path.dirname(__file__),
-                                    "..", "scripts"))
-    try:
-        import check_bench
-    finally:
-        sys.path.pop(0)
-    for i, ratio in ((1, 0.99), (2, 0.99)):
-        with open(tmp_path / f"BENCH_pr{i:02d}.json", "w") as f:
-            json.dump({"after": {"trace_overhead":
-                                 {"fanout_ratio": ratio}}}, f)
-    argv = ["--dir", str(tmp_path), "--require",
-            "trace_overhead.fanout_ratio",
-            "--min", "trace_overhead.fanout_ratio=0.95"]
-    assert check_bench.main(argv) == 0
-    with open(tmp_path / "BENCH_pr03.json", "w") as f:
-        json.dump({"after": {"trace_overhead":
-                             {"fanout_ratio": 0.90}}}, f)
-    assert check_bench.main(argv) == 1
-
-
 # --------------------------------------------------------------------- e2e
 def _spawn_env():
     env = dict(os.environ)
